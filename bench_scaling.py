@@ -54,12 +54,12 @@ def measure(n_chips: int, per_chip_batch: int = None,
         batches.append(jax.device_put(host, batch_sh))
     for i in range(3):
         wstate, mets = step(wstate, batches[i % 2])
-    float(mets["loss"])  # drain (see bench.py)
+    jax.block_until_ready((wstate, mets))
     t0 = time.perf_counter()
     iters = iters or ITERS
     for i in range(iters):
         wstate, mets = step(wstate, batches[i % 2])
-    float(mets["loss"])
+    jax.block_until_ready((wstate, mets))
     return batch * iters / (time.perf_counter() - t0)
 
 

@@ -129,9 +129,13 @@ topology-identical, so the swap re-traces nothing.
 
 Prints ONE JSON line in the bench.py contract:
   {"metric": "serving_decode_tokens_per_sec", "value": N,
-   "unit": "tokens/s", "vs_baseline": N, ...}
+   "unit": "tokens/s", "vs_baseline": N, ...,
+   "device": {"platform": ..., "kind": ..., "count": N}}
+Off a TPU the headline is ``serving_scenarios_counts_only`` with no
+value: the scenarios' counts are exact anywhere, their timings are not
+device metrics.
 
-``--json OUT`` additionally writes the same document (plus
+``--json OUT`` (TPU runs only) additionally writes the same document (plus
 ``schema_version``) to a file — a stable machine-readable schema per
 scenario (tokens/s, TTFT/queue-wait percentiles, recompiles, and the
 goodput/memory numbers: decode bandwidth-utilization, tokens/s/chip,
@@ -215,7 +219,19 @@ def main(argv=None):
                          "perf-trajectory record")
     cli = ap.parse_args(argv)
 
+    import jax
     import jax.numpy as jnp
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    on_chip = dev.platform == "tpu"
+    if cli.json and not on_chip:
+        ap.error(
+            "--json writes the perf-trajectory record, whose rates and "
+            f"latencies are device metrics; this is a {dev.platform} "
+            "run.  Without --json the scenarios still run and print "
+            "their counts.")
 
     import veles_tpu as vt
     from veles_tpu.runtime.engine import DecodeEngine
@@ -1938,6 +1954,17 @@ def main(argv=None):
         "model": {"vocab": V, "dim": DIM, "layers": 2},
         "conc4_tokens_per_sec": conc4["tokens_per_sec"],
     }
+    out["device"] = device
+    if not on_chip:
+        # the scenarios' counts (dispatches, recompiles, hit pages,
+        # incomplete streams) are exact anywhere; their times and rates
+        # say how fast XLA's CPU backend is and carry no device metric's
+        # name at the top of the record
+        out.update(
+            metric="serving_scenarios_counts_only", value=None,
+            vs_baseline=None,
+            note=f"{dev.platform} run: every time, rate and ratio below "
+                 "is from the host backend and is not a device metric")
     print(json.dumps(out))
     if cli.json:
         with open(cli.json, "w") as f:

@@ -75,7 +75,9 @@ def test_pick_disabled_returns_default(tuned):
                          default="b") == "b"
 
 
-def test_pick_failure_falls_back(tuned):
+def test_pick_failure_names_the_candidate(tuned):
+    """A candidate the device refuses is an error that names it — never
+    a quiet fall-back to the default formulation."""
     def broken(x):
         raise RuntimeError("boom")
 
@@ -83,8 +85,9 @@ def test_pick_failure_falls_back(tuned):
         return x * 2
 
     x = jnp.ones((4, 4))
-    assert autotune.pick("op3", {"ok": ok, "broken": broken}, [x],
-                         default="ok") == "ok"
+    with pytest.raises(RuntimeError, match="op3.*'broken'.*boom"):
+        autotune.pick("op3", {"ok": ok, "broken": broken}, [x],
+                      default="ok")
 
 
 def test_lrn_auto_resolves_via_autotune(tuned):

@@ -1,0 +1,198 @@
+"""The Pallas kernels of the main path, compiled for a TPU v5e that is
+described and not attached (the chip's own compiler is installed here).
+
+Interpret mode — what the rest of the suite runs the kernels in — passes
+where the real lowering refuses a program: a tile that is not aligned, too
+much VMEM, a kernel that cannot be partitioned over a mesh.  These cases
+compile each kernel at the shapes ``chip_smoke.py`` runs on the chip, on one
+described chip, and the kernel call sites of the units (attention, dropout)
+inside a jitted function over a 4-device mesh of described chips with
+batch-sharded operands.  The loader's gather is a jit of its own on one
+device (loader/fullbatch.py); no mesh reaches it.  Nothing runs: a compile
+that passes is not a chip run.
+
+The topology is described inside a fixture, in this one file, never at
+import: only one process at a time may hold the TPU library, and under
+pytest-xdist every worker imports every test file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from veles_tpu import ops
+from veles_tpu.ops import pallas_kernels as pk
+from veles_tpu.parallel.mesh import MeshSpec, make_mesh
+from veles_tpu.units.base import Context, Spec
+from veles_tpu.units.nn import Dropout
+from veles_tpu.units.parallel_nn import MultiHeadAttention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without the chip: keep
+    # the cache out of these compiles
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def for_the_chip(monkeypatch):
+    """Code that asks the backend still sees the CPU here and would pick
+    interpret mode: steer the one policy function, in the test."""
+    monkeypatch.setattr(ops, "use_pallas_default", lambda platform=None: True)
+    monkeypatch.setattr(pk, "use_pallas_default", lambda platform=None: True)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
+
+
+def _custom_calls(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call")
+
+
+def _value_and_grads(f):
+    def g(*args):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(f(*a).astype(jnp.float32)),
+            argnums=tuple(range(len(args))))(*args)
+    return g
+
+
+def _flash(B, T, H, Hk, D, window=None, grad=True):
+    def case(sh):
+        f = lambda q, k, v: pk.flash_attention(  # noqa: E731
+            q, k, v, True, None, window=window)
+        return (_value_and_grads(f) if grad else f,
+                [_sds((B, T, H, D), "bfloat16", sh),
+                 _sds((B, T, Hk, D), "bfloat16", sh),
+                 _sds((B, T, Hk, D), "bfloat16", sh)],
+                3 if grad else 1)
+    return case
+
+
+def _paged(B, H, Hk, D, dtype, psz=16, n_ptab=128):
+    def case(sh):
+        rows = B * n_ptab + 1
+        return (lambda q, k, v, ptab, pos: pk.paged_attention_decode(
+                    q, k, v, ptab, pos, page_size=psz, n_kv_heads=Hk),
+                [_sds((B, H, D), "float32", sh),
+                 _sds((rows, psz, Hk, D), dtype, sh),
+                 _sds((rows, psz, Hk, D), dtype, sh),
+                 _sds((B, n_ptab), "int32", sh), _sds((B,), "int32", sh)],
+                1)
+    return case
+
+
+def _dropout(shape, dtype, rate):
+    def case(sh):
+        return (lambda x, s: _value_and_grads(
+                    lambda x: pk.fused_dropout(x, s, rate))(x),
+                [_sds(shape, dtype, sh), _sds((), "uint32", sh)], 2)
+    return case
+
+
+def _mean_disp(n, f):
+    def case(sh):
+        return (lambda x, m, r: pk.mean_disp_normalize(x, m, r),
+                [_sds((n, f), "uint8", sh), _sds((f,), "float32", sh),
+                 _sds((f,), "float32", sh)], 1)
+    return case
+
+
+def _gather(n, f, m):
+    def case(sh):
+        return (lambda d, i: pk.gather_rows(d, i),
+                [_sds((n, f), "float32", sh), _sds((m,), "int32", sh)], 1)
+    return case
+
+
+#: name -> builder(sharding) -> (function, argument shapes, kernels expected)
+ONE_CHIP = {
+    "flash_fwd_b16_t2048": _flash(16, 2048, 8, 8, 64, grad=False),
+    "flash_fwd_bwd_b16_t2048": _flash(16, 2048, 8, 8, 64),
+    "flash_fwd_bwd_t4096_d128": _flash(1, 4096, 8, 8, 128),
+    "flash_window_gqa_t8192": _flash(1, 8192, 8, 2, 64, window=1024),
+    "paged_decode_f32_b32_h16_d128": _paged(32, 16, 16, 128, "float32"),
+    "paged_decode_bf16_gqa": _paged(32, 16, 4, 128, "bfloat16"),
+    "paged_decode_serve_geometry": _paged(8, 8, 8, 64, "float32"),
+    "fused_dropout_f32_4096sq": _dropout((4096, 4096), "float32", 0.3),
+    "fused_dropout_bf16_alexnet_fc": _dropout((512, 4096), "bfloat16", 0.5),
+    "mean_disp_normalize_alexnet_batch": _mean_disp(512, 227 * 227 * 3),
+    "gather_rows_60000x784": _gather(60000, 784, 512),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ONE_CHIP))
+def test_kernel_compiles_for_one_chip(topo, for_the_chip, name):
+    fn, args, kernels = ONE_CHIP[name](SingleDeviceSharding(topo.devices[0]))
+    assert _custom_calls(fn, *args) == kernels
+
+
+def _attention_site(mesh, batch_sh):
+    u = MultiHeadAttention(8, name="attn", rope=True, residual=True,
+                           use_flash=True)
+    spec = Spec((16, 2048, 512), jnp.bfloat16)
+    params, _ = jax.eval_shape(lambda k: u.init(k, [spec]),
+                               jax.random.key(0))
+
+    def f(params, x):
+        ctx = Context(train=True, key=None, mesh=mesh)
+        return u.apply(params, {}, [x], ctx)[0]
+
+    rep = NamedSharding(mesh, P())
+    return (_value_and_grads(f),
+            [jax.tree.map(lambda s: _sds(s.shape, s.dtype, rep), params),
+             _sds(spec.shape, spec.dtype, batch_sh)], 3)
+
+
+def _dropout_site(mesh, batch_sh):
+    u = Dropout(0.5, name="drop", use_pallas=True)
+
+    def f(x, key):
+        ctx = Context(train=True, key=key, mesh=mesh)
+        return _value_and_grads(
+            lambda x: u.apply({}, {}, [x], ctx)[0])(x)
+
+    return (f, [_sds((512, 4096), "bfloat16", batch_sh),
+                jax.eval_shape(lambda: jax.random.key(0))], 2)
+
+
+#: name -> (mesh spec, builder(mesh, batch sharding))
+CALL_SITES = {
+    "attention_data4": (MeshSpec(data=4), _attention_site),
+    "attention_data2_fsdp2": (MeshSpec(data=2, fsdp=2), _attention_site),
+    "attention_data2_model2": (MeshSpec(data=2, model=2), _attention_site),
+    "dropout_data4": (MeshSpec(data=4), _dropout_site),
+    "dropout_data2_fsdp2": (MeshSpec(data=2, fsdp=2), _dropout_site),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CALL_SITES))
+def test_kernel_call_site_compiles_under_a_mesh(topo, for_the_chip, name):
+    """The case the bare kernel call fails: inside a GSPMD-partitioned jit
+    XLA refuses it ("Mosaic kernels cannot be automatically partitioned");
+    the units hand it a shard_map over the batch (and head) axes."""
+    mesh_spec, site = CALL_SITES[name]
+    mesh = make_mesh(mesh_spec, devices=topo.devices)
+    dp = tuple(a for a in ("data", "fsdp") if mesh.shape[a] > 1)
+    fn, args, kernels = site(mesh, NamedSharding(mesh, P(dp)))
+    assert _custom_calls(fn, *args) == kernels

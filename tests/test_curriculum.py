@@ -3,10 +3,12 @@ configs/induction_lm64_curriculum.sh; closest reference machinery is
 rollback-to-best, manualrst_veles_algorithms.rst:164)."""
 import json
 import os
+import subprocess
+import sys
 
 import pytest
 
-from tests.test_cli import CONFIG_PY, run_cli
+from tests.test_cli import CONFIG_PY, REPO, run_cli
 
 
 @pytest.fixture
@@ -61,6 +63,24 @@ def test_curriculum_bar_stops_early(tmp_path, config_file):
     assert r.returncode == 0, r.stderr
     summary = json.loads(res.read_text())
     assert summary["phases_run"] == 1  # stopped after phase 1
+
+
+def test_curriculum_parent_initialises_no_backend(tmp_path, config_file):
+    """One process per chip: each phase is a child that needs the device,
+    so the parent may pin a platform but must touch no device itself."""
+    spec = write_spec(tmp_path, bar=100.0)  # one phase is enough
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import sys; from jax._src import xla_bridge;"
+         "from veles_tpu.__main__ import main;"
+         "rc = main(sys.argv[1:]);"
+         "print('BACKENDS', xla_bridge.backends_are_initialized());"
+         "sys.exit(rc)", config_file, "--curriculum", spec,
+         "--curriculum-out", str(tmp_path / "cur"), "--platform", "cpu"],
+        capture_output=True, text=True, cwd=str(tmp_path), timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO))
+    assert r.returncode == 0, r.stderr
+    assert "BACKENDS False" in r.stdout, r.stdout
 
 
 def test_curriculum_placeholder_expansion():

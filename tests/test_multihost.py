@@ -13,7 +13,6 @@ import sys
 
 import numpy as np
 import pytest
-from conftest import NEEDS_VMA
 
 SCRIPT = os.path.join(os.path.dirname(__file__), "_multihost_train.py")
 PP_SCRIPT = os.path.join(os.path.dirname(__file__), "_multihost_pp.py")
@@ -63,7 +62,6 @@ def test_two_process_training_loopback(tmp_path):
 
 
 @pytest.mark.slow
-@NEEDS_VMA
 def test_two_process_pp2_fused_1f1b_matches_single(tmp_path):
     """The fused-1F1B shard_map schedule SPANS the two-process Gloo
     boundary (VERDICT #2): stage 0 on host 0's only device, stage 1 on

@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from conftest import NEEDS_VMA
 
 
 from veles_tpu.parallel import (MeshSpec, init_moe_params, make_mesh,
@@ -17,7 +16,6 @@ def _stage_fn(params, x):
     return jnp.tanh(x @ params["w"] + params["b"])
 
 
-@NEEDS_VMA
 def test_pipeline_matches_sequential(rng):
     S, M, mb, D = 4, 8, 8, 16
     mesh = make_mesh(MeshSpec(data=2, pipe=4))
@@ -37,7 +35,6 @@ def test_pipeline_matches_sequential(rng):
                                rtol=2e-5, atol=2e-6)
 
 
-@NEEDS_VMA
 def test_pipeline_grad_flows(rng):
     """The pipelined forward must be differentiable (training path)."""
     S, M, mb, D = 2, 2, 4, 8
@@ -58,7 +55,6 @@ def test_pipeline_grad_flows(rng):
     assert not np.allclose(np.asarray(g["w"][0]), np.asarray(g["w"][1]))
 
 
-@NEEDS_VMA
 def test_pipeline_heterogeneous_stages(rng):
     """Round-2: stages with different parameter structures (list of
     stage_fns), verified against the sequential composition."""
@@ -97,7 +93,6 @@ def test_pipeline_heterogeneous_stages(rng):
         assert float(jnp.abs(g["w1"]).sum()) > 0
 
 
-@NEEDS_VMA
 def test_pipeline_io_sharded(rng):
     """Round-2: inputs/outputs are sharded over the pipe axis, not
     replicated — per-device memory drops S× (the round-1 verdict's
@@ -251,7 +246,6 @@ def _mean_mse(y, t):
     return jnp.mean(jnp.square(y - t))
 
 
-@NEEDS_VMA
 def test_pipeline_1f1b_matches_autodiff(rng):
     """The hand-scheduled 1F1B step must produce the same loss and stage
     grads as jax.grad through the sequential reference."""
@@ -288,7 +282,6 @@ def test_pipeline_1f1b_matches_autodiff(rng):
                                    rtol=2e-4, atol=2e-5)
 
 
-@NEEDS_VMA
 def test_pipeline_1f1b_data_sharded(rng):
     """1F1B with the microbatch dim sharded over the data axis: grads and
     loss must match the unsharded run."""
@@ -313,7 +306,6 @@ def test_pipeline_1f1b_data_sharded(rng):
                                    rtol=2e-4, atol=2e-5)
 
 
-@NEEDS_VMA
 def test_pipeline_1f1b_heterogeneous(rng):
     """1F1B over different per-stage callables/param structures."""
     from veles_tpu.parallel import pipeline_train_step
@@ -360,7 +352,6 @@ def test_pipeline_1f1b_heterogeneous(rng):
         pipeline_train_step(fns * 2, _mean_mse, params * 2, x, t, mesh)
 
 
-@NEEDS_VMA
 def test_pipeline_1f1b_bounded_memory(rng):
     """The 1F1B step's compiled temp memory must beat AD-through-GPipe at
     high microbatch count (the bounded-stash property: K=2(S-1)+1 stashed
@@ -392,7 +383,6 @@ def test_pipeline_1f1b_bounded_memory(rng):
 
 
 @pytest.mark.parametrize("S,M", [(2, 2), (2, 6), (8, 8), (8, 16)])
-@NEEDS_VMA
 def test_pipeline_1f1b_schedule_sweep(rng, S, M):
     """1F1B loss matches the sequential reference across depths and
     microbatch counts (fill/drain edge cases)."""
@@ -500,7 +490,6 @@ def _chain_ref(stage_fn, params, x, y, loss_fn, L, n_mb):
     return jax.value_and_grad(f)(params)
 
 
-@NEEDS_VMA
 def test_interleaved_1f1b_matches_ad(rng):
     """v virtual chunks per device: loss and per-stage grads exactly
     match AD through the sequential chain, for v in {1, 2, 4} and a
@@ -533,7 +522,6 @@ def test_interleaved_1f1b_matches_ad(rng):
                                    err_msg=f"v={v}")
 
 
-@NEEDS_VMA
 def test_interleaved_1f1b_keyed_aux_and_dp(rng):
     """Keyed mode (per-microbatch fold_in, same derivation as the plain
     schedules) with an aux channel, composed with a data axis."""

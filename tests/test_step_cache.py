@@ -8,6 +8,7 @@ recompile-with-scaled-schedule path, and the prefetch worker's device
 placement is equivalent to the synchronous fallback."""
 
 import glob
+import os
 
 import jax
 import jax.numpy as jnp
@@ -259,24 +260,71 @@ def test_step_cache_hits_across_reinitialize():
     assert tr.step_cache.recompiles == 0
 
 
-def test_persistent_cache_writes_entries(tmp_path):
-    assert not enable_persistent_cache("")  # empty config = disabled
-    prev = root.common.get("compile_cache", "")
-    root.common.compile_cache = str(tmp_path / "xlacache")
-    try:
-        d, lab = _blob()
-        tr = vt.Trainer(_fc_wf(), _loader(d, lab), opt.SGD(0.05),
-                        vt.Decision(max_epochs=1))
-        tr.initialize(seed=0)
-        entries = glob.glob(str(tmp_path / "xlacache" / "*"))
-        assert entries, "persistent compilation cache wrote nothing"
-    finally:
-        # back to pristine-disabled so later tests don't write into the
-        # deleted tmp dir
-        root.common.compile_cache = prev
-        jax.config.update("jax_compilation_cache_dir", None)
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
+@pytest.fixture
+def persistent_cache():
+    """The suite runs with jax's persistent cache switched off
+    (conftest.py); the tests of the cache rule switch it on, and put jax's
+    settings back afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = (jax.config.jax_enable_compilation_cache,
+            jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs)
+    jax.config.update("jax_enable_compilation_cache", True)
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev[0])
+    jax.config.update("jax_compilation_cache_dir", prev[1])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", prev[2])
+    compilation_cache.reset_cache()
+
+
+def test_persistent_cache_writes_entries(tmp_path, monkeypatch,
+                                         persistent_cache):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(root.common, "compile_cache",
+                        str(tmp_path / "xlacache"))
+    d, lab = _blob()
+    tr = vt.Trainer(_fc_wf(), _loader(d, lab), opt.SGD(0.05),
+                    vt.Decision(max_epochs=1))
+    tr.initialize(seed=0)
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xlacache")
+    entries = glob.glob(str(tmp_path / "xlacache" / "*"))
+    assert entries, "persistent compilation cache wrote nothing"
+
+
+def test_persistent_cache_placed_from_outside(tmp_path, monkeypatch,
+                                              persistent_cache):
+    """Where JAX_COMPILATION_CACHE_DIR is set, jax reads it itself and the
+    code sets no directory — not the default, not the configured one."""
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "outside"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "outside"))
+    monkeypatch.setattr(root.common, "compile_cache",
+                        str(tmp_path / "configured"))
+    assert enable_persistent_cache() == str(tmp_path / "outside")
+    assert enable_persistent_cache(str(tmp_path / "asked")) \
+        == str(tmp_path / "outside")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "outside")
+    assert not (tmp_path / "configured").exists()
+    assert not (tmp_path / "asked").exists()
+
+
+def test_persistent_cache_default_is_one_path_in_the_checkout(
+        tmp_path, monkeypatch, persistent_cache):
+    """Unset, the cache resolves to the same in-checkout directory from
+    any cwd: it is part of what an entry is keyed on."""
+    import veles_tpu
+    from veles_tpu.runtime.step_cache import DEFAULT_COMPILE_CACHE
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(root.common, "compile_cache", "")
+    seen = []
+    for cwd in (tmp_path, os.path.dirname(veles_tpu.__file__)):
+        monkeypatch.chdir(cwd)
+        seen.append(enable_persistent_cache())
+        assert jax.config.jax_compilation_cache_dir == seen[-1]
+    checkout = os.path.dirname(os.path.dirname(
+        os.path.abspath(veles_tpu.__file__)))
+    assert seen[0] == seen[1] == DEFAULT_COMPILE_CACHE
+    assert os.path.commonpath([checkout, seen[0]]) == checkout
+    assert str(tmp_path) not in seen[0]
 
 
 def test_req_int_rejects_json_booleans():

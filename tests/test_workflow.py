@@ -358,8 +358,8 @@ def test_remat_config_knob_exact_and_saves_memory(rng):
 
     Memory note: XLA:CPU's buffer analysis reports the same temp bytes
     with or without remat (it schedules the recompute adjacent to the
-    original forward), so the HBM saving is asserted on the chip
-    (.chipq/verify_remat.py), not here."""
+    original forward), so the HBM saving cannot be asserted
+    here; on the chip it is not measured yet."""
     import veles_tpu as vt
     from veles_tpu.models.standard import build_workflow
     from veles_tpu.ops import optimizers as opt

@@ -248,11 +248,13 @@ def _defaults():
     root.common.cache_dir = ".veles_tpu"
     root.common.autotune = True              # measured per-device op picks
     root.common.snapshot_dir = "snapshots"
-    # Persistent XLA compilation cache directory ("" = disabled): set via
-    # --compile-cache or root.common.compile_cache=DIR overrides; see
-    # runtime/step_cache.py and docs/compile_cache.md. Programs whose
-    # backend compile is faster than compile_cache_min_compile_secs are
-    # not persisted (0 = persist everything).
+    # Persistent XLA compilation cache directory: "" = the fixed
+    # in-checkout default (runtime/step_cache.py DEFAULT_COMPILE_CACHE);
+    # set via --compile-cache or root.common.compile_cache=DIR.  Where
+    # JAX_COMPILATION_CACHE_DIR is set it wins and no directory is set
+    # in code (docs/compile_cache.md).  Programs whose backend compile is
+    # faster than compile_cache_min_compile_secs are not persisted
+    # (0 = persist everything).
     root.common.compile_cache = ""
     root.common.compile_cache_min_compile_secs = 0.0
     # Upper bound (MiB) on the tensors blob compare_snapshots /
@@ -284,12 +286,11 @@ def _defaults():
     #                                            status.json event flushes
     # Deep performance observability (docs/observability.md: memory
     # ledger, goodput/MFU, rolling SLO windows, profiler endpoint).
-    root.common.observe.peak_tflops = 0.0    # measured peak for MFU; 0 =
-    #                                          use runtime/benchmark.py's
-    #                                          cached GEMM calibration
-    root.common.observe.peak_hbm_gbps = 0.0  # HBM bandwidth peak for the
-    #                                          decode MBU gauge (0 = MBU
-    #                                          reported as 0 / unknown)
+    # MFU / decode-MBU denominators: 0 = the device's published peak
+    # (runtime/benchmark.py DEVICE_PEAKS, keyed by device_kind; off a
+    # TPU the figures then read 0, "not measured")
+    root.common.observe.peak_tflops = 0.0
+    root.common.observe.peak_hbm_gbps = 0.0
     root.common.observe.memory_poll_s = 2.0  # device memory_stats() poll
     #                                          period (0 = no poller)
     root.common.observe.slo.window_s = 60.0  # rolling SLO window length

@@ -183,13 +183,16 @@ def _e2e_config(**overrides) -> dict:
     return cfg
 
 
-def _synth_store(n: int, seed: int = 13):
+def _synth_store(n: int, seed: int = 13, n_classes: int = 1000):
     """Deterministic synthetic decoded-JPEG store (uint8 256x256x3) +
-    labels — the single recipe behind every e2e input-pipeline variant."""
+    labels — the single recipe behind every e2e input-pipeline variant.
+    The pixels carry no signal; with ``n_classes`` well under the head's
+    1000 the label prior alone is learnable, which is what lets a run of
+    a few steps show a falling loss (``chip_smoke.py``)."""
     hw = ImagenetHostLoader.STORE_HW
     rng = np.random.default_rng(seed)
     store = rng.integers(0, 256, (n, hw, hw, 3), np.uint8)
-    labels = np.arange(n, dtype=np.int32) % 1000
+    labels = np.arange(n, dtype=np.int32) % n_classes
     return store, labels
 
 
@@ -205,7 +208,7 @@ def alexnet_e2e_workflow(minibatch_size=128, n_train=4096,
 
 
 def alexnet_e2e_device_workflow(minibatch_size=128, n_train=4096,
-                                n_valid=512, seed=13,
+                                n_valid=512, seed=13, n_classes=1000,
                                 **overrides) -> StandardWorkflow:
     """End-to-end AlexNet on the TPU-native input pipeline: the uint8
     256x256 store lives in HBM (FullBatchAugmentedLoader) and the random
@@ -218,7 +221,7 @@ def alexnet_e2e_device_workflow(minibatch_size=128, n_train=4096,
     from ..loader.fullbatch import FullBatchAugmentedLoader
 
     sw = StandardWorkflow(_e2e_config(**overrides))
-    store, labels = _synth_store(n_train + n_valid, seed)
+    store, labels = _synth_store(n_train + n_valid, seed, n_classes)
     sw.loader = FullBatchAugmentedLoader(
         {TRAIN: store[n_valid:], VALID: store[:n_valid]},
         {TRAIN: labels[n_valid:], VALID: labels[:n_valid]},

@@ -30,8 +30,6 @@ from __future__ import annotations
 import functools
 from typing import Optional
 
-import inspect
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -41,34 +39,11 @@ from jax.experimental.pallas import tpu as pltpu
 from . import (use_pallas_default,  # policy lives pallas-free in ops/__init__
                check_attention_window, check_gqa_heads)
 
-#: ``pltpu.CompilerParams`` across jax versions (the parallel/mesh.py
-#: ``shard_map`` shim pattern): older jax names the class
-#: ``TPUCompilerParams`` and lacks some fields (e.g.
-#: ``has_side_effects``).  Fields the resident class does not know are
-#: DROPPED — they are Mosaic lowering hints, not kernel semantics, and
-#: the kernels here run interpret-mode wherever the old class exists
-#: without them (the CPU test tier), so a missing hint can never change
-#: results.
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-_COMPILER_PARAMS_FIELDS = frozenset(
-    inspect.signature(_COMPILER_PARAMS_CLS).parameters)
-
-
-def compiler_params(**kwargs):
-    """Version-portable ``pltpu.CompilerParams(**kwargs)``."""
-    return _COMPILER_PARAMS_CLS(**{k: v for k, v in kwargs.items()
-                                   if k in _COMPILER_PARAMS_FIELDS})
-
-
-#: ``pltpu.HBM`` across jax versions: older jax only exposes the ANY
-#: memory space, which is how its pallas lowering says "leave the
-#: operand in HBM / let the DMA address it" — the same contract the
-#: gather kernel wants from HBM.
-_HBM = getattr(pltpu, "HBM", None) or pltpu.ANY
-
-
 def _interpret(interpret: Optional[bool]) -> bool:
+    """``None`` follows the backend: compiled through Mosaic on a TPU, the
+    Pallas interpreter elsewhere.  Nothing else selects interpret mode, so
+    a kernel the chip's compiler refuses raises there instead of running
+    interpreted."""
     if interpret is None:
         return not use_pallas_default()
     return interpret
@@ -228,7 +203,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
         # batch*head and q-block steps are independent; only the k sweep
         # carries the online-softmax state — telling Mosaic lets it
         # pipeline DMAs across grid steps instead of serializing.
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(interpret),
     )(qm, km, vm)
@@ -397,7 +372,7 @@ def _flash_bwd(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, tq_p, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=itp,
     )(qm, km, vm, dom, lse, delta)
@@ -436,7 +411,7 @@ def _flash_bwd(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=itp,
     )(qm, km, vm, dom, lse, delta)
@@ -615,7 +590,7 @@ def paged_attention_decode(q, k_pool, v_pool, ptab, pos, *, page_size,
         out_shape=jax.ShapeDtypeStruct((B, H, Dh), jnp.float32),
         # batch rows are independent; the page sweep carries the
         # online-softmax state
-        compiler_params=compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(interpret),
     )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32),
@@ -644,11 +619,14 @@ def _dropout_kernel(seed_ref, x_ref, o_ref, *, rate, block_rows, block_cols,
                     n_cols):
     # The mask bit for element (row, col) is a hash of its GLOBAL linear
     # index, so the mask is identical for any (block_rows, block_cols)
-    # tiling — backward can regenerate it with different tile choices.
+    # tiling — backward can regenerate it with different tile choices —
+    # and, through the row offset in seed_ref[0, 1], for any sharding of
+    # the rows over a mesh (Dropout.apply passes each shard its offset).
     pid_r, pid_c = pl.program_id(0), pl.program_id(1)
     r = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, block_cols), 0)
     c = jax.lax.broadcasted_iota(jnp.uint32, (block_rows, block_cols), 1)
-    row = pid_r.astype(jnp.uint32) * np.uint32(block_rows) + r
+    row = (seed_ref[0, 1] + pid_r.astype(jnp.uint32) * np.uint32(block_rows)
+           + r)
     col = pid_c.astype(jnp.uint32) * np.uint32(block_cols) + c
     lin = row * np.uint32(n_cols) + col
     # One fmix32-style finalizer pass (add-xorshift-mul x2) is already a
@@ -670,7 +648,7 @@ def _dropout_kernel(seed_ref, x_ref, o_ref, *, rate, block_rows, block_cols,
 _DROPOUT_BLOCK_ELEMS = 1 << 19
 
 
-def _dropout_apply(x, seed, rate, block_rows, interpret):
+def _dropout_apply(x, seed, rate, block_rows, interpret, row_offset=0):
     orig_shape = x.shape
     flat = x.reshape(-1, orig_shape[-1]) if x.ndim > 1 else x.reshape(1, -1)
     rows, cols = flat.shape
@@ -687,7 +665,8 @@ def _dropout_apply(x, seed, rate, block_rows, interpret):
     rows_p = _round_up(rows, block_rows)
     cols_p = _round_up(cols, block_cols)
     flat = jnp.pad(flat, ((0, rows_p - rows), (0, cols_p - cols)))
-    seed_arr = jnp.asarray(seed, jnp.uint32).reshape(1, 1)
+    seed_arr = jnp.stack([jnp.asarray(seed, jnp.uint32),
+                          jnp.asarray(row_offset, jnp.uint32)]).reshape(1, 2)
     kernel = functools.partial(_dropout_kernel, rate=float(rate),
                                block_rows=block_rows,
                                block_cols=block_cols, n_cols=cols)
@@ -707,21 +686,30 @@ def _dropout_apply(x, seed, rate, block_rows, interpret):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def fused_dropout(x, seed, rate, block_rows=256, interpret=None):
+def fused_dropout(x, seed, rate, block_rows=256, interpret=None,
+                  row_offset=0):
     """Dropout whose mask is a deterministic splitmix32 hash of
     (seed, element index), generated inside the kernel.  The backward pass
     re-runs the same kernel on the cotangent — the mask is never stored
-    (reference stored the random state per unit: ocl/random.cl)."""
-    return _dropout_apply(x, seed, rate, block_rows, interpret)
+    (reference stored the random state per unit: ocl/random.cl).
+
+    ``row_offset`` is the global index of ``x``'s first row (all but the
+    last axis flattened) when ``x`` is one shard of a larger array: the
+    hash then sees global indices and the mask does not depend on how the
+    rows are sharded."""
+    return _dropout_apply(x, seed, rate, block_rows, interpret, row_offset)
 
 
-def _dropout_vjp_fwd(x, seed, rate, block_rows, interpret):
-    return _dropout_apply(x, seed, rate, block_rows, interpret), seed
+def _dropout_vjp_fwd(x, seed, rate, block_rows, interpret, row_offset):
+    return (_dropout_apply(x, seed, rate, block_rows, interpret, row_offset),
+            (seed, row_offset))
 
 
-def _dropout_vjp_bwd(rate, block_rows, interpret, seed, g):
-    # Same seed -> same mask -> d/dx (x * keep) = g * keep.
-    return _dropout_apply(g, seed, rate, block_rows, interpret), None
+def _dropout_vjp_bwd(rate, block_rows, interpret, res, g):
+    # Same seed and offset -> same mask -> d/dx (x * keep) = g * keep.
+    seed, row_offset = res
+    return (_dropout_apply(g, seed, rate, block_rows, interpret, row_offset),
+            None, None)
 
 
 fused_dropout.defvjp(_dropout_vjp_fwd, _dropout_vjp_bwd)
@@ -811,8 +799,8 @@ def gather_rows_packed(packed, idx, *, interpret=None):
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m,),
-        in_specs=[pl.BlockSpec(memory_space=_HBM)],
-        out_specs=pl.BlockSpec(memory_space=_HBM),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
         scratch_shapes=[pltpu.SemaphoreType.DMA(())],
     )
     return pl.pallas_call(
@@ -821,7 +809,7 @@ def gather_rows_packed(packed, idx, *, interpret=None):
         out_shape=jax.ShapeDtypeStruct((m,) + packed.shape[1:],
                                        packed.dtype),
         interpret=_interpret(interpret),
-        compiler_params=compiler_params(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
     )(jnp.asarray(idx, jnp.int32), packed)
 
 

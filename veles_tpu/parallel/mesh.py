@@ -83,17 +83,39 @@ def make_mesh(spec: Optional[MeshSpec] = None,
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across jax versions: the public API landed
-    after 0.4.x, where it lives at ``jax.experimental.shard_map`` with
-    the replication check named ``check_rep`` instead of
-    ``check_vma``.  All veles_tpu shard_map call sites route through
-    here so schedule code is written against the current API only."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map``; the one name every veles_tpu call site (and
+    the analysis registry's shard_map scopes) goes through."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
+
+
+def batch_axes(mesh: Mesh, n: int):
+    """The mesh axes a leading (batch) dimension of extent ``n`` is
+    sharded over: data×fsdp (fsdp workers are data-parallel too), or
+    None when neither is > 1 or their product does not tile ``n``.
+    One definition for the step's batch shardings and for the kernels
+    shard_map'd under them (:func:`shard_batch`)."""
+    dp = tuple(a for a in ("data", "fsdp") if mesh.shape.get(a, 1) > 1)
+    if not dp or n % math.prod(mesh.shape[a] for a in dp):
+        return None
+    return dp if len(dp) > 1 else dp[0]
+
+
+def shard_batch(f, mesh: Mesh, specs: Sequence[P], out_spec: P):
+    """``f`` run per shard of a multi-device ``mesh``, each operand split
+    as its spec says and replicated over every other axis.
+
+    Why it exists: a Mosaic (Pallas TPU) kernel inside a GSPMD-partitioned
+    jit does not compile — XLA cannot partition the custom call and says
+    so ("Mosaic kernels cannot be automatically partitioned").  The kernel
+    call sites (attention, dropout) therefore hand the partitioner a
+    manual region in which each device runs the kernel on its own rows.
+    On a one-device mesh there is nothing to partition and ``f`` is
+    returned as is."""
+    if mesh.size == 1:
+        return f
+    return shard_map(f, mesh=mesh, in_specs=tuple(specs),
+                     out_specs=out_spec, check_vma=False)
 
 
 # -- sharding rules ----------------------------------------------------------
@@ -219,9 +241,7 @@ def batch_shardings(batch_spec, mesh: Mesh, *, seq_axis: Optional[int] = None):
         if len(shape) == 0:
             return NamedSharding(mesh, P())
         parts: list = [None] * len(shape)
-        dp = tuple(a for a in ("data", "fsdp") if mesh.shape[a] > 1)
-        if dp and shape[0] % math.prod(mesh.shape[a] for a in dp) == 0:
-            parts[0] = dp if len(dp) > 1 else dp[0]
+        parts[0] = batch_axes(mesh, shape[0])
         if (seq_axis is not None and len(shape) > seq_axis
                 and mesh.shape["seq"] > 1
                 and shape[seq_axis] % mesh.shape["seq"] == 0):

@@ -405,9 +405,7 @@ class ArtifactRunner(DecodeEngine):
         # _init_config just set.
         self._prefill_start = bool(man.get("prefill_start", False))
         self._chunk_capable = self._prefill_start
-        # strict: a sealed program that can't AOT-compile here must
-        # fail the LOAD, never lazily crash the first request
-        self.step_cache = StepCache(strict=True)
+        self.step_cache = StepCache()
         self.status = status
 
         self._exp_decode = _deserialize(self.art_dir, man, "decode",

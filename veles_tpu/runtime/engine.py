@@ -75,11 +75,13 @@ from ..config import root
 from ..logger import Logger
 from ..units.base import Context
 from .admission import AdmissionController
+from .benchmark import resolve_peak_hbm_gbps
 from .generate import DecodePlan
 from .memory import memory_monitor, tree_bytes
 from .metrics import ScopedCounter, next_trace_id, registry, span_ring
 from .slo import slo_tracker
-from .step_cache import StepCache, tree_signature
+from .step_cache import (StepCache, enable_persistent_cache,
+                         tree_signature)
 
 
 class EngineOverloaded(RuntimeError):
@@ -1337,7 +1339,10 @@ class DecodeEngine(Logger):
         # head width (== logits' last dim), for the top_k no-op sentinel
         self._vocab = self._head_width(params)
 
-        # the lifetime decode program, AOT-compiled up front
+        # the lifetime decode program, AOT-compiled up front (through
+        # the persistent cache: a restarted server skips the backend
+        # compile of every program it has built before)
+        enable_persistent_cache()
         self._decode = self._compile_decode(params)
 
         # megastep decode: the fourth program kind, compiled only when
@@ -1480,7 +1485,7 @@ class DecodeEngine(Logger):
         self._g_decode_mbu = reg.gauge(
             "vt_decode_mbu",
             "decode model-bandwidth-utilization: achieved bytes/s over "
-            "root.common.observe.peak_hbm_gbps (0 = peak unknown)")
+            "the device's published HBM peak (0 = not measured: no TPU)")
         self._g_tps_chip = reg.gauge(
             "vt_tokens_per_sec_per_chip",
             "recent decode throughput per local device")
@@ -2339,8 +2344,7 @@ class DecodeEngine(Logger):
         idle = (self._last_step_at <= 0
                 or time.monotonic() - self._last_step_at > 2.0)
         bw = self._bw_ewma if self._bw_ewma > 0 and not idle else 0.0
-        peak_gbps = float(
-            root.common.observe.get("peak_hbm_gbps", 0.0) or 0.0)
+        peak_gbps = resolve_peak_hbm_gbps()
         mbu = bw / (peak_gbps * 1e9) if peak_gbps > 0 else 0.0
         try:
             chips = max(jax.local_device_count(), 1)

@@ -31,59 +31,61 @@ cost next to step cost.
 
 from __future__ import annotations
 
+import os
 import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
+from jax.experimental.compilation_cache import compilation_cache
 
 from ..config import root
 from ..logger import Logger, TraceContext
 from .metrics import registry
 
 
-def enable_persistent_cache(cache_dir: Optional[str] = None) -> bool:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (default:
-    ``root.common.compile_cache``; empty = disabled).  Idempotent, safe to
-    call before every compile; returns whether the cache is active.
+#: Where the persistent cache lives when nothing outside places it: one
+#: fixed directory inside the checkout, resolved from the package's own
+#: location so every cwd and every process of one checkout share it (the
+#: directory is part of what JAX keys an entry on — one that moves never
+#: hits).  Git-ignored with the rest of ``.veles_tpu/``.
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".veles_tpu", "compile_cache")
+
+
+def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
+    """Switch JAX's persistent compilation cache on and return the
+    directory it uses.  Idempotent, safe to call before every compile;
+    ``Trainer.initialize``, ``DecodeEngine`` and ``chip_smoke.py`` all
+    come through here.
+
+    Placement: where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already
+    reads it and no directory is set in code.  Otherwise ``cache_dir``,
+    then ``root.common.compile_cache`` (``--compile-cache``), then
+    :data:`DEFAULT_COMPILE_CACHE`.
 
     The persistent cache is keyed on the optimized HLO + compile options,
     so it composes with (rather than replaces) the in-process StepCache:
     a process restart re-traces but skips the XLA backend compile.
     """
-    import os
-    cache_dir = cache_dir if cache_dir is not None \
-        else root.common.get("compile_cache", "")
-    if not cache_dir:
-        return False
-    cache_dir = os.path.abspath(os.path.expanduser(str(cache_dir)))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # default min-compile-time gate (1s) would silently skip the small
-    # CPU-tier programs the tests exercise; cache everything unless the
-    # config says otherwise
-    min_secs = float(root.common.get("compile_cache_min_compile_secs", 0.0))
-    try:
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", min_secs)
-    except (AttributeError, ValueError):  # older jax without the knob
-        pass
-    # jax initializes its cache object at most ONCE, at the first backend
-    # compile — a loader/prng jit before this call would freeze it to
-    # "no directory" forever; reset to pristine when the live cache does
-    # not point at the requested directory so the update takes effect.
-    try:
-        from jax._src import compilation_cache as _cc
-        live = getattr(_cc, "_cache", None)
-        # _path is a pathlib-style object — compare as str, else the
-        # mismatch guard is always true and every call resets
-        live_path = str(getattr(live, "_path", "")) if live is not None \
-            else None
-        if getattr(_cc, "_cache_initialized", False) \
-                and live_path != cache_dir:
-            _cc.reset_cache()
-    except Exception:
-        pass
-    return True
+    # jax's own 1 s gate would skip the small programs; persist
+    # everything unless the config says otherwise
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(root.common.get("compile_cache_min_compile_secs", 0.0)))
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    cache_dir = os.path.abspath(os.path.expanduser(str(
+        cache_dir or root.common.get("compile_cache", "")
+        or DEFAULT_COMPILE_CACHE)))
+    if jax.config.jax_compilation_cache_dir != cache_dir:
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        # jax opens its cache object once; a process that already
+        # compiled against another directory has to drop it
+        compilation_cache.reset_cache()
+    return cache_dir
 
 
 def _leaf_sig(path, leaf) -> Tuple[str, str, str]:
@@ -133,14 +135,7 @@ class StepCache(Logger):
     lifecycle keeps at zero across rollbacks and restores.
     """
 
-    def __init__(self, *, aot: bool = True, strict: bool = False):
-        self.aot = aot
-        # strict: an AOT lower/compile failure RAISES instead of
-        # falling back to on-demand jit.  The lazy fallback is a valid
-        # degradation for freshly traced model code (exotic signatures
-        # still run); for a sealed artifact's deserialized programs it
-        # would turn a load-time failure into a mid-request crash.
-        self.strict = strict
+    def __init__(self):
         self._entries: Dict[Any, dict] = {}
         self.compiles = 0
         self.hits = 0
@@ -215,16 +210,9 @@ class StepCache(Logger):
         with TraceContext("step_compile", program=kind):
             t0 = time.perf_counter()
             fn, state_sh, batch_sh = builder()
-            compiled = None
-            if self.aot:
-                try:
-                    compiled = fn.lower(*args).compile()
-                except Exception as e:  # exotic signature: keep the jit
-                    if self.strict:
-                        raise
-                    self.warning(
-                        "AOT compile of %s step failed (%s: %s); falling "
-                        "back to on-demand jit", kind, type(e).__name__, e)
+            # a program the compiler refuses fails HERE, by name, not
+            # later inside whichever call first runs a lazy jit
+            compiled = fn.lower(*args).compile()
             wall = time.perf_counter() - t0
         self.compiles += 1
         self.compile_wall_s += wall
@@ -232,17 +220,16 @@ class StepCache(Logger):
         self._m_wall.inc(wall)
 
         cost: Dict[str, float] = {}
-        if compiled is not None:
-            try:
-                ca = compiled.cost_analysis()
-                if isinstance(ca, (list, tuple)):
-                    ca = ca[0] if ca else {}
-                for label, k in (("flops", "flops"),
-                                 ("bytes_accessed", "bytes accessed")):
-                    if k in ca:
-                        cost[label] = float(ca[k])
-            except Exception:  # cost analysis is best-effort observability
-                pass
+        try:
+            ca = compiled.cost_analysis()
+            if isinstance(ca, (list, tuple)):
+                ca = ca[0] if ca else {}
+            for label, k in (("flops", "flops"),
+                             ("bytes_accessed", "bytes accessed")):
+                if k in ca:
+                    cost[label] = float(ca[k])
+        except Exception:  # cost analysis is best-effort observability
+            pass
         self.event("step_compile", program=kind, wall_s=round(wall, 4),
                    **cost)
         self.info(
@@ -250,7 +237,7 @@ class StepCache(Logger):
             kind, wall, cost.get("flops", 0.0) / 1e9,
             cost.get("bytes_accessed", 0.0) / 1e6)
         self._entries[full_key] = {
-            "fn": compiled if compiled is not None else fn,
+            "fn": compiled,
             "state_sh": state_sh, "batch_sh": batch_sh,
             "wall_s": wall, "cost": cost,
             # strong refs keep id()-anchored key components unique for

@@ -122,8 +122,8 @@ class Trainer(Logger):
             "vt_train_epoch", "current training epoch")
         # goodput (docs/observability.md "Goodput & MFU"): the train
         # program's cost analysis over the epoch wall, against the
-        # measured peak (runtime/benchmark.py GEMM calibration or the
-        # root.common.observe.peak_tflops override)
+        # device's published peak (runtime/benchmark.py DEVICE_PEAKS, or
+        # the root.common.observe.peak_tflops override)
         self._g_flops_sec = reg.gauge(
             "vt_train_flops_per_sec",
             "achieved training flops/s over the last train-epoch wall "
@@ -132,7 +132,7 @@ class Trainer(Logger):
         self._g_mfu = reg.gauge(
             "vt_train_mfu",
             "model FLOPs utilization of the last train epoch against "
-            "the measured peak (0 = peak unknown)")
+            "the device's published peak (0 = not measured: no TPU)")
 
     # -- setup -------------------------------------------------------------
     def initialize(self, seed: Optional[int] = None,
@@ -167,9 +167,8 @@ class Trainer(Logger):
                 (s.shape[0] * host_count(),) + tuple(s.shape[1:]), s.dtype)
                 for k, s in specs.items()}
         self._batch_spec = specs
-        # Persistent XLA compilation cache (no-op unless
-        # root.common.compile_cache / --compile-cache points somewhere):
-        # must be active BEFORE the first compile to be of any use.
+        # Persistent XLA compilation cache: must be active BEFORE the
+        # first compile to be of any use.
         enable_persistent_cache()
         self._compile_steps()
         if self._state_sh is not None:
@@ -421,7 +420,7 @@ class Trainer(Logger):
             samples_done += int(train_mets.get("n_samples", 0))
             # epoch goodput: the compiled step's cost analysis times the
             # steps run, over the epoch wall — and MFU against the
-            # measured peak (runtime/benchmark.py).  Host arithmetic
+            # device's published peak (runtime/benchmark.py).  Host arithmetic
             # only; the compiled programs are untouched.
             goodput = epoch_goodput(
                 self._train_cost["flops"],

@@ -13,6 +13,7 @@ the Znicz "smart init" (uniform ±1/sqrt(fan_in)).
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Sequence
 
 import jax
@@ -331,18 +332,42 @@ class Dropout(Unit):
             [x, seed], default="pallas")
         self._resolved = winner == "pallas"
 
+    def uses_kernel(self) -> bool:
+        """The fused Pallas kernel (True) or ``jax.random`` (False): the
+        forced or measured pick, else the platform default."""
+        return ops.use_pallas_default() if self._resolved is None \
+            else bool(self._resolved)
+
     def apply(self, params, state, xs, ctx):
         x = xs[0]
         if not ctx.train or self.ratio <= 0.0:
             return x, state
         key = ctx.unit_key(self.name)
-        use_pallas = (ops.use_pallas_default()
-                      if self._resolved is None else self._resolved)
-        if use_pallas:
+        if self.uses_kernel():
             # In-kernel counter-based RNG; mask regenerated in backward
             # (ops/pallas_kernels.py, parity: ocl/random.cl).
             seed = jax.random.bits(key, dtype=jnp.uint32)
-            return ops.fused_dropout(x, seed, self.ratio), state
+            if ctx.mesh is None or ctx.manual_axes is not None \
+                    or x.ndim < 2:
+                return ops.fused_dropout(x, seed, self.ratio), state
+            # under a GSPMD mesh each device runs the kernel on its own
+            # batch rows (parallel.mesh.shard_batch); the row offset
+            # keeps the mask a function of GLOBAL element indices, so
+            # it does not depend on the mesh
+            from jax.sharding import PartitionSpec as P
+            from ..parallel.mesh import batch_axes, shard_batch
+            axes = batch_axes(ctx.mesh, x.shape[0])
+
+            def local(xl, seed):  # shard-map-root: data,fsdp
+                rows = math.prod(xl.shape[:-1])
+                off = 0 if axes is None else \
+                    jax.lax.axis_index(axes).astype(jnp.uint32) * rows
+                return ops.fused_dropout(xl, seed, self.ratio,
+                                         row_offset=off)
+
+            spec = P(axes, *(None,) * (x.ndim - 1))
+            return shard_batch(local, ctx.mesh, (spec, P()),
+                               spec)(x, seed), state
         keep = 1.0 - self.ratio
         mask = jax.random.bernoulli(key, keep, x.shape)
         return jnp.where(mask, x / keep, 0.0).astype(x.dtype), state

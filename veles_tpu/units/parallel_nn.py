@@ -16,6 +16,7 @@ identical local computation, so the same config runs anywhere.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -212,10 +213,27 @@ class MultiHeadAttention(Forward):
             o = ring_attention(q, k, v, ctx.mesh, axis_name=self.seq_axis,
                                causal=self.causal, window=self.window)
         else:
-            o = blockwise_attention(q, k, v, block_size=self.block_size,
-                                    causal=self.causal, window=self.window,
-                                    use_flash=self._resolved_flash,
-                                    flash_blocks=self._resolved_blocks)
+            from .. import ops
+            use_flash = (ops.use_pallas_default()
+                         if self._resolved_flash is None
+                         else self._resolved_flash)
+            attend = functools.partial(
+                blockwise_attention, block_size=self.block_size,
+                causal=self.causal, window=self.window,
+                use_flash=use_flash, flash_blocks=self._resolved_blocks)
+            if use_flash and ctx.mesh is not None \
+                    and ctx.manual_axes is None:
+                # the flash kernel under a GSPMD mesh: each device runs
+                # it on its own batch rows (and heads, where a 'model'
+                # axis tiles them) — see parallel.mesh.shard_batch
+                from jax.sharding import PartitionSpec as P
+                from ..parallel.mesh import batch_axes, shard_batch
+                tp = ctx.axis_size("model")
+                heads = "model" if tp > 1 and H % tp == 0 \
+                    and self.n_kv_heads % tp == 0 else None
+                spec = P(batch_axes(ctx.mesh, B), None, heads, None)
+                attend = shard_batch(attend, ctx.mesh, (spec,) * 3, spec)
+            o = attend(q, k, v)
         y = o.reshape(B, T, -1) @ params["wo"].astype(dt)
         if self.residual:
             y = y + xq
