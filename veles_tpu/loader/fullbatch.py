@@ -150,14 +150,15 @@ class FullBatchLoader(ArrayLoader):
                 @jax.jit
                 def gather(tree, idx):
                     out = {}
-                    for key, a in tree.items():
-                        meta = packed_meta.get((klass, key))
-                        if meta is not None:
-                            f, sshape = meta
-                            out[key] = unpack_rows(
-                                gather_rows_packed(a, idx), f, sshape)
-                        else:
-                            out[key] = jnp.take(a, idx, axis=0)
+                    with jax.named_scope("loader_gather"):
+                        for key, a in tree.items():
+                            meta = packed_meta.get((klass, key))
+                            if meta is not None:
+                                f, sshape = meta
+                                out[key] = unpack_rows(
+                                    gather_rows_packed(a, idx), f, sshape)
+                            else:
+                                out[key] = jnp.take(a, idx, axis=0)
                     return out
                 return gather
 
@@ -166,7 +167,9 @@ class FullBatchLoader(ArrayLoader):
         else:
             @jax.jit
             def take_gather(tree, idx):
-                return jax.tree.map(lambda a: jnp.take(a, idx, axis=0), tree)
+                with jax.named_scope("loader_gather"):
+                    return jax.tree.map(
+                        lambda a: jnp.take(a, idx, axis=0), tree)
 
             self._gather = {klass: take_gather
                             for klass in self._dev_data}
@@ -331,19 +334,21 @@ class FullBatchAugmentedLoader(FullBatchLoader):
         @jax.jit
         def aug(tree, idx, offs, flips):
             out = {}
-            for key, a in tree.items():
-                if key == "@input":
-                    imgs = jnp.take(a, idx, axis=0)
+            with jax.named_scope("loader_aug"):
+                for key, a in tree.items():
+                    if key == "@input":
+                        imgs = jnp.take(a, idx, axis=0)
 
-                    def crop1(img, off, flip):
-                        c = jax.lax.dynamic_slice(
-                            img, (off[0], off[1]) + (0,) * (img.ndim - 2),
-                            (ch, cw) + img.shape[2:])
-                        return jnp.where(flip, c[:, ::-1], c)
+                        def crop1(img, off, flip):
+                            c = jax.lax.dynamic_slice(
+                                img,
+                                (off[0], off[1]) + (0,) * (img.ndim - 2),
+                                (ch, cw) + img.shape[2:])
+                            return jnp.where(flip, c[:, ::-1], c)
 
-                    out[key] = jax.vmap(crop1)(imgs, offs, flips)
-                else:
-                    out[key] = jnp.take(a, idx, axis=0)
+                        out[key] = jax.vmap(crop1)(imgs, offs, flips)
+                    else:
+                        out[key] = jnp.take(a, idx, axis=0)
             return out
 
         self._aug = aug

@@ -8,8 +8,9 @@ Design changes:
   * MongoDB sink is dropped; the ``event()`` timeline is written as JSON-lines
     to a local file (set ``root.common.trace_file``) so it stays greppable and
     feeds the profiler/status tooling without a database.
-  * Integrates with ``jax.profiler`` via :class:`TraceContext` for on-device
-    profiling instead of ``--sync-run`` style device syncs.
+  * Timed spans are ``runtime.metrics.span``: one entry point that feeds the
+    span ring, the ``jax.profiler`` host plane and, through
+    :func:`event_tracer`, this JSONL timeline.
 """
 
 from __future__ import annotations
@@ -109,6 +110,12 @@ class EventTracer:
 _tracer = EventTracer()
 
 
+def event_tracer() -> EventTracer:
+    """The process's JSONL timeline (a no-op sink until
+    ``root.common.trace_file`` is set)."""
+    return _tracer
+
+
 class Logger:
     """Mixin granting ``self.logger`` + ``info/debug/warning/error`` and the
     ``event()`` trace API (reference: veles/logger.py:59,264)."""
@@ -139,34 +146,3 @@ class Logger:
     def event(self, name: str, kind: str = "single", **attrs):
         """Emit a timeline event: kind in {"begin", "end", "single"}."""
         _tracer.emit(name, kind, unit=type(self).__name__, **attrs)
-
-
-class TraceContext:
-    """``with TraceContext("train_step"):`` — emits begin/end events and an
-    optional jax.profiler StepTraceAnnotation."""
-
-    def __init__(self, name: str, step: Optional[int] = None, **attrs):
-        self.name = name
-        self.step = step
-        self.attrs = attrs
-        self._jax_ctx = None
-
-    def __enter__(self):
-        _tracer.emit(self.name, "begin", **self.attrs)
-        if self.step is not None:
-            try:
-                import jax.profiler
-                self._jax_ctx = jax.profiler.StepTraceAnnotation(
-                    self.name, step_num=self.step)
-                self._jax_ctx.__enter__()
-            except Exception:  # profiling must never break training
-                self._jax_ctx = None
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(*exc)
-        _tracer.emit(self.name, "end", seconds=dt, **self.attrs)
-        return False

@@ -206,6 +206,7 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(interpret),
+        name="flash_fwd",
     )(qm, km, vm)
     out = out[:, :Tq].reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
     if return_lse:
@@ -375,6 +376,7 @@ def _flash_bwd(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=itp,
+        name="flash_bwd_dq",
     )(qm, km, vm, dom, lse, delta)
 
     # dK/dV: grid over kv heads; the minor sweep covers (group member g,
@@ -414,6 +416,7 @@ def _flash_bwd(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=itp,
+        name="flash_bwd_dkv",
     )(qm, km, vm, dom, lse, delta)
 
     def back(x, T, nh):
@@ -593,6 +596,7 @@ def paged_attention_decode(q, k_pool, v_pool, ptab, pos, *, page_size,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_interpret(interpret),
+        name="paged_attention_decode",
     )(jnp.asarray(ptab, jnp.int32), jnp.asarray(pos, jnp.int32),
       q, k_pool, v_pool)
 
@@ -681,6 +685,7 @@ def _dropout_apply(x, seed, rate, block_rows, interpret, row_offset=0):
                                lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows_p, cols_p), x.dtype),
         interpret=_interpret(interpret),
+        name="dropout",
     )(seed_arr, flat)
     return out[:rows, :cols].reshape(orig_shape)
 
@@ -757,6 +762,7 @@ def mean_disp_normalize(x, mean, rdisp, *, block_rows=128, block_cols=4096,
                                lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows_p, cols_p), dtype),
         interpret=_interpret(interpret),
+        name="mean_disp_normalize",
     )(flat, mean_f, rdisp_f)
     return out[:rows, :cols].reshape(orig_shape)
 
@@ -809,6 +815,7 @@ def gather_rows_packed(packed, idx, *, interpret=None):
         out_shape=jax.ShapeDtypeStruct((m,) + packed.shape[1:],
                                        packed.dtype),
         interpret=_interpret(interpret),
+        name="gather_rows_packed",
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
     )(jnp.asarray(idx, jnp.int32), packed)
 

@@ -12,7 +12,8 @@ gauge, so no perf PR could be judged against a tail-latency baseline.
 Design rules (docs/observability.md "Metrics & tracing"):
 
 * **zero dependencies** — stdlib only, no prometheus_client; the text
-  format is ~40 lines to emit and every scraper speaks it;
+  format is ~40 lines to emit and every scraper speaks it (``span``
+  alone reaches for ``jax.profiler``, when it is entered);
 * **host-side only** — nothing here may be called from traced scope
   (the analyzer's VT103 rule enforces it: ``time``/IO inside a traced
   program is flagged at lint time);
@@ -32,9 +33,10 @@ Design rules (docs/observability.md "Metrics & tracing"):
 
 The span ring is the request-level half: bounded (``root.common
 .observe.span_ring``), host-timestamped spans — per-request serving
-timelines (queue-wait → prefill → decode), per-epoch training spans,
-status events as instants — served as ``GET /trace.json`` and written
-by ``--trace-out``, loadable directly in Perfetto / ``chrome://tracing``.
+timelines (queue-wait → prefill → decode), the trainer's ``train_run``
+tree (:class:`span`), status events as instants — served as
+``GET /trace.json`` and written by ``--trace-out``, loadable directly in
+Perfetto / ``chrome://tracing``.
 """
 
 from __future__ import annotations
@@ -48,6 +50,7 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from ..config import root
+from ..logger import event_tracer
 
 #: default latency buckets (seconds): sub-ms prefills on warm caches up
 #: to the engine's 60s retry ceiling; chosen so TTFT, queue-wait and
@@ -634,6 +637,81 @@ def span_ring() -> SpanRing:
             _SPANS = SpanRing(
                 int(root.common.observe.get("span_ring", 512)))
         return _SPANS
+
+
+_SPAN_IDS = itertools.count(1)
+
+
+class _OpenSpans(threading.local):
+    """The spans each thread has open, innermost last."""
+
+    def __init__(self):
+        self.stack: List["span"] = []
+
+
+_OPEN = _OpenSpans()
+
+
+class span:
+    """``with span("eval", klass="validation") as sp:`` — THE host-side
+    span entry point: one call site puts the interval on three
+    timelines.
+
+    * the **span ring**: on exit one complete event whose args carry
+      ``id``, ``parent`` (the enclosing span on this thread, or None)
+      and ``trace`` (a root span draws a new ``next_trace_id()``;
+      children inherit it), so a reader rebuilds the tree without
+      comparing timestamps.  ``tid`` is the trace id, as the engine's
+      request spans have it: one track per ``Trainer.run()``;
+    * the **profiler**: a ``jax.profiler.TraceAnnotation`` of the same
+      name and args, so the span lies on the host plane of any active
+      capture, on the device trace's clock (a flag test otherwise);
+    * the **JSONL timeline**: a begin/end pair, when
+      ``root.common.trace_file`` is set.
+
+    ``sp.args`` may be added to inside the body (totals known only at
+    the end); ``sp.seconds`` holds the duration after exit.  An
+    exception in the body still closes the span (``error`` in its
+    args) and propagates."""
+
+    def __init__(self, name: str, cat: str = "host", **args):
+        self.name = str(name)
+        self.cat = cat
+        self.args = args
+        self.id = next(_SPAN_IDS)
+        self.parent: Optional[int] = None
+        self.trace: Optional[int] = None
+        self.seconds = 0.0
+
+    def _ids(self) -> dict:
+        return {"id": self.id, "parent": self.parent, "trace": self.trace}
+
+    def __enter__(self):
+        import jax.profiler
+        stack = _OPEN.stack
+        if stack:
+            self.parent, self.trace = stack[-1].id, stack[-1].trace
+        else:
+            self.trace = next_trace_id()
+        stack.append(self)
+        event_tracer().emit(self.name, "begin", **self.args, **self._ids())
+        self._annotation = jax.profiler.TraceAnnotation(
+            self.name, **self.args)
+        self._annotation.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.seconds = time.monotonic() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
+        _OPEN.stack.pop()
+        args = {**self.args, **self._ids()}
+        if exc_type is not None:
+            args["error"] = exc_type.__name__
+        span_ring().add(self.name, self._t0, self.seconds, cat=self.cat,
+                        tid=self.trace, args=args)
+        event_tracer().emit(self.name, "end", seconds=self.seconds, **args)
+        return False
 
 
 def write_chrome_trace(path: str) -> str:

@@ -24,9 +24,9 @@ Three layers:
   an unchanged program skips XLA entirely.
 
 Per-program cost analysis (FLOPs, bytes accessed, compile wall seconds)
-is logged through the existing :class:`~veles_tpu.logger.TraceContext` /
-event-trace path, so ``root.common.trace_file`` timelines show compile
-cost next to step cost.
+is logged through :class:`~veles_tpu.runtime.metrics.span` and the
+event-trace path, so ``root.common.trace_file`` timelines (and the span
+ring) show compile cost next to step cost.
 """
 
 from __future__ import annotations
@@ -39,8 +39,8 @@ import jax
 from jax.experimental.compilation_cache import compilation_cache
 
 from ..config import root
-from ..logger import Logger, TraceContext
-from .metrics import registry
+from ..logger import Logger
+from .metrics import registry, span
 
 
 #: Where the persistent cache lives when nothing outside places it: one
@@ -207,7 +207,7 @@ class StepCache(Logger):
             self._m_hits.labels(program=kind).inc()
             return ent["fn"], ent["state_sh"], ent["batch_sh"]
 
-        with TraceContext("step_compile", program=kind):
+        with span("step_compile", cat="compile", program=kind):
             t0 = time.perf_counter()
             fn, state_sh, batch_sh = builder()
             # a program the compiler refuses fails HERE, by name, not

@@ -22,15 +22,15 @@ import numpy as np
 
 from .. import prng
 from ..config import root
-from ..loader.base import TRAIN, VALID, TEST, Loader
-from ..logger import Logger, TraceContext
+from ..loader.base import CLASS_NAMES, TRAIN, VALID, TEST, Loader
+from ..logger import Logger
 from ..ops.optimizers import (ANOM_CONSEC_KEY, LR_MULT_KEY, Optimizer,
                               reserved_opt_neutral)
 from ..units.workflow import Workflow
 from .benchmark import epoch_goodput, resolve_peak_tflops
 from .decision import Decision
 from .memory import memory_monitor, tree_bytes
-from .metrics import registry, span_ring
+from .metrics import registry, span
 from .snapshotter import (Snapshotter, _to_numpy, restore_with_walkback)
 from .step_cache import StepCache, enable_persistent_cache
 
@@ -104,17 +104,28 @@ class Trainer(Logger):
         self.anomaly_steps_skipped = 0
         self.anomaly_rollbacks = 0
         self.snapshot_walkbacks = 0
-        # per-step phase breakdown (docs/observability.md "Metrics &
-        # tracing"): where a training second actually goes — blocked on
-        # the loader, moving the batch H2D, dispatching the step, or
-        # writing a snapshot.  Host-side wall times only; the step
-        # phase is dispatch + any implicit sync the NEXT phase forces,
-        # never a device sync of its own.
+        # phase breakdown (docs/observability.md "Metrics & tracing"):
+        # where a training second actually goes.  Per step — blocked on
+        # the loader, moving the batch H2D, dispatching the step (host
+        # wall: dispatch + any implicit sync the NEXT phase forces,
+        # never a device sync of its own).  Per epoch, each beside the
+        # span of the same work in run() — train_epoch (first dispatch
+        # to the end of the drain: the device's seconds as the host can
+        # honestly see them), train_drain, eval, boundary, snapshot.
         reg = registry()
         self._m_phase = reg.histogram(
             "vt_train_phase_seconds",
-            "per-step wall time by phase: data_wait | h2d | step | "
-            "snapshot", labels=("phase",))
+            "wall time by phase: per step data_wait | h2d | step; per "
+            "epoch train_epoch | train_drain | eval | boundary | snapshot",
+            labels=("phase",))
+        self._m_steps = reg.counter(
+            "vt_train_steps_total",
+            "batches dispatched to the compiled train / eval step, by "
+            "the loader's class", labels=("klass",))
+        self._step_num = 0      # train steps this trainer has dispatched
+        # the current run()'s seconds by phase (its span's args)
+        self._spent = dict.fromkeys(
+            ("train_epoch", "eval", "boundary", "snapshot"), 0.0)
         self._m_anom = reg.counter(
             "vt_train_anomaly_skips_total",
             "train steps skipped by the in-graph anomaly sentinel")
@@ -360,7 +371,8 @@ class Trainer(Logger):
     def _run_epoch_train(self, epoch: int) -> Dict[str, float]:
         sums: Dict[str, Any] = {}
         phase = self._m_phase
-        with TraceContext("train_epoch", epoch=epoch):
+        steps = self._m_steps.labels(klass=CLASS_NAMES[TRAIN])
+        with span("train_epoch", cat="train", epoch=epoch) as sp:
             # _batches yields batches already device-placed (H2D runs in
             # the prefetch worker, overlapped with the previous step);
             # data_wait is the time THIS thread blocked on the feed —
@@ -378,7 +390,13 @@ class Trainer(Logger):
                 phase.labels(phase="data_wait").observe(
                     time.monotonic() - t0)
                 t0 = time.monotonic()
-                self.wstate, mets = self._train_step(self.wstate, batch)
+                # profiler only (the ring holds 512 spans): a capture's
+                # host plane carries the trainer's own step number
+                with jax.profiler.StepTraceAnnotation(
+                        "train_step", step_num=self._step_num):
+                    self.wstate, mets = self._train_step(self.wstate, batch)
+                self._step_num += 1
+                steps.inc()
                 # Accumulate ON DEVICE — a float() here would sync the
                 # pipeline every step (the reference's --sync-run behavior,
                 # veles/accelerated_units.py:186-193, as an accident).
@@ -387,33 +405,73 @@ class Trainer(Logger):
                 sums["n_batches"] = sums.get("n_batches", 0) + 1
                 phase.labels(phase="step").observe(
                     time.monotonic() - t0)
-        return aggregate_epoch_metrics(
-            {k: float(v) for k, v in sums.items()})
+            # the one host sync of the epoch: it ends when the device has
+            # finished the last step, so the enclosing span's end is the
+            # device's
+            with span("train_drain", cat="train", epoch=epoch) as drain:
+                totals = {k: float(v) for k, v in sums.items()}
+            mets = aggregate_epoch_metrics(totals)
+            sp.args.update((k, round(v, 6)) for k, v in mets.items()
+                           if isinstance(v, float))
+        phase.labels(phase="train_drain").observe(drain.seconds)
+        self._phase_done("train_epoch", sp)
+        return mets
 
     def _run_epoch_eval(self, klass: int, epoch: int) -> Dict[str, float]:
         if self.loader.class_lengths[klass] == 0:
             return {}
         self._ensure_eval_step()
         sums: Dict[str, float] = {}
-        with TraceContext("eval_epoch", epoch=epoch, klass=klass):
+        steps = self._m_steps.labels(klass=CLASS_NAMES[klass])
+        with span("eval", cat="train", epoch=epoch,
+                  klass=CLASS_NAMES[klass]) as sp:
             for batch in self._batches(klass, epoch):
                 mets = self._eval_step(self.wstate, batch)
+                steps.inc()
                 for k, v in mets.items():
                     sums[k] = sums[k] + v if k in sums else v
                 sums["n_batches"] = sums.get("n_batches", 0) + 1
-        return aggregate_epoch_metrics(
-            {k: float(v) for k, v in sums.items()})
+            totals = {k: float(v) for k, v in sums.items()}
+        self._phase_done("eval", sp)
+        return aggregate_epoch_metrics(totals)
+
+    def _phase_done(self, phase: str, sp) -> None:
+        """Book a closed span's seconds under ``phase``, one of the four
+        that partition a run: the process's histogram, and this run's
+        own sums."""
+        self._m_phase.labels(phase=phase).observe(sp.seconds)
+        self._spent[phase] += sp.seconds
 
     # -- main loop ----------------------------------------------------------
     def run(self) -> Dict[str, Any]:
         if self.wstate is None:
             self.initialize()
+        # one span tree per call: train_run > (train_epoch > train_drain,
+        # epoch_decision, eval, epoch_decision, snapshot) per epoch.
+        # train_epoch, eval, boundary and snapshot partition the run; what
+        # they leave is its self time.  The run's own sums go into the
+        # span's args, so a reader of one run needs no snapshot of the
+        # process-global histogram at its start.
+        with span("train_run", cat="train") as run_span:
+            mono0, epoch0, step0 = (time.monotonic(),
+                                    self.loader.epoch_number, self._step_num)
+            self._spent = dict.fromkeys(self._spent, 0.0)
+            self._run()
+            spent = self._spent
+            run_span.args.update(
+                epochs=self.loader.epoch_number - epoch0,
+                steps=self._step_num - step0,
+                self_s=round(time.monotonic() - mono0
+                             - sum(spent.values()), 6),
+                **{f"{k}_s": round(v, 6) for k, v in spent.items()})
+        return self.results
+
+    def _run(self) -> None:
         t0 = time.time()
         samples_done = 0
         epoch = self.loader.epoch_number
         while not self.decision.complete:
             t_ep = time.time()
-            mono_ep = time.monotonic()
             self._g_epoch.set(epoch)
             train_mets = self._run_epoch_train(epoch)
             t_train = time.time()
@@ -431,7 +489,10 @@ class Trainer(Logger):
             self._last_mfu = goodput["mfu"]
             # anomaly accounting + (possibly) rollback escalation BEFORE
             # eval, so a rolled-back epoch validates the restored weights
-            self._check_anomalies(epoch, train_mets)
+            # — which is why epoch_decision opens twice an epoch
+            with span("epoch_decision", cat="train", epoch=epoch) as sp:
+                self._check_anomalies(epoch, train_mets)
+            self._phase_done("boundary", sp)
             valid_mets = self._run_epoch_eval(VALID, epoch)
             if root.common.timings:
                 # reference: per-unit/root.common.timings wall prints
@@ -443,47 +504,9 @@ class Trainer(Logger):
                     train_mets.get("n_samples", 0.0)
                     / max(t_train - t_ep, 1e-9),
                     time.time() - t_train)
-            stop = self.decision.on_epoch(epoch, train_mets, valid_mets)
-            if self.recorder is not None:
-                self.recorder.record(
-                    epoch,
-                    **{f"train_{k}": v for k, v in train_mets.items()},
-                    **{f"valid_{k}": v for k, v in valid_mets.items()})
-            if self.status is not None:
-                self.status.update(
-                    epoch=epoch, best_value=self.decision.best_value,
-                    best_epoch=self.decision.best_epoch,
-                    train_mfu=round(goodput["mfu"], 4),
-                    train_flops_per_sec=round(
-                        goodput["flops_per_sec"], 1),
-                    anomaly_steps_skipped=self.anomaly_steps_skipped,
-                    anomaly_rollbacks=self.anomaly_rollbacks,
-                    snapshot_walkbacks=self.snapshot_walkbacks,
-                    **{f"valid_{k}": v for k, v in valid_mets.items()})
-
-            if (self.decision.improved
-                    and (self.decision.rollback_after is not None
-                         or self._anomaly_patience() > 0)):
-                # Host-side copy: train_step donates wstate buffers, so an
-                # on-device alias would reference deleted arrays by the time
-                # a rollback happens. (All hosts reach this branch — the
-                # decision is identical everywhere — so the collective
-                # gather inside _host_state_copy is safe.)
-                self._best_wstate = self._host_state_copy()
-            if self.decision.want_rollback and self._best_wstate is not None:
-                # Reference: rollback to best snapshot + lr drop
-                # (manualrst_veles_algorithms.rst:164). The cumulative
-                # multiplier is written into the restored state's traced
-                # opt_state scalar — the compiled steps are untouched
-                # (ZERO recompiles; the restore re-places onto the mesh).
-                self.wstate = Snapshotter.restore_wstate(
-                    {"wstate": self._best_wstate}, like=self.wstate,
-                    shardings=self._state_sh)
-                self.wstate = self._apply_lr_multiplier(self.wstate)
-
-            # Advance the loader first so a restored checkpoint resumes at
-            # the *next* epoch instead of repeating the completed one.
-            self.loader.next_epoch()
+            with span("epoch_decision", cat="train", epoch=epoch) as sp:
+                stop = self._decide(epoch, train_mets, valid_mets, goodput)
+            self._phase_done("boundary", sp)
             if (self.snapshotter is not None
                     and self.snapshotter.tick(best=self.decision.improved)):
                 # tick() is deterministic across hosts, so throttled
@@ -495,21 +518,12 @@ class Trainer(Logger):
                 # every host a snapshotter with the same interval;
                 # wall-clock time_interval throttling can diverge across
                 # hosts and is rejected at initialize().
-                t_snap = time.monotonic()
-                payload = self._payload()
-                if jax.process_index() == 0:
-                    self.snapshotter.save(f"ep{epoch}", payload,
-                                          best=self.decision.improved)
-                self._m_phase.labels(phase="snapshot").observe(
-                    time.monotonic() - t_snap)
-            # one span per epoch in the shared ring: training epochs
-            # land on the same /trace.json timeline serving requests do
-            span_ring().add(
-                "train_epoch", mono_ep, time.monotonic() - mono_ep,
-                cat="train", tid=0,
-                args={"epoch": epoch,
-                      **{k: round(v, 6) for k, v in train_mets.items()
-                         if isinstance(v, float)}})
+                with span("snapshot", cat="train", epoch=epoch) as sp:
+                    payload = self._payload()
+                    if jax.process_index() == 0:
+                        self.snapshotter.save(f"ep{epoch}", payload,
+                                              best=self.decision.improved)
+                self._phase_done("snapshot", sp)
             epoch = self.loader.epoch_number
             if stop:
                 break
@@ -533,7 +547,54 @@ class Trainer(Logger):
             "snapshot_walkbacks": self.snapshot_walkbacks,
             **{f"test_{k}": v for k, v in test_mets.items()},
         })
-        return self.results
+
+    def _decide(self, epoch, train_mets, valid_mets, goodput) -> bool:
+        """The host work of an epoch boundary after validation: the
+        decision, the recorder and status page, the best-state copy, a
+        rollback, and the loader's advance.  Returns the decision's
+        stop."""
+        stop = self.decision.on_epoch(epoch, train_mets, valid_mets)
+        if self.recorder is not None:
+            self.recorder.record(
+                epoch,
+                **{f"train_{k}": v for k, v in train_mets.items()},
+                **{f"valid_{k}": v for k, v in valid_mets.items()})
+        if self.status is not None:
+            self.status.update(
+                epoch=epoch, best_value=self.decision.best_value,
+                best_epoch=self.decision.best_epoch,
+                train_mfu=round(goodput["mfu"], 4),
+                train_flops_per_sec=round(
+                    goodput["flops_per_sec"], 1),
+                anomaly_steps_skipped=self.anomaly_steps_skipped,
+                anomaly_rollbacks=self.anomaly_rollbacks,
+                snapshot_walkbacks=self.snapshot_walkbacks,
+                **{f"valid_{k}": v for k, v in valid_mets.items()})
+
+        if (self.decision.improved
+                and (self.decision.rollback_after is not None
+                     or self._anomaly_patience() > 0)):
+            # Host-side copy: train_step donates wstate buffers, so an
+            # on-device alias would reference deleted arrays by the time
+            # a rollback happens. (All hosts reach this branch — the
+            # decision is identical everywhere — so the collective
+            # gather inside _host_state_copy is safe.)
+            self._best_wstate = self._host_state_copy()
+        if self.decision.want_rollback and self._best_wstate is not None:
+            # Reference: rollback to best snapshot + lr drop
+            # (manualrst_veles_algorithms.rst:164). The cumulative
+            # multiplier is written into the restored state's traced
+            # opt_state scalar — the compiled steps are untouched
+            # (ZERO recompiles; the restore re-places onto the mesh).
+            self.wstate = Snapshotter.restore_wstate(
+                {"wstate": self._best_wstate}, like=self.wstate,
+                shardings=self._state_sh)
+            self.wstate = self._apply_lr_multiplier(self.wstate)
+
+        # Advance the loader first so a restored checkpoint resumes at
+        # the *next* epoch instead of repeating the completed one.
+        self.loader.next_epoch()
+        return stop
 
     # -- anomaly sentinel escalation ----------------------------------------
     def _anomaly_patience(self) -> int:

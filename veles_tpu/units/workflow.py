@@ -31,7 +31,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..logger import Logger, TraceContext
+from ..logger import Logger
 from ..ops.optimizers import Optimizer, guarded_update, tree_select
 from .base import Context, Spec, Unit
 
@@ -172,6 +172,13 @@ class Workflow(Logger):
             xs = [outputs[s] for s in u.inputs]
             up = params.get(u.name, {})
             us = state.get(u.name, {})
+            # the unit's name scopes its operations in the compiled
+            # program (jvp(name) / transpose(jvp(name)) under AD), which
+            # is how a profile tells conv2's backward from lrn1's
+            def apply(p, s, *xs, _u=u):
+                with jax.named_scope(_u.name):
+                    return _u.apply(p, s, list(xs), ctx)
+
             if getattr(u, "remat", False) and ctx.train:
                 # activation rematerialization: recompute this unit's
                 # internals in the backward instead of taping them —
@@ -179,11 +186,9 @@ class Workflow(Logger):
                 # FLOPs for HBM). Stochastic units are safe: the ctx key
                 # is a closed-over tracer, so the recompute draws the
                 # SAME mask.
-                y, ns = jax.checkpoint(
-                    lambda p, s, *xs, _u=u: _u.apply(p, s, list(xs),
-                                                     ctx))(up, us, *xs)
+                y, ns = jax.checkpoint(apply)(up, us, *xs)
             else:
-                y, ns = u.apply(up, us, xs, ctx)
+                y, ns = apply(up, us, *xs)
             outputs[u.name] = y
             # lint: disable=VT101 dict emptiness is static structure at
             # trace time (sparse nstate, not a value-dependent branch)
@@ -207,8 +212,9 @@ class Workflow(Logger):
             return {}
         ev = self.evaluator
         xs = [outputs[s] for s in ev.inputs]
-        return ev.metrics(params.get(ev.name, {}), state.get(ev.name, {}),
-                          xs, ctx)
+        with jax.named_scope("metrics"):
+            return ev.metrics(params.get(ev.name, {}),
+                              state.get(ev.name, {}), xs, ctx)
 
     # -- compiled steps ----------------------------------------------------
     def _build_step(self, optimizer: Optimizer) -> Callable:
@@ -256,11 +262,12 @@ class Workflow(Logger):
 
                 grads, (outputs, nstate, mets) = jax.grad(
                     loss_fn, has_aux=True)(wstate["params"])
-                params, opt_state, ok, gnorm = guarded_update(
-                    optimizer, grads, wstate["opt_state"],
-                    wstate["params"], wstate["step"],
-                    outputs[self.evaluator.name], clip_norm=clip,
-                    sentinel=sentinel, inject_nan_steps=inject)
+                with jax.named_scope("optimizer"):
+                    params, opt_state, ok, gnorm = guarded_update(
+                        optimizer, grads, wstate["opt_state"],
+                        wstate["params"], wstate["step"],
+                        outputs[self.evaluator.name], clip_norm=clip,
+                        sentinel=sentinel, inject_nan_steps=inject)
                 if ok is not None:
                     # a skipped step contributes nothing to the epoch
                     # aggregates (its loss/n_samples would be NaN or
