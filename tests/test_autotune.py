@@ -350,3 +350,77 @@ def test_attention_block_size_sweep(tuned, monkeypatch):
     rec2 = db2[kind]["autotune"][key]
     assert "flash" not in rec2["ms"]          # re-measured, not reused
     assert set(rec2["ms"]) == set(rec["ms"])
+
+
+def _kernel_runs_in_chain(probe, args, monkeypatch):
+    """How many times each flash kernel still runs in the program
+    ``autotune.measure`` times for ``probe``: its own chain, taken as it
+    hands it to ``jax.jit``, compiled, and the interpret-mode kernels
+    (one ``while`` over the grid each) counted in the optimised HLO."""
+    import collections
+    import re
+    import jax
+
+    chain = []
+
+    def take(fn, *a, **kw):
+        chain.append(fn)
+        raise StopIteration
+
+    with monkeypatch.context() as m, pytest.raises(StopIteration):
+        m.setattr(jax, "jit", take)
+        autotune.measure(probe, args)
+    text = jax.jit(chain[0]).lower(*args).compile().as_text()
+    return collections.Counter(
+        name for line in text.splitlines() if " while(" in line
+        for name in re.findall(r"op_name=\"[^\"]*?\((flash_\w+?)\)+/while",
+                               line))
+
+
+@pytest.mark.parametrize("probe", ["prepare", "bare_value_and_grad"])
+def test_attention_probe_keeps_the_backward_alive(monkeypatch, probe):
+    """``measure`` chains 4 repetitions through the first output alone.
+    The probe ``prepare`` builds keeps all three kernels in every one; a
+    bare ``value_and_grad`` (what it was before ISSUE 28) has a scalar
+    primal first, and XLA removes both backward kernels from every
+    repetition but the last, so the pick timed the forward."""
+    import functools
+    import jax
+    from veles_tpu.parallel.ring_attention import blockwise_attention
+    from veles_tpu.units.parallel_nn import MultiHeadAttention
+
+    attend = functools.partial(
+        blockwise_attention, block_size=16, causal=True, window=None,
+        use_flash=True, flash_blocks=(16, 16))  # interpret mode off-TPU
+    if probe == "prepare":
+        fn, backward = MultiHeadAttention._probe(attend), 4
+    else:
+        def fn(q, k, v):
+            return jax.value_and_grad(
+                lambda q, k, v: jnp.sum(attend(q, k, v)),
+                argnums=(0, 1, 2))(q, k, v)
+        backward = 1
+    args = [jnp.ones((1, 32, 1, 8), jnp.float32)] * 3
+    assert _kernel_runs_in_chain(fn, args, monkeypatch) == {
+        "flash_fwd": 4, "flash_bwd_dq": backward,
+        "flash_bwd_dkv": backward}
+
+
+def test_flash_tiles_gauge_after_a_trace():
+    """``vt_flash_tiles{kernel, class}`` says how often each tile class's
+    body engages at the traced shape: T = 2048 at 512 x 512 is 6 dead,
+    4 edge, 6 interior a head, in all three kernels."""
+    import jax
+    from veles_tpu.ops import pallas_kernels as pk
+    from veles_tpu.runtime.metrics import registry
+
+    x = jax.ShapeDtypeStruct((1, 2048, 1, 64), jnp.bfloat16)
+    jax.eval_shape(
+        jax.grad(lambda q, k, v: jnp.sum(pk.flash_attention(
+            q, k, v, True, None, 512, 512, True).astype(jnp.float32)),
+            argnums=(0, 1, 2)), x, x, x)
+    gauge = registry().get("vt_flash_tiles")
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert {c: gauge.labels(**{"kernel": kernel, "class": c}).value
+                for c in ("dead", "edge", "interior")} \
+            == {"dead": 6, "edge": 4, "interior": 6}

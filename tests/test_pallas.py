@@ -168,14 +168,7 @@ def test_fused_dropout_stream_statistics():
 
 def _windowed_reference(q, k, v, window):
     """Dense causal sliding-window attention reference."""
-    B, T, H, D = q.shape
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (D ** -0.5)
-    qpos = jnp.arange(T)[:, None]
-    kpos = jnp.arange(T)[None, :]
-    mask = (kpos <= qpos) & (kpos > qpos - window)
-    s = jnp.where(mask[None, None], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    return _dense_reference(q, k, v, True, window)
 
 
 @pytest.mark.parametrize("T,window", [(96, 32), (100, 16), (64, 64)])
@@ -252,6 +245,140 @@ def test_flash_attention_gqa_with_window(rng):
                               W)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
+
+
+# -- flash attention by tile class --------------------------------------------
+
+def _dense_reference(q, k, v, causal, window):
+    """Dense attention with absolute-position causal/window masks and
+    grouped kv heads: the position-by-position statement of what the
+    flash kernels' tile classes must add up to."""
+    G = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * (q.shape[-1] ** -0.5)
+    mask = _pair_mask(q.shape[1], k.shape[1], q.shape[1], k.shape[1],
+                      causal, window)
+    s = jnp.where(mask[None, None], s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _pair_mask(tq_p, tk_p, tq, tk, causal, window):
+    """(tq_p, tk_p) bool: which (query, key) pairs attend, padding out."""
+    qpos = np.arange(tq_p)[:, None]
+    kpos = np.arange(tk_p)[None, :]
+    mask = (qpos < tq) & (kpos < tk)
+    if causal:
+        mask &= kpos <= qpos
+        if window is not None:
+            mask &= kpos > qpos - window
+    return mask
+
+
+# (tq, tk, block_q, block_k, causal, window, (G, D) or None): the last
+# names kv groups and head size of an interpret-mode run against the
+# dense reference; None where only the classes are checked (T = 2048 is
+# the benchmark's shape, too slow for the interpreter)
+_TILE_CASES = {
+    "lm_512x512": (2048, 2048, 512, 512, True, None, None),
+    "lm_256x1024": (2048, 2048, 256, 1024, True, None, None),
+    "lm_256x256": (2048, 2048, 256, 256, True, None, None),
+    "lm_window": (2048, 2048, 256, 512, True, 600, None),
+    "causal": (48, 48, 16, 16, True, None, (1, 16)),
+    "causal_tall_blocks": (64, 64, 32, 16, True, None, (1, 16)),
+    "causal_wide_blocks": (64, 64, 16, 32, True, None, (1, 8)),
+    "window": (96, 96, 16, 16, True, 32, (1, 16)),
+    "window_wide_blocks": (64, 64, 16, 32, True, 24, (1, 16)),
+    "window_padded": (100, 100, 16, 16, True, 16, (1, 16)),
+    "gqa2": (64, 64, 16, 16, True, None, (2, 16)),
+    "gqa4_window": (96, 96, 16, 16, True, 32, (4, 16)),
+    "cross_padded": (24, 40, 16, 16, False, None, (1, 8)),
+    "cross_causal_short_q": (17, 40, 16, 8, True, None, (1, 16)),
+    "cross_causal_long_q": (40, 24, 16, 16, True, None, (2, 16)),
+    "padded": (37, 37, 16, 16, True, None, (1, 8)),
+    "noncausal": (48, 48, 16, 16, False, None, (1, 16)),
+    # K blocks wider than a register's 128 lanes, head wider too: the
+    # lane-replicated row state is tiled, not sliced (pk._lanes)
+    "wide_lanes": (256, 256, 128, 256, True, None, (1, 256)),
+    "odd_lanes": (192, 192, 64, 192, True, None, (1, 8)),
+}
+_TILE_COUNTS = {  # ISSUE 28: what vt_flash_tiles must read
+    "lm_512x512": {"dead": 6, "edge": 4, "interior": 6},
+    "lm_256x1024": {"dead": 4, "edge": 8, "interior": 4},
+    "lm_256x256": {"dead": 28, "edge": 8, "interior": 28},
+    "noncausal": {"dead": 0, "edge": 0, "interior": 9},
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TILE_CASES))
+def test_flash_tile_classes(rng, case):
+    """The kernels' tile predicates against a brute-force mask: every
+    interior tile is all-true (and every all-true tile interior), every
+    dead tile all-false, a clamped block index names a live tile and
+    repeats over consecutive dead steps; and the classed kernels'
+    forward and three gradients equal the dense reference."""
+    tq, tk, bq_req, bk_req, causal, window, run = _TILE_CASES[case]
+    bq, bk, tq_p, tk_p = pk._flash_blocks(tq, tk, bq_req, bk_req)
+    n_qb, n_kb = tq_p // bq, tk_p // bk
+    geom = dict(causal=causal, window=window, block_q=bq, block_k=bk)
+    mask = _pair_mask(tq_p, tk_p, tq, tk, causal, window)
+    counts = {"dead": 0, "edge": 0, "interior": 0}
+    live = np.zeros((n_qb, n_kb), bool)
+    for qi in range(n_qb):
+        for kj in range(n_kb):
+            tile = mask[qi * bq:(qi + 1) * bq, kj * bk:(kj + 1) * bk]
+            live[qi, kj] = pk._flash_block_live(qi, kj, **geom) \
+                is not False
+            interior = bool(pk._flash_tile_interior(qi, kj, tq=tq, tk=tk,
+                                                    **geom))
+            assert interior == tile.all(), (qi, kj)
+            assert live[qi, kj] or not tile.any(), (qi, kj)
+            assert live[qi, kj] or not interior
+            if tq == tk:  # no tile is live through padded rows alone
+                assert live[qi, kj] == tile.any(), (qi, kj)
+            counts["interior" if interior else
+                   "edge" if live[qi, kj] else "dead"] += 1
+    assert pk.flash_tile_classes(tq, tk, bq_req, bk_req, causal,
+                                 window) == counts
+    assert counts == _TILE_COUNTS.get(case, counts)
+    # the index maps' clamps: identity on a live tile, else the nearest
+    # live block of the sweep (so a dead step fetches nothing new)
+    for sweep, clamp in (
+            (live, lambda i, j: pk._flash_live_k(i, j, n_kb=n_kb, **geom)),
+            (live.T, lambda j, i: pk._flash_live_q(j, i, n_qb=n_qb,
+                                                   **geom))):
+        for a, row in enumerate(sweep):
+            got = [int(clamp(a, b)) for b in range(len(row))]
+            assert all(0 <= g < len(row) for g in got)
+            if not row.any():
+                continue
+            alive = np.flatnonzero(row)
+            want = np.clip(np.arange(len(row)), alive[0], alive[-1])
+            assert got == list(want), (a, got)
+    if run is None:
+        return
+    G, D = run
+    q = jnp.asarray(rng.standard_normal((1, tq, 2 * G, D)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, tk, 2, D)), jnp.float32)
+            for _ in range(2))
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(jnp.square(attend(q, k, v)))
+
+    def flash(q, k, v):
+        return pk.flash_attention(q, k, v, causal, None, bq_req, bk_req,
+                                  True, window)
+
+    def dense(q, k, v):
+        return _dense_reference(q, k, v, causal, window)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(dense(q, k, v)),
+                               rtol=2e-4, atol=2e-5)
+    got = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-3, atol=2e-4)
 
 
 # -- paged-attention decode kernel -------------------------------------------

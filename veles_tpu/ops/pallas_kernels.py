@@ -28,10 +28,13 @@ them on the CPU backend with ``interpret=True``; see tests/test_pallas.py).
 from __future__ import annotations
 
 import functools
+import math
+import operator
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -57,6 +60,23 @@ def _round_up(n: int, m: int) -> int:
 # Flash attention (forward kernel + recompute backward)
 # ---------------------------------------------------------------------------
 
+_LANES = 128  # a vector register's lane count: row state is kept that wide
+
+
+def _lanes(x, n):
+    """Row state ``x`` (rows, _LANES), every lane of a row the same value,
+    at width ``n``: per-row values stay lane-replicated from the reduction
+    that made them to the (rows, n) tile that uses them, in scratch and in
+    HBM alike.  A (rows, 1) column costs a masked store and a lane
+    broadcast at every use, which on the chip was a third of the forward
+    at 512-wide K blocks (PERF.md section 6, PR 28)."""
+    if n <= _LANES:
+        return x[:, :n]
+    if n % _LANES == 0:
+        return pltpu.repeat(x, n // _LANES, 1)
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                       acc_ref, *,
                       scale, causal, window, block_q, block_k, tq, tk,
@@ -65,7 +85,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     VMEM holds only one (block_q, D) Q tile and one (block_k, D) K/V tile at
     a time — the m/l/acc online-softmax state lives in scratch that persists
     across the sequentially-iterated k steps (long T streams from HBM
-    block-by-block instead of residing whole in VMEM)."""
+    block-by-block instead of residing whole in VMEM).  ``scale`` is None
+    when the caller folded it into Q (``_flash_scale``)."""
     qi, kj = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kj == 0)
@@ -74,7 +95,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _step():
+    def _step(masked):
         # Operands stay in their storage dtype (bf16 inputs hit the MXU at
         # the bf16 rate); accumulation is forced to f32 via
         # preferred_element_type — casting to f32 first would silently run
@@ -84,49 +105,41 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         v_blk = v_ref[0]
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < tk
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = mask & (k_pos <= q_pos)
-            if window is not None:
-                # sliding window: key positions in (q - window, q]
-                mask = mask & (k_pos > q_pos - window)
-        s = jnp.where(mask, s, -1e30)
-        # Row state m/l is kept as (block_q, 1) column vectors — keepdims
-        # math throughout, because Mosaic's layout rules want >=2-D values
-        # (rank-2 with a unit minor dim lowers cleanly; rank-1 does not).
+            preferred_element_type=jnp.float32)
+        if scale is not None:
+            s = s * scale
+        mask = _flash_tile_mask(
+            qi, kj, causal=causal, window=window, block_q=block_q,
+            block_k=block_k, tq=tq, tk=tk, rows=False) if masked else None
+        if mask is not None:
+            s = jnp.where(mask, s, -1e30)
+        # Row state m/l is (block_q, _LANES), lane-replicated (_lanes); the
+        # keepdims reductions broadcast into it.
         m = m_ref[:]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - _lanes(m_new, block_k))
+        if mask is not None:
+            p = jnp.where(mask, p, 0.0)
         m_ref[:] = m_new
         l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_ref[:] = acc_ref[:] * _lanes(alpha, acc_ref.shape[-1]) \
+            + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    # Skip fully-masked K blocks: with causal, those after the diagonal;
-    # with a sliding window also those entirely before it — cost becomes
-    # O(T*window) instead of O(T^2/2).
-    live = _flash_block_live(qi, kj, causal=causal, window=window,
-                             block_q=block_q, block_k=block_k)
-    if live is None:
-        _step()
-    else:
-        pl.when(live)(_step)
+    _flash_by_tile_class(_step, qi, kj, causal=causal, window=window,
+                         block_q=block_q, block_k=block_k, tq=tq, tk=tk)
 
     @pl.when(kj == n_kb - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[:]
-                    / jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
-        # logsumexp per row, consumed by the Pallas backward kernels.
-        # Stored as (BH, T, 1): the unit minor dim keeps the block shape
-        # legal under Mosaic's (8, 128)-divisible-or-full rule.
-        lse_ref[0] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
+        denom = jnp.maximum(l_ref[:], 1e-30)
+        o_ref[0] = (acc_ref[:] / _lanes(denom, acc_ref.shape[-1])
+                    ).astype(o_ref.dtype)
+        # logsumexp per row, consumed by the Pallas backward kernels, kept
+        # lane-replicated: (BH, T, _LANES) is what a (BH, T, 1) array
+        # occupies under the (8, 128) tiling anyway.
+        lse_ref[0] = m_ref[:] + jnp.log(denom)
 
 
 def _flash_layout(x, T, t_p):
@@ -164,40 +177,45 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
     H_kv = k.shape[2]
     G = _gqa_groups(q, k)
     window = check_attention_window(window, causal)
-    scale_ = scale if scale is not None else D ** -0.5
+    scale_, folded = _flash_scale(scale, D)
+    _note_tile_classes(("flash_fwd",), Tq, Tk, block_q, block_k, causal,
+                       window)
     block_q, block_k, tq_p, tk_p = _flash_blocks(Tq, Tk, block_q, block_k)
 
-    qm = _flash_layout(q, Tq, tq_p)
+    qm = _flash_layout(q * scale_ if folded else q, Tq, tq_p)
     km = _flash_layout(k, Tk, tk_p)
     vm = _flash_layout(v, Tk, tk_p)
 
     n_kb = tk_p // block_k
+    geom = dict(causal=causal, window=window, block_q=block_q,
+                block_k=block_k)
     kernel = functools.partial(
-        _flash_fwd_kernel, scale=scale_, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, tq=Tq, tk=Tk, n_kb=n_kb)
+        _flash_fwd_kernel, scale=None if folded else scale_, tq=Tq, tk=Tk,
+        n_kb=n_kb, **geom)
     # GQA: index-map arithmetic on grid indices is static.
     kv_row = _kv_row_map(H, H_kv, G)
+    live_k = functools.partial(_flash_live_k, n_kb=n_kb, **geom)
     out, lse = pl.pallas_call(
         kernel,
         grid=(B * H, tq_p // block_q, n_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D),
-                         lambda b, i, j: (kv_row(b), j, 0)),
+                         lambda b, i, j: (kv_row(b), live_k(i, j), 0)),
             pl.BlockSpec((1, block_k, D),
-                         lambda b, i, j: (kv_row(b), j, 0)),
+                         lambda b, i, j: (kv_row(b), live_k(i, j), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, tq_p, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, tq_p, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, tq_p, _LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         # batch*head and q-block steps are independent; only the k sweep
@@ -214,26 +232,37 @@ def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
     return out
 
 
-def _flash_bwd_mask(qi, kj, *, causal, window, block_q, block_k, tq, tk):
-    """Validity mask for one (block_q, block_k) tile: in-range rows/cols
-    plus the causal triangle (and sliding window).  Padded Q rows carry a
-    bogus lse (=-1e30 + log eps), so P must be forced to zero there or
-    they'd pollute dK/dV."""
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = (q_pos < tq) & (k_pos < tk)
+def _flash_tile_mask(qi, kj, *, causal, window, block_q, block_k, tq, tk,
+                     rows):
+    """Validity mask of one (block_q, block_k) edge tile, or None where
+    nothing in it needs one: the causal triangle (and sliding window) as
+    ONE iota difference against the tile's scalar offset, plus the
+    in-range columns (and, with ``rows``, rows) only where T is padded.
+    ``rows`` is the backward's: padded Q rows carry a bogus lse
+    (=-1e30 + log eps), so P must be forced to zero there or they'd
+    pollute dK/dV; the forward's padded rows are sliced off."""
+    shape = (block_q, block_k)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    conds = []
+    if tk % block_k:
+        conds.append(col < tk - kj * block_k)
+    if rows and tq % block_q:
+        conds.append(row < tq - qi * block_q)
     if causal:
-        mask = mask & (k_pos <= q_pos)
+        # k_pos <= q_pos  <=>  col - row <= qi*block_q - kj*block_k
+        ahead, diag = col - row, qi * block_q - kj * block_k
+        conds.append(ahead <= diag)
         if window is not None:
-            mask = mask & (k_pos > q_pos - window)
-    return mask
+            # key positions in (q - window, q]
+            conds.append(ahead > diag - window)
+    return functools.reduce(operator.and_, conds) if conds else None
 
 
 def _flash_block_live(qi, kj, *, causal, window, block_q, block_k):
     """Block-level liveness: does tile (qi, kj) contain ANY unmasked pair?
-    Shared by the fwd/dq kernels (k minor) and the dkv kernel (q minor)."""
+    Shared by the fwd/dq kernels (k minor) and the dkv kernel (q minor).
+    ``None`` when nothing can be dead (non-causal)."""
     if not causal:
         return None
     live = kj * block_k <= qi * block_q + block_q - 1
@@ -242,47 +271,167 @@ def _flash_block_live(qi, kj, *, causal, window, block_q, block_k):
     return live
 
 
+def _flash_tile_interior(qi, kj, *, causal, window, block_q, block_k, tq,
+                         tk):
+    """Is EVERY pair of tile (qi, kj) unmasked?  The tile then lies wholly
+    on or below the diagonal, wholly inside the window where one is set,
+    and holds no padded row or column, so its body needs no mask.  With
+    ``_flash_block_live`` this is the one definition of the three tile
+    classes: *dead* (not live), *interior*, and *edge* (live, not
+    interior).  Works on grid indices in a kernel and on ints in
+    ``flash_tile_classes``; the Python ``True`` when no tile of the call
+    can need a mask."""
+    conds = []
+    if tq % block_q:
+        conds.append((qi + 1) * block_q <= tq)
+    if tk % block_k:
+        conds.append((kj + 1) * block_k <= tk)
+    if causal:
+        conds.append(kj * block_k + block_k - 1 <= qi * block_q)
+        if window is not None:
+            conds.append(kj * block_k > qi * block_q + block_q - 1 - window)
+    return functools.reduce(operator.and_, conds) if conds else True
+
+
+def _flash_by_tile_class(step, qi, kj, *, causal, window, block_q, block_k,
+                         tq, tk):
+    """Run ``step(masked)`` as tile (qi, kj)'s class needs: not at all on a
+    dead tile, mask-free on an interior one, masked on an edge.  The masked
+    body is the fallback, so a predicate that is too strict costs speed
+    and never correctness."""
+    live = _flash_block_live(qi, kj, causal=causal, window=window,
+                             block_q=block_q, block_k=block_k)
+    interior = _flash_tile_interior(qi, kj, causal=causal, window=window,
+                                    block_q=block_q, block_k=block_k,
+                                    tq=tq, tk=tk)
+    if interior is True:
+        step(False)
+        return
+    edge = jnp.logical_not(interior)
+    if live is not None:
+        edge &= live
+    pl.when(interior)(functools.partial(step, False))
+    pl.when(edge)(functools.partial(step, True))
+
+
+def _flash_live_k(qi, kj, *, causal, window, block_q, block_k, n_kb):
+    """``kj`` clamped into q tile ``qi``'s live K range, for the index maps
+    of the operands that sweep k (K and V in ``flash_fwd`` and
+    ``flash_bwd_dq``): a dead step then names the block its live
+    neighbour holds, and the pipeline issues no DMA for it."""
+    if not causal:
+        return kj
+    hi = jnp.minimum((qi * block_q + block_q - 1) // block_k, n_kb - 1)
+    if window is not None:
+        kj = jnp.maximum(
+            kj, jnp.maximum(qi * block_q - window + 1, 0) // block_k)
+    return jnp.minimum(kj, hi)
+
+
+def _flash_live_q(kj, qi, *, causal, window, block_q, block_k, n_qb):
+    """``qi`` clamped into k tile ``kj``'s live Q range: ``_flash_live_k``
+    for the q-minor sweep of ``flash_bwd_dkv`` (Q, dO, lse, delta)."""
+    if not causal:
+        return qi
+    qi = jnp.maximum(qi, kj * block_k // block_q)
+    hi = n_qb - 1
+    if window is not None:
+        hi = jnp.minimum(
+            (kj * block_k + block_k + window - 2) // block_q, hi)
+    return jnp.minimum(qi, hi)
+
+
+def flash_tile_classes(tq, tk, block_q=256, block_k=1024, causal=False,
+                       window=None):
+    """How many (q-tile, k-tile) grid steps of one head are ``dead``,
+    ``edge`` and ``interior`` (``flash_attention``'s docstring) at these
+    lengths and requested blocks: counted with the kernels' own
+    predicates, and what ``vt_flash_tiles`` reports."""
+    block_q, block_k, tq_p, tk_p = _flash_blocks(tq, tk, block_q, block_k)
+    geom = dict(causal=causal, window=window, block_q=block_q,
+                block_k=block_k)
+    # the predicates are plain arithmetic: one evaluation over the grid
+    qi, kj = np.ogrid[:tq_p // block_q, :tk_p // block_k]
+    grid = np.broadcast(qi, kj).shape
+    live = _flash_block_live(qi, kj, **geom)
+    live = np.broadcast_to(True if live is None else live, grid)
+    interior = np.broadcast_to(
+        _flash_tile_interior(qi, kj, tq=tq, tk=tk, **geom), grid)
+    return {"dead": int((~live).sum()),
+            "edge": int((live & ~interior).sum()),
+            "interior": int(interior.sum())}
+
+
+def _note_tile_classes(kernels, tq, tk, block_q, block_k, causal, window):
+    """Set ``vt_flash_tiles{kernel, class}`` while a call is traced: how
+    often the per-class bodies engage at the shapes the program runs."""
+    from ..runtime.metrics import registry
+    gauge = registry().gauge(
+        "vt_flash_tiles",
+        "flash attention grid steps a head by tile class, as last traced",
+        labels=("kernel", "class"))
+    counts = flash_tile_classes(tq, tk, block_q, block_k, causal, window)
+    for kernel in kernels:
+        for cls, n in counts.items():
+            gauge.labels(**{"kernel": kernel, "class": cls}).set(n)
+
+
+def _flash_scale(scale, D):
+    """(scale, folded): a power-of-two scale commutes with every rounding
+    of the score products, so it is applied once to Q in the layout pass
+    and leaves the (block_q, block_k) score tile; any other scale stays
+    on the scores."""
+    scale = D ** -0.5 if scale is None else float(scale)
+    return scale, scale > 0 and math.frexp(scale)[0] == 0.5
+
+
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, acc_ref, *, scale, causal, window,
-                         block_q, block_k, tq, tk, n_kb):
+                         dq_ref, acc_ref, *, scale, out_scale, causal,
+                         window, block_q, block_k, tq, tk, n_kb):
     """Grid = (BH, n_q_blocks, n_k_blocks), k minor; dQ accumulates in
     scratch across the k sweep (two-pass recompute backward: S and P are
-    rebuilt from Q/K and the saved row logsumexp, never materialized)."""
+    rebuilt from Q/K and the saved row logsumexp, never materialized).
+    With the scale folded into Q (``scale`` None) the second ``* scale``
+    moves from the score tile to the accumulator (``out_scale``)."""
     qi, kj = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kj == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    def _step():
+    def _step(masked):
         q, k_blk, v_blk, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        mask = _flash_bwd_mask(qi, kj, causal=causal, window=window,
-                               block_q=block_q, block_k=block_k,
-                               tq=tq, tk=tk)
-        # lse/delta blocks are (block_q, 1) column vectors — broadcast
-        # against the (block_q, block_k) score tile directly.
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0]), 0.0)
+            preferred_element_type=jnp.float32)
+        if scale is not None:
+            s = s * scale
+        # lse/delta blocks are lane-replicated row state (_lanes)
+        p = jnp.exp(s - _lanes(lse_ref[0], block_k))
+        if masked:
+            p = jnp.where(
+                _flash_tile_mask(qi, kj, causal=causal, window=window,
+                                 block_q=block_q, block_k=block_k,
+                                 tq=tq, tk=tk, rows=True), p, 0.0)
         dp = jax.lax.dot_general(
             do, v_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0]) * scale
+        ds = p * (dp - _lanes(delta_ref[0], block_k))
+        if scale is not None:
+            ds = ds * scale
         acc_ref[:] += jax.lax.dot_general(
             ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = _flash_block_live(qi, kj, causal=causal, window=window,
-                             block_q=block_q, block_k=block_k)
-    if live is None:
-        _step()
-    else:
-        pl.when(live)(_step)
+    _flash_by_tile_class(_step, qi, kj, causal=causal, window=window,
+                         block_q=block_q, block_k=block_k, tq=tq, tk=tk)
 
     @pl.when(kj == n_kb - 1)
     def _finalize():
-        dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
+        acc = acc_ref[:]
+        if out_scale is not None:
+            acc = acc * out_scale
+        dq_ref[0] = acc.astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -291,7 +440,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     """Grid = (B*H_kv, n_k_blocks, n_qsweep), q minor; dK/dV accumulate in
     scratch across the q sweep.  With GQA, n_qsweep = n_q_blocks * G: the
     minor axis enumerates (group member g, q block qi) — every q head of
-    the group folds into the same kv-head accumulator."""
+    the group folds into the same kv-head accumulator.  With the scale
+    folded into Q (``scale`` None) dK's product with that Q carries it."""
     kj, i = pl.program_id(1), pl.program_id(2)
     qi = i % n_qb
 
@@ -300,32 +450,34 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    def _step():
+    def _step(masked):
         q, k_blk, v_blk, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
         s = jax.lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        mask = _flash_bwd_mask(qi, kj, causal=causal, window=window,
-                               block_q=block_q, block_k=block_k,
-                               tq=tq, tk=tk)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0]), 0.0)
+            preferred_element_type=jnp.float32)
+        if scale is not None:
+            s = s * scale
+        p = jnp.exp(s - _lanes(lse_ref[0], block_k))
+        if masked:
+            p = jnp.where(
+                _flash_tile_mask(qi, kj, causal=causal, window=window,
+                                 block_q=block_q, block_k=block_k,
+                                 tq=tq, tk=tk, rows=True), p, 0.0)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
             do, v_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0]) * scale
+        ds = p * (dp - _lanes(delta_ref[0], block_k))
+        if scale is not None:
+            ds = ds * scale
         dk_acc[:] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = _flash_block_live(qi, kj, causal=causal, window=window,
-                             block_q=block_q, block_k=block_k)
-    if live is None:
-        _step()
-    else:
-        pl.when(live)(_step)
+    _flash_by_tile_class(_step, qi, kj, causal=causal, window=window,
+                         block_q=block_q, block_k=block_k, tq=tq, tk=tk)
 
     @pl.when(i == n_qsweep - 1)
     def _finalize():
@@ -339,36 +491,42 @@ def _flash_bwd(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
     Tk = k.shape[1]
     H_kv = k.shape[2]
     G = _gqa_groups(q, k)
-    scale_ = scale if scale is not None else D ** -0.5
+    scale_, folded = _flash_scale(scale, D)
+    _note_tile_classes(("flash_bwd_dq", "flash_bwd_dkv"), Tq, Tk, block_q,
+                       block_k, causal, window)
     block_q, block_k, tq_p, tk_p = _flash_blocks(Tq, Tk, block_q, block_k)
     n_qb, n_kb = tq_p // block_q, tk_p // block_k
 
-    qm = _flash_layout(q, Tq, tq_p)
+    qm = _flash_layout(q * scale_ if folded else q, Tq, tq_p)
     km = _flash_layout(k, Tk, tk_p)
     vm = _flash_layout(v, Tk, tk_p)
     dom = _flash_layout(g, Tq, tq_p)
     om = _flash_layout(out, Tq, tq_p)
     # delta_i = rowsum(dO * O) — cheap elementwise+reduce, left to XLA;
-    # shaped (BH, T, 1) to match the kernels' column-vector blocks.
-    delta = jnp.sum(dom.astype(jnp.float32) * om.astype(jnp.float32),
-                    axis=-1, keepdims=True)
+    # lane-replicated like lse (_lanes).
+    delta = jnp.broadcast_to(
+        jnp.sum(dom.astype(jnp.float32) * om.astype(jnp.float32),
+                axis=-1, keepdims=True), lse.shape)
 
     itp = _interpret(interpret)
-    common = dict(scale=scale_, causal=causal, window=window,
-                  block_q=block_q, block_k=block_k, tq=Tq, tk=Tk)
+    geom = dict(causal=causal, window=window, block_q=block_q,
+                block_k=block_k)
+    common = dict(scale=None if folded else scale_, tq=Tq, tk=Tk, **geom)
     kv_row = _kv_row_map(H, H_kv, G)
+    live_k = functools.partial(_flash_live_k, n_kb=n_kb, **geom)
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, n_kb=n_kb, **common),
+        functools.partial(_flash_bwd_dq_kernel, n_kb=n_kb,
+                          out_scale=scale_ if folded else None, **common),
         grid=(B * H, n_qb, n_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, D),
-                         lambda b, i, j: (kv_row(b), j, 0)),
+                         lambda b, i, j: (kv_row(b), live_k(i, j), 0)),
             pl.BlockSpec((1, block_k, D),
-                         lambda b, i, j: (kv_row(b), j, 0)),
+                         lambda b, i, j: (kv_row(b), live_k(i, j), 0)),
             pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, tq_p, D), q.dtype),
@@ -382,24 +540,31 @@ def _flash_bwd(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
     # dK/dV: grid over kv heads; the minor sweep covers (group member g,
     # q block) so all G q heads of a group fold into one accumulator.
     # q-side rows for kv row b and sweep index i: head (b % H_kv)*G + g.
-    q_row = (lambda b, i: (b, i)) if G == 1 else \
-        (lambda b, i: ((b // H_kv) * H + (b % H_kv) * G + i // n_qb,
-                       i % n_qb))
+    # The q block of a dead step is clamped into k tile j's live range
+    # (within its group member), so it is not fetched.
+    live_q = functools.partial(_flash_live_q, n_qb=n_qb, **geom)
+    if G == 1:
+        def q_row(b, j, i):
+            return b, live_q(j, i)
+    else:
+        def q_row(b, j, i):
+            return ((b // H_kv) * H + (b % H_kv) * G + i // n_qb,
+                    live_q(j, i % n_qb))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, n_qb=n_qb,
                           n_qsweep=n_qb * G, **common),
         grid=(B * H_kv, n_kb, n_qb * G),
         in_specs=[
             pl.BlockSpec((1, block_q, D),
-                         lambda b, j, i: (*q_row(b, i), 0)),
+                         lambda b, j, i: (*q_row(b, j, i), 0)),
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_q, D),
-                         lambda b, j, i: (*q_row(b, i), 0)),
-            pl.BlockSpec((1, block_q, 1),
-                         lambda b, j, i: (*q_row(b, i), 0)),
-            pl.BlockSpec((1, block_q, 1),
-                         lambda b, j, i: (*q_row(b, i), 0)),
+                         lambda b, j, i: (*q_row(b, j, i), 0)),
+            pl.BlockSpec((1, block_q, _LANES),
+                         lambda b, j, i: (*q_row(b, j, i), 0)),
+            pl.BlockSpec((1, block_q, _LANES),
+                         lambda b, j, i: (*q_row(b, j, i), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
@@ -440,9 +605,29 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
     attention matrix is never materialized in either direction.
 
     ``window=W`` (requires ``causal=True``) restricts each query to keys
-    in ``(q - W, q]`` — sliding-window local attention. Fully-out-of-
-    window K blocks are skipped in all three kernels, so fwd+bwd cost is
-    O(T·W) instead of O(T²/2)."""
+    in ``(q - W, q]`` — sliding-window local attention.
+
+    **Tile classes.**  Every (block_q, block_k) grid step of the three
+    kernels is classed from its grid indices and the call's static sizes
+    (``_flash_block_live``, ``_flash_tile_interior``), and does only what
+    its class needs:
+
+    * *dead* — no unmasked pair (above the diagonal, or wholly before the
+      window): neither computed nor fetched.  The body is skipped, and
+      the index maps clamp the sweeping operand's block index into the
+      live range, so consecutive dead steps name the block already
+      resident and no DMA is issued.  Cost is O(T·W) with a window
+      instead of O(T²/2).
+    * *interior* — every pair valid (wholly on or below the diagonal,
+      inside the window, no padded row or column): a body without iota,
+      compare or select.
+    * *edge* — the rest (diagonal tiles, window borders, padded tails):
+      the masked body, which is correct for any tile.
+
+    ``flash_tile_classes`` counts them for a call; ``vt_flash_tiles``
+    reports the counts of the last traced call.  A power-of-two ``scale``
+    (D = 64: 0.125) is applied once to Q outside the kernels, where it is
+    exact, instead of to every score tile."""
     return _flash_fwd(q, k, v, causal=causal, scale=scale, block_q=block_q,
                       block_k=block_k, interpret=interpret, window=window)
 
@@ -605,8 +790,7 @@ def paged_attention_decode(q, k_pool, v_pool, ptab, pos, *, page_size,
 # Fused dropout with in-kernel counter-based RNG
 # ---------------------------------------------------------------------------
 
-import numpy as np  # noqa: E402  (np scalars stay literals under tracing)
-
+# np scalars stay literals under tracing
 _GOLDEN = np.uint32(0x9E3779B9)
 _MIX1 = np.uint32(0x85EBCA6B)
 _MIX2 = np.uint32(0xC2B2AE35)

@@ -121,10 +121,14 @@ class MultiHeadAttention(Forward):
 
         # flash candidates: the global on-chip default plus per-shape
         # alternatives; dedupe by the kernel's EFFECTIVE clamped blocks
-        # so tiny T doesn't measure the same program four times
+        # so tiny T doesn't measure the same program four times.  Four
+        # shapes, because a cold start's set-up is this sweep; two of
+        # them large, because at long T the backward kernels are bound
+        # by the MXU's half-filled D = 64 passes and fewer, larger steps
+        # win (PERF.md section 6, PR 28)
         from ..ops.pallas_kernels import _flash_blocks
         cand_blocks, seen = [], set()
-        for bq, bk in ((256, 1024), (512, 512), (256, 512), (128, 1024)):
+        for bq, bk in ((256, 1024), (512, 512), (1024, 512), (1024, 1024)):
             eff = _flash_blocks(T, T, bq, bk)
             if eff not in seen:
                 seen.add(eff)
@@ -139,17 +143,10 @@ class MultiHeadAttention(Forward):
         args = [jnp.asarray(rng.standard_normal(s), dt) for s in shapes]
 
         def run(use_flash, blocks=None):
-            def f(q, k, v):
-                # value_and_grad: the primal keeps the forward alive
-                # under DCE, timing the full training cost
-                return jax.value_and_grad(
-                    lambda q, k, v: jnp.sum(blockwise_attention(
-                        q, k, v, block_size=self.block_size,
-                        causal=self.causal, window=self.window,
-                        use_flash=use_flash,
-                        flash_blocks=blocks).astype(jnp.float32)),
-                    argnums=(0, 1, 2))(q, k, v)
-            return f
+            return self._probe(functools.partial(
+                blockwise_attention, block_size=self.block_size,
+                causal=self.causal, window=self.window,
+                use_flash=use_flash, flash_blocks=blocks))
 
         candidates = {f"flash_{bq}x{bk}": run(True, (bq, bk))
                       for bq, bk in cand_blocks}
@@ -158,6 +155,23 @@ class MultiHeadAttention(Forward):
                                default=f"flash_{cand_blocks[0][0]}"
                                        f"x{cand_blocks[0][1]}")
         self._resolved_flash, self._resolved_blocks = parse(winner)
+
+    @staticmethod
+    def _probe(attend):
+        """What ``prepare`` times for one candidate: forward and backward
+        of ``attend(q, k, v)``, as a training step runs them.
+        ``autotune.measure`` chains its repetitions through the first
+        output alone, and whatever that output does not need is removed
+        from every repetition but the last: a bare ``value_and_grad``
+        (primal first) loses the two backward kernels that way.  So the
+        one output needs the primal and all three gradients."""
+        def f(q, k, v):
+            loss, grads = jax.value_and_grad(
+                lambda q, k, v: jnp.sum(attend(q, k, v).astype(jnp.float32)),
+                argnums=(0, 1, 2))(q, k, v)
+            return loss + 1e-30 * sum(
+                jnp.sum(g.astype(jnp.float32)) for g in grads)
+        return f
 
     def output_spec(self, in_specs: Sequence[Spec]) -> Spec:
         return in_specs[0]
