@@ -125,8 +125,35 @@ def _gather(n, f, m):
     return case
 
 
+def _grouped(rows, groups, k, n, grad=True):
+    """The ragged grouped product of the routed experts: a buffer of
+    ``rows`` (every route may land here) in tiles of 128."""
+    def case(sh):
+        f = lambda lhs, rhs, sizes: pk.grouped_matmul(  # noqa: E731
+            lhs, rhs, sizes, 128, 512)
+        if grad:
+            f = lambda lhs, rhs, sizes, _f=f: _value_and_grads(  # noqa: E731
+                lambda a, b: _f(a, b, sizes))(lhs, rhs)
+        return (f, [_sds((rows, k), "bfloat16", sh),
+                    _sds((groups, k, n), "bfloat16", sh),
+                    _sds((groups,), "int32", sh)], 3 if grad else 1)
+    return case
+
+
+def _flash_gqa_d128(window):
+    return _flash(1, 4096, 32, 4, 128, window=window)
+
+
 #: name -> builder(sharding) -> (function, argument shapes, kernels expected)
 ONE_CHIP = {
+    "grouped_matmul_fwd_up_16x2048x1024": _grouped(
+        34816, 16, 2048, 1024, grad=False),
+    "grouped_matmul_fwd_bwd_up_16x2048x1024": _grouped(
+        34816, 16, 2048, 1024),
+    "grouped_matmul_fwd_bwd_down_16x1024x2048": _grouped(
+        34816, 16, 1024, 2048),
+    "flash_fwd_bwd_t4096_h32_kv4_d128_window2048": _flash_gqa_d128(2048),
+    "flash_fwd_bwd_t4096_h32_kv4_d128_full": _flash_gqa_d128(None),
     "flash_fwd_b16_t2048": _flash(16, 2048, 8, 8, 64, grad=False),
     "flash_fwd_bwd_b16_t2048": _flash(16, 2048, 8, 8, 64),
     "flash_fwd_bwd_t4096_d128": _flash(1, 4096, 8, 8, 128),

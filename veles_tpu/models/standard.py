@@ -8,7 +8,8 @@ SURVEY.md §2.10). Here gradient units don't exist (autodiff), so the factory
 wires loader→forwards→evaluator and pairs with a Trainer.
 
 Layer dicts: ``{"type": "conv_relu", "n_kernels": 96, "kx": 11, ...}``;
-``type`` resolves through LAYER_TYPES. The per-layer ``hyperparams`` key
+``type`` resolves through LAYER_TYPES; ``inputs`` (a list of unit names or
+batch keys) replaces the default of the layer before. The per-layer ``hyperparams`` key
 lands in the optimizer's per-unit table (per-layer lr/momentum/l2 —
 reference item docs manualrst_veles_algorithms.rst:166).
 """
@@ -25,6 +26,7 @@ LAYER_TYPES = {
     # parallelism-aware units (sp/pp/ep as config-constructible features)
     "attention": parallel_nn.MultiHeadAttention,
     "moe": parallel_nn.MoEFFN,
+    "routed_experts": parallel_nn.RoutedExpertsFFN,
     "pipeline_stack": parallel_nn.PipelineStack,
     # recurrent family (reference: Znicz RNN/LSTM "created but not
     # tested", manualrst_veles_algorithms.rst:115-134 — here tested)
@@ -52,6 +54,8 @@ LAYER_TYPES = {
     "embedding": nn.Embedding,
     "ffn": nn.FFN,
     "layer_norm": nn.LayerNorm,
+    "rms_norm": nn.RMSNorm,
+    "gated_mlp": nn.GatedMLP,
     "seq_last": nn.SeqLast,
 }
 
@@ -59,7 +63,8 @@ LAYER_TYPES = {
 # layer-type prefixes that take a compute_dtype kwarg (the MXU-bf16
 # switch); shared with PipelineStack's stage-config builder
 COMPUTE_DTYPE_TYPES = ("all2all", "softmax", "conv", "deconv", "rnn",
-                       "gru", "lstm", "attention", "ffn")
+                       "gru", "lstm", "attention", "ffn", "gated_mlp",
+                       "routed_experts")
 
 
 def build_workflow(name: str, layers: Sequence[dict], *,
@@ -78,6 +83,10 @@ def build_workflow(name: str, layers: Sequence[dict], *,
         ltype = spec.pop("type")
         spec.pop("hyperparams", None)
         lname = spec.pop("name", f"l{i}_{ltype}")
+        # a layer follows the one before it unless it names its inputs
+        # (unit names or batch keys): a second input is how a block's
+        # residual stream reaches the unit that adds to it
+        inputs = tuple(spec.pop("inputs", (prev,)))
         # activation rematerialization knob: the training forward wraps
         # this unit in jax.checkpoint, recomputing its internals in the
         # backward instead of taping them (HBM-for-FLOPs trade — the
@@ -94,7 +103,7 @@ def build_workflow(name: str, layers: Sequence[dict], *,
             # pipeline_stack forwards compute_dtype into its stage
             # sublists (only to unit types that take it)
             spec.setdefault("compute_dtype", compute_dtype)
-        unit = klass(name=lname, inputs=(prev,), **spec)
+        unit = klass(name=lname, inputs=inputs, **spec)
         unit.remat = remat
         wf.add(unit)
         prev = lname

@@ -50,6 +50,7 @@ ACTIVATIONS = {
     "raw_tanh": jnp.tanh,
     "sigmoid": sigmoid,
     "sincos": sincos,
+    "silu": jax.nn.silu,
 }
 
 

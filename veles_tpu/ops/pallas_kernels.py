@@ -653,6 +653,186 @@ flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 # ---------------------------------------------------------------------------
+# Grouped matrix products over ragged row groups (dropless experts)
+# ---------------------------------------------------------------------------
+#
+# Rows arrive sorted by group, each group padded with zero rows to whole
+# tiles of ``block_rows`` (``group_tiles``), so a tile of rows belongs to
+# one group and the group's matrix is picked by a scalar-prefetched table
+# in the BlockSpec index maps.  The grid's row dimension is dynamic: it is
+# the number of tiles the groups really fill, so rows behind the last
+# group cost no step, no fetch and no product (their output rows are left
+# unwritten: callers never read them), and an empty group costs nothing.
+
+def group_tiles(group_sizes, block_rows: int):
+    """``(tiles (G,), row_start (G,))`` of ``group_sizes`` (G,) rows
+    sorted by group: each group starts at a tile boundary and fills
+    ``ceil(size / block_rows)`` tiles."""
+    tiles = (group_sizes + block_rows - 1) // block_rows
+    row_start = (jnp.cumsum(tiles) - tiles) * block_rows
+    return tiles.astype(jnp.int32), row_start.astype(jnp.int32)
+
+
+def _tile_groups(group_sizes, n_rows: int, block_rows: int):
+    """``(n_tiles (), tile_group (n_rows // block_rows,))``: the tiles in
+    use and each tile's group (tiles past ``n_tiles`` name the last)."""
+    tiles, _ = group_tiles(group_sizes, block_rows)
+    tile_end = jnp.cumsum(tiles)
+    t = jnp.arange(n_rows // block_rows, dtype=jnp.int32)
+    tile_group = jnp.minimum(jnp.searchsorted(tile_end, t, side="right"),
+                             group_sizes.shape[0] - 1)
+    return tile_end[-1].astype(jnp.int32), tile_group.astype(jnp.int32)
+
+
+def _gmm_kernel(tile_group_ref, lhs_ref, rhs_ref, out_ref, *, transpose_rhs):
+    del tile_group_ref
+    dims = (((1,), (1,)), ((), ())) if transpose_rhs \
+        else (((1,), (0,)), ((), ()))
+    out_ref[...] = jax.lax.dot_general(
+        lhs_ref[...], rhs_ref[0], dims,
+        preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+
+def _gmm(lhs, rhs, n_tiles, tile_group, *, block_rows, transpose_rhs,
+         interpret):
+    """``out[rows of tile t] = lhs[rows of tile t] @ rhs[tile_group[t]]``
+    (``@ rhs[...].T`` with ``transpose_rhs``) for the first ``n_tiles``
+    tiles.  One step a tile; a group's whole matrix is one block, fetched
+    once while the tiles before it compute."""
+    M, K = lhs.shape
+    N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose_rhs=transpose_rhs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((block_rows, K), lambda t, g: (t, 0)),
+                pl.BlockSpec((1,) + rhs.shape[1:],
+                             lambda t, g: (g[t], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((block_rows, N), lambda t, g: (t, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((M, N), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_GMM_VMEM),
+        interpret=_interpret(interpret),
+        name="grouped_matmul_t" if transpose_rhs else "grouped_matmul",
+    )(tile_group, lhs, rhs)
+
+
+def _gmm_dw_kernel(step_group_ref, step_tile_ref, step_flags_ref, lhs_ref,
+                   g_ref, out_ref, acc_ref):
+    del step_group_ref, step_tile_ref
+    flags = step_flags_ref[pl.program_id(1)]
+
+    @pl.when(flags & 1 != 0)        # the group's first step
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(flags & 4 != 0)        # the group has rows
+    def _():
+        acc_ref[...] += jax.lax.dot_general(
+            lhs_ref[...], g_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(flags & 2 != 0)        # the group's last step
+    def _():
+        out_ref[0] = acc_ref[...].astype(out_ref.dtype)
+
+
+def _gmm_dw(lhs, g, group_sizes, *, block_rows, block_cols, interpret):
+    """``out[e] = lhs[rows of e].T @ g[rows of e]`` (G, K, N): the
+    products' gradient to the groups' matrices.  A group's tiles are
+    consecutive steps that add into one float32 block; a group with no
+    rows gets one step that writes zeros and multiplies nothing."""
+    M, K = lhs.shape
+    N = g.shape[1]
+    G = group_sizes.shape[0]
+    n_row_tiles = M // block_rows
+    tiles, _ = group_tiles(group_sizes, block_rows)
+    steps = jnp.maximum(tiles, 1)
+    step_end = jnp.cumsum(steps)
+    step_start = step_end - steps
+    tile_start = jnp.cumsum(tiles) - tiles
+    s = jnp.arange(n_row_tiles + G, dtype=jnp.int32)
+    step_group = jnp.minimum(
+        jnp.searchsorted(step_end, s, side="right"), G - 1).astype(jnp.int32)
+    step_tile = jnp.clip(tile_start[step_group] + s - step_start[step_group],
+                         0, n_row_tiles - 1).astype(jnp.int32)
+    flags = ((s == step_start[step_group]) * 1
+             + (s == step_end[step_group] - 1) * 2
+             + (group_sizes[step_group] > 0) * 4).astype(jnp.int32)
+    block_cols = min(block_cols, N)
+    return pl.pallas_call(
+        _gmm_dw_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(N // block_cols, step_end[-1].astype(jnp.int32)),
+            in_specs=[
+                pl.BlockSpec((block_rows, K),
+                             lambda j, s, sg, st, sf: (st[s], 0)),
+                pl.BlockSpec((block_rows, block_cols),
+                             lambda j, s, sg, st, sf: (st[s], j)),
+            ],
+            out_specs=pl.BlockSpec((1, K, block_cols),
+                                   lambda j, s, sg, st, sf: (sg[s], 0, j)),
+            scratch_shapes=[pltpu.VMEM((K, block_cols), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((G, K, N), lhs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_GMM_VMEM),
+        interpret=_interpret(interpret),
+        name="grouped_matmul_dw",
+    )(step_group, step_tile, flags, lhs, g)
+
+
+#: a group's whole matrix is one block (4 MB at 2048 x 1024 bf16), double
+#: buffered beside the row tiles: more than the 16 MB a kernel gets unasked
+_GMM_VMEM = 64 * 1024 * 1024
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def grouped_matmul(lhs, rhs, group_sizes, block_rows=128, block_cols=512,
+                   interpret=None):
+    """Ragged grouped product: ``lhs`` (M, K) holds its rows sorted by
+    group in the tile-aligned layout of ``group_tiles`` (zero rows pad a
+    group to whole tiles of ``block_rows``; M is a multiple of it),
+    ``rhs`` is (G, K, N), ``group_sizes`` (G,) int32 the rows of each
+    group, zero included.  Returns (M, N): row r of group e is ``lhs[r] @
+    rhs[e]``; rows past the last group's tiles are not written and hold
+    anything.  Forward (``grouped_matmul``), the gradient to the rows
+    (``grouped_matmul_t``) and to the matrices (``grouped_matmul_dw``,
+    ``block_cols`` wide a step) are three Pallas kernels; the rows' cost
+    follows ``group_sizes``, not M."""
+    n_tiles, tile_group = _tile_groups(group_sizes, lhs.shape[0], block_rows)
+    return _gmm(lhs, rhs, n_tiles, tile_group, block_rows=block_rows,
+                transpose_rhs=False, interpret=interpret)
+
+
+def _gmm_vjp_fwd(lhs, rhs, group_sizes, block_rows, block_cols, interpret):
+    out = grouped_matmul(lhs, rhs, group_sizes, block_rows, block_cols,
+                         interpret)
+    return out, (lhs, rhs, group_sizes)
+
+
+def _gmm_vjp_bwd(block_rows, block_cols, interpret, res, g):
+    lhs, rhs, group_sizes = res
+    n_tiles, tile_group = _tile_groups(group_sizes, lhs.shape[0], block_rows)
+    g = g.astype(lhs.dtype)
+    dlhs = _gmm(g, rhs, n_tiles, tile_group, block_rows=block_rows,
+                transpose_rhs=True, interpret=interpret)
+    drhs = _gmm_dw(lhs, g, group_sizes, block_rows=block_rows,
+                   block_cols=block_cols, interpret=interpret)
+    return dlhs, drhs.astype(rhs.dtype), None
+
+
+grouped_matmul.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
 # Paged-attention decode (fused page gather + online softmax)
 # ---------------------------------------------------------------------------
 

@@ -17,6 +17,13 @@ overflow drops the weakest routes):
 * ``"dense"`` (round 2): one-hot einsum dispatch — kept because its
   dispatch/combine einsums are what GSPMD lowers to all_to_all over ICI
   when the expert axis is sharded, and as the cross-check reference.
+
+Beside the capacity path, ``routed_experts_apply`` is the dropless one:
+no capacity and no drops, an expert's rows are whatever the router gives
+it (zero included), the layer is told which experts of the router's
+width it holds and computes their part of the result, and the experts are
+gated (three matrices).  Its products are ragged grouped products sized
+by the routes (``ops/pallas_kernels.grouped_matmul``).
 """
 
 from __future__ import annotations
@@ -260,6 +267,256 @@ def moe_apply_manual(params: dict, x: jnp.ndarray, *, axis_name: str,
     y = _combine_slots(ye, slot_idx, keep, gates, x.dtype)
     aux = _switch_aux(topi, probs, axis_name=axis_name)
     return y, aux
+
+
+# -- dropless routed experts --------------------------------------------------
+
+def route_scores(x, router, *, top_k: int, bias=None,
+                 route_norm: bool = True, route_scale: float = 1.0):
+    """(weights (T, K) float32, expert ids (T, K)): sigmoid scores of all
+    the router's experts in float32 (the product too, whatever the matmul
+    default), the ``top_k`` of ``scores + bias`` (``bias`` gets no
+    gradient), and their scores normalised to sum to one if
+    ``route_norm``, times ``route_scale``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    choose = scores if bias is None \
+        else scores + jax.lax.stop_gradient(bias)
+    _, topi = jax.lax.top_k(jax.lax.stop_gradient(choose), top_k)
+    w = jnp.take_along_axis(scores, topi, axis=-1)
+    if route_norm:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return w * route_scale, topi
+
+
+#: ``dispatch_plan``'s row of a route to an expert that is not held
+NO_ROW = 2 ** 30
+
+
+def dispatch_plan(topi, n_held: int, offset: int, block_rows: int):
+    """Where each route goes among the rows sorted by expert, each
+    expert's rows starting at a multiple of ``block_rows``.
+
+    Routes to experts ``offset .. offset + n_held`` are sorted by expert
+    (stable: by token within an expert).  Returns ``(sizes (n_held,),
+    row_of_route (T, K), rows_needed ())``: the routes each held expert
+    got, each route's row (``NO_ROW`` for an expert not held), and the
+    rows up to the last expert's last tile."""
+    T, K = topi.shape
+    R = T * K
+    local = topi.reshape(-1) - offset
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sorted_key = key[order]
+    sizes_all = jnp.zeros(n_held + 1, jnp.int32).at[key].add(1)
+    sizes = sizes_all[:n_held]
+    from ..ops.pallas_kernels import group_tiles
+    tiles, row_start = group_tiles(sizes, block_rows)
+    first = jnp.cumsum(sizes_all) - sizes_all
+    rank = jnp.arange(R, dtype=jnp.int32) - first[sorted_key]
+    row_sorted = jnp.where(
+        sorted_key < n_held,
+        jnp.concatenate([row_start, jnp.zeros(1, jnp.int32)])[sorted_key]
+        + rank, NO_ROW)
+    row_of_route = jnp.zeros(R, jnp.int32).at[order].set(row_sorted)
+    return sizes, row_of_route.reshape(T, K), jnp.sum(tiles) * block_rows
+
+
+def buffer_rows(n_tokens: int, top_k: int, n_held: int, n_experts: int,
+                block_rows: int):
+    """(small, large) row counts of the buffer the sorted rows go
+    through.  ``large`` holds every route that can land here, so nothing
+    is ever dropped: T * min(K, n_held) rows and a tile of padding an
+    expert.  ``small`` holds three times what uniform routing sends here
+    (a router that nothing balances sends a layer half or one and a half
+    times its share from the first step on); it is what a step uses when
+    its routes fit, since gathers and elementwise passes cost the
+    buffer's rows, not the routed ones."""
+    pad = n_held * block_rows
+    large = _round_up(n_tokens * min(top_k, n_held), block_rows) + pad
+    small = _round_up(3 * n_tokens * top_k * n_held // n_experts,
+                      block_rows) + pad
+    return min(small, large), large
+
+
+@jax.custom_vjp
+def take_rows(x, src, back):
+    """``out[i] = x[src[i]]``, a zero row where ``src[i] == len(x)``, for
+    a ``src`` that takes each row of ``x`` at most ``back.shape[1]``
+    times: ``back[r]`` are the ``i`` with ``src[i] == r``, filled up with
+    ``len(src)``.  Knowing ``back``, the gradient is a gather too
+    (``dx[r] = sum of g[back[r]]``) where autodiff would scatter-add
+    row by row, which on the TPU cost ten times the gather."""
+    return _gather_or_zero(x, src)
+
+
+def _gather_or_zero(x, idx):
+    n = x.shape[0]
+    rows = jnp.take(x, jnp.minimum(idx, n - 1), axis=0)
+    return jnp.where((idx < n)[..., None], rows, jnp.zeros((), x.dtype))
+
+
+def _take_rows_fwd(x, src, back):
+    return _gather_or_zero(x, src), (src, back)
+
+
+def _take_rows_bwd(res, g):
+    src, back = res
+    dx = _gather_or_zero(g, back)               # (n, times, D)
+    return jnp.sum(dx.astype(jnp.float32), axis=1).astype(g.dtype), None, \
+        None
+
+
+take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def grouped_products(lhs, rhs, sizes, *, block_rows: int, use_pallas: bool):
+    """One ragged grouped product in the tile-aligned layout: the Pallas
+    kernels, or XLA's ``ragged_dot`` over the same rows (each group as
+    long as its tiles)."""
+    if use_pallas:
+        from ..ops.pallas_kernels import grouped_matmul
+        return grouped_matmul(lhs, rhs, sizes, block_rows)
+    padded = (sizes + block_rows - 1) // block_rows * block_rows
+    return jax.lax.ragged_dot(lhs, rhs, padded,
+                              preferred_element_type=jnp.float32
+                              ).astype(lhs.dtype)
+
+
+def routed_experts_apply(params: dict, x: jnp.ndarray, *, top_k: int,
+                         n_held: Optional[int] = None, offset: int = 0,
+                         bias=None, route_norm: bool = True, route_scale: float = 1.0,
+                         block_rows: int = 128, compute_dtype=None,
+                         use_pallas: Optional[bool] = None):
+    """Dropless top-k routed gated experts, the held experts' part.
+
+    ``x`` (T, D); ``params``: ``router`` (D, n_experts), and the held
+    experts' ``wg``, ``wu`` (n_held, D, H) and ``wd`` (n_held, H, D),
+    expert ``offset + i`` of the router at index i.  Every token is
+    routed over all the router's experts; a route to an expert not held
+    adds nothing.  Returns (y (T, D) float32, counters): ``y[t] = sum
+    over held e in top_k(t) of w[t, e] * wd[e](silu(wg[e] x[t]) * wu[e]
+    x[t])``; ``counters`` are int32 scalars: ``rows_routed`` (routes
+    that landed on held experts), ``rows_computed`` (rows the grouped
+    products ran, tile padding included), ``experts_active`` (held
+    experts with a row), ``expert_rows_max``.
+
+    The sorted rows go through a buffer of static size.  One that holds
+    every route that can land here is ``min(K, n_held) / K`` of all
+    routes, eight times what uniform routing sends to 16 of 128 experts,
+    and gathers and elementwise passes cost its rows, used or not.  So
+    the step looks at the rows it needs and takes the small buffer when
+    they fit and the large one when they do not (``buffer_rows``): one
+    ``lax.cond``, both compiled, no drop either way."""
+    if use_pallas is None:
+        from ..ops import use_pallas_default
+        use_pallas = use_pallas_default()
+    T, D = x.shape
+    n_held = params["wg"].shape[0] if n_held is None else int(n_held)
+    dt = compute_dtype or x.dtype
+    with jax.named_scope("moe_route"):
+        w, topi = route_scores(x, params["router"], top_k=top_k, bias=bias,
+                               route_norm=route_norm,
+                               route_scale=route_scale)
+    with jax.named_scope("moe_dispatch"):
+        sizes, row_of_route, rows_needed = dispatch_plan(
+            topi, n_held, offset, block_rows)
+    small, large = buffer_rows(T, topi.shape[1], n_held,
+                               params["router"].shape[1], block_rows)
+    y = experts_through_buffers(
+        (small, large, block_rows, bool(use_pallas)), x.astype(dt), w,
+        params["wg"].astype(dt), params["wu"].astype(dt),
+        params["wd"].astype(dt), row_of_route, sizes, rows_needed)
+    counters = {"rows_routed": jnp.sum(sizes),
+                "rows_computed": rows_needed,
+                "experts_active": jnp.sum((sizes > 0).astype(jnp.int32)),
+                "expert_rows_max": jnp.max(sizes)}
+    return y, counters
+
+
+def _through_buffer(M, block_rows, use_pallas, x, w, wg, wu, wd,
+                    row_of_route, sizes):
+    """The held experts' part of y (T, D) float32, the sorted rows going
+    through a buffer of M rows (which has to hold them)."""
+    T, K = row_of_route.shape
+    with jax.named_scope("moe_dispatch"):
+        row = jnp.minimum(row_of_route, M)              # M: no row
+        route_of_row = jnp.full(M, T * K, jnp.int32).at[
+            row.reshape(-1)].set(jnp.arange(T * K, dtype=jnp.int32),
+                                 mode="drop")
+        rows = take_rows(x, route_of_row // K, row)
+    with jax.named_scope("moe_experts"):
+        product = functools.partial(grouped_products, sizes=sizes,
+                                    block_rows=block_rows,
+                                    use_pallas=use_pallas)
+        g = product(rows, wg)
+        u = product(rows, wu)
+        h = (jax.nn.silu(g.astype(jnp.float32))
+             * u.astype(jnp.float32)).astype(x.dtype)
+        out_rows = product(h, wd)
+    with jax.named_scope("moe_combine"):
+        # rows no product wrote hold anything: take_rows selects, it does
+        # not multiply
+        picked = take_rows(out_rows, row.reshape(-1), route_of_row[:, None])
+        return jnp.einsum("tk,tkd->td", w,
+                          picked.reshape(T, K, -1).astype(jnp.float32))
+
+
+def _with_the_buffer_that_fits(cfg, rows_needed, make, *operands):
+    """``make(M)(*operands)`` at the small buffer when the rows fit it,
+    at the large one when not: one ``lax.cond``, both compiled."""
+    small, large = cfg[:2]
+    if small == large:
+        return make(large)(*operands)
+    return jax.lax.cond(rows_needed <= small, make(small), make(large),
+                        *operands)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def experts_through_buffers(cfg, x, w, wg, wu, wd, row_of_route, sizes,
+                            rows_needed):
+    """``_through_buffer`` at the buffer that fits, ``cfg = (small, large,
+    block_rows, use_pallas)``.  Its gradient runs the forward again inside
+    the branch it takes: differentiating through a ``lax.cond`` keeps
+    what both branches would save, the large buffer's with the small
+    one's, for every layer until its backward, which is more than the
+    device has.  So nothing of the buffer outlives its branch, forward or
+    backward, and a caller has nothing to gain from rematerialising this
+    again."""
+    return _with_the_buffer_that_fits(
+        cfg, rows_needed,
+        lambda M: lambda *a: _through_buffer(M, *cfg[2:], *a, row_of_route,
+                                             sizes),
+        x, w, wg, wu, wd)
+
+
+def _experts_fwd(cfg, x, w, wg, wu, wd, row_of_route, sizes, rows_needed):
+    y = experts_through_buffers(cfg, x, w, wg, wu, wd, row_of_route, sizes,
+                                rows_needed)
+    return y, (x, w, wg, wu, wd, row_of_route, sizes, rows_needed)
+
+
+def _experts_bwd(cfg, res, dy):
+    *operands, row_of_route, sizes, rows_needed = res
+
+    def back(M):
+        def run(dy, *operands):
+            _, vjp = jax.vjp(
+                lambda *a: _through_buffer(M, *cfg[2:], *a, row_of_route,
+                                           sizes), *operands)
+            return vjp(dy)
+        return run
+
+    grads = _with_the_buffer_that_fits(cfg, rows_needed, back, dy, *operands)
+    return tuple(grads) + (None, None, None)
+
+
+experts_through_buffers.defvjp(_experts_fwd, _experts_bwd)
 
 
 def moe_shardings(params: dict, mesh: Mesh, axis: str = "expert") -> dict:

@@ -379,6 +379,7 @@ class Trainer(Logger):
             # near zero while prefetch keeps up, the loader's share of
             # the step when it does not
             it = iter(self._batches(TRAIN, epoch))
+            last: Dict[str, Any] = {}
             while True:
                 t0 = time.monotonic()
                 batch = next(it, None)
@@ -402,6 +403,7 @@ class Trainer(Logger):
                 # veles/accelerated_units.py:186-193, as an accident).
                 for k, v in mets.items():
                     sums[k] = sums[k] + v if k in sums else v
+                last = mets
                 sums["n_batches"] = sums.get("n_batches", 0) + 1
                 phase.labels(phase="step").observe(
                     time.monotonic() - t0)
@@ -409,7 +411,9 @@ class Trainer(Logger):
             # finished the last step, so the enclosing span's end is the
             # device's
             with span("train_drain", cat="train", epoch=epoch) as drain:
+                counters = self._drain_unit_counters(sums, last)
                 totals = {k: float(v) for k, v in sums.items()}
+            self._publish_unit_counters(TRAIN, counters)
             mets = aggregate_epoch_metrics(totals)
             sp.args.update((k, round(v, 6)) for k, v in mets.items()
                            if isinstance(v, float))
@@ -431,9 +435,29 @@ class Trainer(Logger):
                 for k, v in mets.items():
                     sums[k] = sums[k] + v if k in sums else v
                 sums["n_batches"] = sums.get("n_batches", 0) + 1
+            counters = self._drain_unit_counters(sums, mets)
             totals = {k: float(v) for k, v in sums.items()}
+        self._publish_unit_counters(klass, counters)
         self._phase_done("eval", sp)
         return aggregate_epoch_metrics(totals)
+
+    @staticmethod
+    def _drain_unit_counters(sums: Dict[str, Any], last: Dict[str, Any]):
+        """Take the units' counters (``Workflow._unit_counters``) out of
+        an epoch's device sums, and read them with the last step's: part
+        of the epoch's one drain.  ``{unit: ({name: sum}, {name: last})}``."""
+        out: Dict[str, Any] = {}
+        for key in [k for k in sums if k.startswith("counters/")]:
+            _, unit, name = key.split("/", 2)
+            summed, final = out.setdefault(unit, ({}, {}))
+            summed[name] = float(sums.pop(key))
+            final[name] = float(last[key])
+        return out
+
+    def _publish_unit_counters(self, klass: int, counters) -> None:
+        for unit, (summed, final) in counters.items():
+            self.workflow[unit].publish_counters(
+                CLASS_NAMES[klass], summed, final)
 
     def _phase_done(self, phase: str, sp) -> None:
         """Book a closed span's seconds under ``phase``, one of the four

@@ -599,6 +599,75 @@ class FFN(Unit):
         return y.astype(x.dtype), state
 
 
+def rms_normalize(x, scale, eps):
+    """``x / sqrt(mean(x^2) + eps) * scale`` over the trailing axis, in
+    float32, returned in ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
+class RMSNorm(Unit):
+    """Root-mean-square normalisation over the trailing feature axis with
+    a learnable scale and no shift.  With a second input the normalised
+    first is added to it, ``y = xs[1] + RMS(xs[0])``: the residual stream
+    of a block wired ``h + N(f(N(h)))``, written in a layer list as
+    ``{"type": "rms_norm", "inputs": [f, h]}``."""
+
+    def __init__(self, eps: float = 1e-5, name=None, inputs=("@input",)):
+        super().__init__(name, inputs)
+        self.eps = float(eps)
+
+    def output_spec(self, in_specs):
+        return in_specs[-1]
+
+    def init(self, key, in_specs):
+        return {"scale": jnp.ones((in_specs[0].shape[-1],))}, {}
+
+    def apply(self, params, state, xs, ctx):
+        y = rms_normalize(xs[0], params["scale"], self.eps)
+        if len(xs) > 1:
+            y = xs[1] + y.astype(xs[1].dtype)
+        return y, state
+
+
+class GatedMLP(Unit):
+    """Per-position gated MLP, ``y = Wd(act(Wg x) * (Wu x))`` (SwiGLU
+    with ``activation="silu"``): three matrices, no bias, no residual."""
+
+    def __init__(self, d_hidden: int, activation: str = "silu", name=None,
+                 inputs=("@input",), compute_dtype=None):
+        super().__init__(name, inputs)
+        self.d_hidden = int(d_hidden)
+        self.activation = activation
+        self.compute_dtype = _cast_policy(compute_dtype)
+
+    def output_spec(self, in_specs):
+        return in_specs[0]
+
+    def init(self, key, in_specs):
+        E = in_specs[0].shape[-1]
+        kg, ku, kd = jax.random.split(key, 3)
+        return {"wg": ops.smart_uniform_init(kg, (E, self.d_hidden), E),
+                "wu": ops.smart_uniform_init(ku, (E, self.d_hidden), E),
+                "wd": ops.smart_uniform_init(kd, (self.d_hidden, E),
+                                             self.d_hidden)}, {}
+
+    def apply(self, params, state, xs, ctx):
+        x = xs[0]
+        y = gated_mlp(x.reshape(-1, x.shape[-1]), params["wg"],
+                      params["wu"], params["wd"], self.activation,
+                      self.compute_dtype)
+        return y.reshape(x.shape).astype(x.dtype), state
+
+
+def gated_mlp(x, wg, wu, wd, activation="silu", compute_dtype=None):
+    """``(act(x wg) * (x wu)) wd`` on (rows, E), float32 accumulation."""
+    h = ACTIVATIONS[activation](ops.dense(x, wg, compute_dtype=compute_dtype)) \
+        * ops.dense(x, wu, compute_dtype=compute_dtype)
+    return ops.dense(h, wd, compute_dtype=compute_dtype)
+
+
 class Embedding(Unit):
     """Token embedding: int tokens (B, T) -> (B, T, dim) by table lookup.
 
@@ -607,10 +676,13 @@ class Embedding(Unit):
     inputs from generic loaders are cast to int32 indices."""
 
     def __init__(self, vocab: int, dim: int, name=None,
-                 inputs=("@input",)):
+                 inputs=("@input",), scale: Optional[float] = None):
         super().__init__(name, inputs)
         self.vocab = int(vocab)
         self.dim = int(dim)
+        # rows leave multiplied by this (sqrt(dim) in models that scale
+        # the embedding to the width of the residual stream)
+        self.scale = None if scale is None else float(scale)
 
     def output_spec(self, in_specs):
         s = in_specs[0]
@@ -622,7 +694,10 @@ class Embedding(Unit):
 
     def apply(self, params, state, xs, ctx):
         idx = xs[0].astype(jnp.int32)
-        return jnp.take(params["table"], idx, axis=0), state
+        rows = jnp.take(params["table"], idx, axis=0)
+        if self.scale is not None:
+            rows = rows * self.scale
+        return rows, state
 
 
 def input_vocab(workflow, params) -> Optional[int]:

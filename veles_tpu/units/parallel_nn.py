@@ -44,7 +44,9 @@ class MultiHeadAttention(Forward):
                  compute_dtype=None, window: Optional[int] = None,
                  n_kv_heads: Optional[int] = None, rope: bool = False,
                  residual: bool = False,
-                 use_flash: Optional[bool] = None):
+                 use_flash: Optional[bool] = None,
+                 qk_norm: bool = False, gate: bool = False,
+                 norm_eps: float = 1e-5):
         super().__init__(name, inputs)
         self.n_heads = int(n_heads)
         self.head_dim = head_dim
@@ -58,6 +60,13 @@ class MultiHeadAttention(Forward):
         # y = x + attn(x): the transformer residual stream (stacked
         # attention layers can't compose circuits without it)
         self.residual = bool(residual)
+        # RMS normalisation of every head's q and k over the head's
+        # channels (learnable scales ``q_norm``, ``k_norm``), before the
+        # rotary embedding
+        self.qk_norm = bool(qk_norm)
+        self.norm_eps = float(norm_eps)
+        # the heads' output times sigmoid(x Wg) before the out projection
+        self.gate = bool(gate)
         # grouped-query attention: fewer K/V heads than Q heads
         from ..ops import check_gqa_heads
         self.n_kv_heads = (self.n_heads if n_kv_heads is None
@@ -183,12 +192,20 @@ class MultiHeadAttention(Forward):
         if self.head_dim is None and E % H:
             raise ValueError(f"model dim {E} not divisible by {H} heads")
         kq, kk, kv, ko = jax.random.split(key, 4)
-        return {
+        params = {
             "wq": _uniform_init(kq, (E, H * D), E),
             "wk": _uniform_init(kk, (E, Hk * D), E),
             "wv": _uniform_init(kv, (E, Hk * D), E),
             "wo": _uniform_init(ko, (H * D, E), H * D),
-        }, {}
+        }
+        if self.qk_norm:
+            params["q_norm"] = jnp.ones((D,))
+            params["k_norm"] = jnp.ones((D,))
+        if self.gate:
+            # a key of its own: the four above stay what they were
+            params["wg"] = _uniform_init(jax.random.fold_in(key, 4),
+                                         (E, H * D), E)
+        return params, {}
 
     def apply(self, params, state, xs, ctx: Context):
         from ..parallel.ring_attention import (_ring_attention_local,
@@ -207,6 +224,10 @@ class MultiHeadAttention(Forward):
         q = proj(params["wq"], H)
         k = proj(params["wk"], self.n_kv_heads)
         v = proj(params["wv"], self.n_kv_heads)
+        if self.qk_norm:
+            from .nn import rms_normalize
+            q = rms_normalize(q, params["q_norm"], self.norm_eps)
+            k = rms_normalize(k, params["k_norm"], self.norm_eps)
         if self.rope:
             from ..ops import rotary_embedding
             # manual mode: x is this rank's T-shard inside an enclosing
@@ -248,7 +269,12 @@ class MultiHeadAttention(Forward):
                 spec = P(batch_axes(ctx.mesh, B), None, heads, None)
                 attend = shard_batch(attend, ctx.mesh, (spec,) * 3, spec)
             o = attend(q, k, v)
-        y = o.reshape(B, T, -1) @ params["wo"].astype(dt)
+        o = o.reshape(B, T, -1)
+        if self.gate:
+            g = jax.nn.sigmoid((xq @ params["wg"].astype(dt))
+                               .astype(jnp.float32))
+            o = (o * g).astype(dt)
+        y = o @ params["wo"].astype(dt)
         if self.residual:
             y = y + xq
         return y.astype(x.dtype), state
@@ -311,6 +337,125 @@ class MoEFFN(Forward):
                                dispatch_mode=self.dispatch_mode)
         return (y.reshape(x.shape),
                 {"aux_loss": aux.astype(jnp.float32)})
+
+
+class RoutedExpertsFFN(Forward):
+    """Dropless routed gated experts beside an optional shared expert,
+    over (B, T, E) or (N, E) activations: ``y = shared(x) + sum over the
+    top_k experts e of w_e expert_e(x)``, every expert a gated MLP
+    ``Wd(silu(Wg x) * (Wu x))`` of width ``d_hidden``.
+
+    No capacity and no drops: an expert's rows are whatever the router
+    gives it, zero included (``parallel/moe.routed_experts_apply``); the
+    router's scores are sigmoids.  The
+    router is ``n_experts`` wide; the unit holds ``experts_held`` of them,
+    ``expert_offset`` onwards (all by default), and computes their part
+    of the result: a route to an expert held elsewhere adds nothing here,
+    which is one chip's share of a layer whose experts are divided over
+    several.  On one device there is no exchange.  ``shared_width`` > 0
+    adds the shared expert, which every token passes through.
+
+    ``route_bias`` is unit state: added to the scores for the choice of
+    experts only, reached by no gradient, and left as it is by the step.
+    Each step's row counts ride the state's ``counters`` to the epoch's
+    drain (``publish_counters``).
+    """
+
+    def __init__(self, n_experts: int, d_hidden: int, name=None,
+                 inputs=("@input",), *, top_k: int = 2,
+                 experts_held: Optional[int] = None, expert_offset: int = 0,
+                 route_norm: bool = True, route_scale: float = 1.0,
+                 shared_width: int = 0,
+                 block_rows: int = 128, compute_dtype=None,
+                 use_pallas: Optional[bool] = None):
+        super().__init__(name, inputs)
+        self.n_experts = int(n_experts)
+        self.d_hidden = int(d_hidden)
+        self.top_k = int(top_k)
+        self.experts_held = self.n_experts if experts_held is None \
+            else int(experts_held)
+        self.expert_offset = int(expert_offset)
+        if not 0 <= self.expert_offset \
+                <= self.n_experts - self.experts_held:
+            raise ValueError(
+                f"experts {self.expert_offset}..+{self.experts_held} are "
+                f"not among the router's {self.n_experts}")
+        self.route_norm = bool(route_norm)
+        self.route_scale = float(route_scale)
+        self.shared_width = int(shared_width)
+        self.block_rows = int(block_rows)
+        self.compute_dtype = compute_dtype
+        self.use_pallas = use_pallas
+
+    def output_spec(self, in_specs):
+        return in_specs[0]
+
+    def init(self, key, in_specs):
+        E, H, G = in_specs[0].shape[-1], self.d_hidden, self.experts_held
+        kr, kg, ku, kd, ks = jax.random.split(key, 5)
+        params = {
+            "router": _uniform_init(kr, (E, self.n_experts), E),
+            "wg": _uniform_init(kg, (G, E, H), E),
+            "wu": _uniform_init(ku, (G, E, H), E),
+            "wd": _uniform_init(kd, (G, H, E), H),
+        }
+        if self.shared_width:
+            S = self.shared_width
+            k1, k2, k3 = jax.random.split(ks, 3)
+            params.update(shared_wg=_uniform_init(k1, (E, S), E),
+                          shared_wu=_uniform_init(k2, (E, S), E),
+                          shared_wd=_uniform_init(k3, (S, E), S))
+        # one buffer each: the step donates its state
+        state = {"route_bias": jnp.zeros((self.n_experts,), jnp.float32),
+                 "counters": {k: jnp.zeros((), jnp.int32) for k in (
+                     "rows_routed", "rows_computed", "experts_active",
+                     "expert_rows_max")}}
+        return params, state
+
+    def apply(self, params, state, xs, ctx: Context):
+        from ..parallel.moe import routed_experts_apply
+        from .nn import gated_mlp
+        x = xs[0]
+        flat = x.reshape(-1, x.shape[-1])
+        y, counters = routed_experts_apply(
+            params, flat, top_k=self.top_k, n_held=self.experts_held,
+            offset=self.expert_offset, bias=state["route_bias"], route_norm=self.route_norm,
+            route_scale=self.route_scale, block_rows=self.block_rows,
+            compute_dtype=self.compute_dtype, use_pallas=self.use_pallas)
+        if self.shared_width:
+            with jax.named_scope("moe_shared"):
+                y = y + gated_mlp(flat, params["shared_wg"],
+                                  params["shared_wu"], params["shared_wd"],
+                                  compute_dtype=self.compute_dtype)
+        return (y.reshape(x.shape).astype(x.dtype),
+                {"route_bias": state["route_bias"], "counters": counters})
+
+    def publish_counters(self, klass: str, sums: dict, last: dict) -> None:
+        """An epoch's counters of this unit, on the host after the drain
+        (``Trainer``): the sums over the epoch's steps of one class, and
+        the last step's."""
+        from ..runtime.metrics import registry
+        reg = registry()
+        rows = reg.counter(
+            "vt_moe_rows_total",
+            "rows of routed experts: routed = routes that landed on the "
+            "experts held here; computed = rows the grouped products ran, "
+            "tile padding included", labels=("unit", "klass", "kind"))
+        rows.labels(unit=self.name, klass=klass, kind="routed").inc(
+            sums["rows_routed"])
+        rows.labels(unit=self.name, klass=klass, kind="computed").inc(
+            sums["rows_computed"])
+        reg.counter(
+            "vt_moe_active_experts_total",
+            "held experts that got at least one row, summed over steps: "
+            "the matrices the grouped products read",
+            labels=("unit", "klass")).labels(
+                unit=self.name, klass=klass).inc(sums["experts_active"])
+        reg.gauge(
+            "vt_moe_expert_rows_max",
+            "rows of the fullest held expert in the epoch's last step",
+            labels=("unit",)).labels(unit=self.name).set(
+                last["expert_rows_max"])
 
 
 class PipelineStack(Forward):

@@ -216,6 +216,17 @@ class Workflow(Logger):
             return ev.metrics(params.get(ev.name, {}),
                               state.get(ev.name, {}), xs, ctx)
 
+    @staticmethod
+    def _unit_counters(nstate) -> Dict[str, jax.Array]:
+        """What units count in a step beside their output (the scalars
+        under ``counters`` in a unit's new state), keyed
+        ``counters/<unit>/<name>`` for the step's metrics: they leave the
+        compiled step with the epoch's metrics and reach the host at the
+        epoch's drain, where the trainer hands them to the unit's
+        ``publish_counters``."""
+        return {f"counters/{name}/{k}": v for name, ns in nstate.items()
+                for k, v in ns.get("counters", {}).items()}
+
     # -- compiled steps ----------------------------------------------------
     def _build_step(self, optimizer: Optimizer) -> Callable:
         """The pure (wstate, batch) -> (wstate, metrics) train function.
@@ -280,6 +291,8 @@ class Workflow(Logger):
                     # poison the epoch grad_norm aggregate
                     mets["grad_norm"] = gnorm if ok is None \
                         else jnp.where(ok, gnorm, 0.0)
+                # not gated: the forward ran whether or not the update did
+                mets.update(self._unit_counters(nstate))
             else:  # pure self-organizing workflows (SOM etc.)
                 outputs, nstate = self.forward(
                     wstate["params"], wstate["state"], batch, ctx)
@@ -378,10 +391,11 @@ class Workflow(Logger):
 
         def step(wstate, batch):
             ctx = Context(train=False, key=None, mesh=self.mesh)
-            outputs, _ = self.forward(wstate["params"], wstate["state"],
-                                      batch, ctx)
-            return self._metrics(wstate["params"], wstate["state"],
-                                 outputs, ctx)
+            outputs, nstate = self.forward(wstate["params"],
+                                           wstate["state"], batch, ctx)
+            return {**self._metrics(wstate["params"], wstate["state"],
+                                    outputs, ctx),
+                    **self._unit_counters(nstate)}
 
         return jax.jit(step, in_shardings=(state_sh, batch_sh),
                        out_shardings=None), state_sh, batch_sh
@@ -392,10 +406,11 @@ class Workflow(Logger):
 
         def step(wstate, batch):
             ctx = Context(train=False, key=None, mesh=self.mesh)
-            outputs, _ = self.forward(wstate["params"], wstate["state"],
-                                      batch, ctx)
-            return self._metrics(wstate["params"], wstate["state"],
-                                 outputs, ctx)
+            outputs, nstate = self.forward(wstate["params"],
+                                           wstate["state"], batch, ctx)
+            return {**self._metrics(wstate["params"], wstate["state"],
+                                    outputs, ctx),
+                    **self._unit_counters(nstate)}
 
         return jax.jit(step) if jit else step
 
