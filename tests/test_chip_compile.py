@@ -4,12 +4,12 @@ described and not attached (the chip's own compiler is installed here).
 Interpret mode — what the rest of the suite runs the kernels in — passes
 where the real lowering refuses a program: a tile that is not aligned, too
 much VMEM, a kernel that cannot be partitioned over a mesh.  These cases
-compile each kernel at the shapes ``chip_smoke.py`` runs on the chip, on one
-described chip, and the kernel call sites of the units (attention, dropout)
-inside a jitted function over a 4-device mesh of described chips with
-batch-sharded operands.  The loader's gather is a jit of its own on one
-device (loader/fullbatch.py); no mesh reaches it.  Nothing runs: a compile
-that passes is not a chip run.
+compile each kernel at the shapes ``chip_smoke.py`` and the benchmark run on
+the chip, on one described chip, and the kernel call sites of the units
+(attention, dropout, the softmax evaluator) inside a jitted function over a
+4-device mesh of described chips with batch-sharded operands.  The loader's
+gather is a jit of its own on one device (loader/fullbatch.py); no mesh
+reaches it.  Nothing runs: a compile that passes is not a chip run.
 
 The topology is described inside a fixture, in this one file, never at
 import: only one process at a time may hold the TPU library, and under
@@ -27,7 +27,7 @@ from veles_tpu import ops
 from veles_tpu.ops import pallas_kernels as pk
 from veles_tpu.parallel.mesh import MeshSpec, make_mesh
 from veles_tpu.units.base import Context, Spec
-from veles_tpu.units.nn import Dropout
+from veles_tpu.units.nn import Dropout, EvaluatorSoftmax
 from veles_tpu.units.parallel_nn import MultiHeadAttention
 
 
@@ -140,6 +140,18 @@ def _grouped(rows, groups, k, n, grad=True):
     return case
 
 
+def _xent(rows, classes, dtype="float32"):
+    """The loss's forward sweep over (rows, classes) logits, with the
+    loss, the predictions and the logits' gradient around it."""
+    def case(sh):
+        def f(x, labels):
+            return jax.value_and_grad(
+                lambda x: pk.softmax_xent_rows(x, labels)[0].mean())(x)
+        return (f, [_sds((rows, classes), dtype, sh),
+                    _sds((rows,), "int32", sh)], 1)
+    return case
+
+
 def _flash_gqa_d128(window):
     return _flash(1, 4096, 32, 4, 128, window=window)
 
@@ -165,6 +177,9 @@ ONE_CHIP = {
     "fused_dropout_bf16_alexnet_fc": _dropout((512, 4096), "bfloat16", 0.5),
     "mean_disp_normalize_alexnet_batch": _mean_disp(512, 227 * 227 * 3),
     "gather_rows_60000x784": _gather(60000, 784, 512),
+    "softmax_xent_opt_8192x50272": _xent(8192, 50272),
+    "softmax_xent_trinity_4096x25024": _xent(4096, 25024),
+    "softmax_xent_bf16_8192x50272": _xent(8192, 50272, "bfloat16"),
 }
 
 
@@ -203,6 +218,22 @@ def _dropout_site(mesh, batch_sh):
                 jax.eval_shape(lambda: jax.random.key(0))], 2)
 
 
+def _evaluator_site(mesh, batch_sh):
+    """The evaluator over an LM's logits with batch-sharded rows: each
+    device sweeps its own 4 x 2048 rows of the 50,272 classes."""
+    u = EvaluatorSoftmax(name="evaluator")
+    assert ops.losses.softmax_loss_path((16, 2048, 50272), mesh) == "swept"
+
+    def f(x, labels, mask):
+        ctx = Context(train=True, key=None, mesh=mesh)
+        return jax.value_and_grad(
+            lambda x: u.apply({}, {}, [x, labels, mask], ctx)[0])(x)
+
+    return (f, [_sds((16, 2048, 50272), "float32", batch_sh),
+                _sds((16, 2048), "int32", batch_sh),
+                _sds((16,), "float32", batch_sh)], 1)
+
+
 #: name -> (mesh spec, builder(mesh, batch sharding))
 CALL_SITES = {
     "attention_data4": (MeshSpec(data=4), _attention_site),
@@ -210,6 +241,8 @@ CALL_SITES = {
     "attention_data2_model2": (MeshSpec(data=2, model=2), _attention_site),
     "dropout_data4": (MeshSpec(data=4), _dropout_site),
     "dropout_data2_fsdp2": (MeshSpec(data=2, fsdp=2), _dropout_site),
+    "evaluator_data4": (MeshSpec(data=4), _evaluator_site),
+    "evaluator_data2_fsdp2": (MeshSpec(data=2, fsdp=2), _evaluator_site),
 }
 
 
@@ -223,3 +256,67 @@ def test_kernel_call_site_compiles_under_a_mesh(topo, for_the_chip, name):
     dp = tuple(a for a in ("data", "fsdp") if mesh.shape[a] > 1)
     fn, args, kernels = site(mesh, NamedSharding(mesh, P(dp)))
     assert _custom_calls(fn, *args) == kernels
+
+
+# -- the LM's loss in the compiled train step ---------------------------------
+
+def _entry_results(text):
+    """(result type, opcode and operands) of each instruction of the
+    compiled module's entry computation."""
+    body = text[text.index("\nENTRY "):]
+    out = []
+    for line in body[:body.index("\n}")].splitlines()[2:]:
+        rhs = line.split(" = ", 1)[1]
+        cut = rhs.index(") ") + 1 if rhs.startswith("(") else rhs.index(" ")
+        out.append((rhs[:cut], rhs[cut + 1:]))
+    return out
+
+
+def test_lm_step_holds_the_logits_once(topo, for_the_chip, monkeypatch):
+    """A small LM's train step over 2048 x 50,272 logits (412 MB),
+    compiled for one described chip: ``apply`` and ``metrics`` both ask,
+    the step has one forward sweep, and the one array of the logits'
+    size that any operation writes is the logits themselves.  Their
+    gradient is computed inside the operations that read it (the head's
+    two backward products and the bias sum).  The plain formulation,
+    forced on the same step, writes several."""
+    import re
+    from veles_tpu.ops import losses
+    from veles_tpu.ops.optimizers import SGD
+    from veles_tpu.units.nn import All2All, Embedding
+    from veles_tpu.units.workflow import Workflow
+    b, t, d, v = 4, 512, 256, 50272
+    sh = SingleDeviceSharding(topo.devices[0])
+    logits_sized = re.compile(
+        rf"f32\[({b},{t},{v}|{b * t},{v}|{v},{b * t})\]")
+
+    def compiled_text():
+        wf = Workflow("lm")
+        wf.add(Embedding(v, d, name="emb", inputs=("@input",)))
+        wf.add(All2All(v, per_position=True, compute_dtype="bfloat16",
+                       name="head", inputs=("emb",)))
+        wf.add(EvaluatorSoftmax(name="ev",
+                                inputs=("head", "@labels", "@mask")))
+        specs = {"@input": Spec((b, t), jnp.int32),
+                 "@labels": Spec((b, t), jnp.int32),
+                 "@mask": Spec((b,), jnp.float32)}
+        wf.build(specs)
+        opt = SGD(lr=0.1)
+        ws = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            jax.eval_shape(lambda k: wf.init_state(k, opt),
+                           jax.random.key(0)))
+        batch = {k: _sds(s.shape, s.dtype, sh) for k, s in specs.items()}
+        return wf.make_train_step(opt).lower(ws, batch).compile().as_text()
+
+    def writers(text):
+        return [op for result, op in _entry_results(text)
+                if logits_sized.search(result) and not op.startswith(
+                    ("bitcast(", "get-tuple-element(", "parameter("))]
+
+    text = compiled_text()
+    assert text.count("tpu_custom_call") == 1 and "softmax_xent_fwd" in text
+    assert len(writers(text)) == 1, writers(text)
+    monkeypatch.setattr(losses, "SWEPT_MIN_BYTES", 1 << 60)
+    plain = compiled_text()
+    assert "tpu_custom_call" not in plain and len(writers(plain)) > 1

@@ -3,10 +3,10 @@
 ``runtime.metrics.span`` puts one interval on the span ring, on the
 profiler's host plane and (when ``root.common.trace_file`` is set) on the
 JSONL timeline; ``Trainer.run()`` leaves a ``train_run`` tree whose phases
-partition it; every unit, the optimizer, the metrics and each Pallas
-kernel carry their names into the lowered programs.  Nothing the program
-emits is called ``epoch_boundary``: the benchmark counts marks of that
-name to find whole epochs.
+partition it; every unit (the evaluator with its metrics), the optimizer
+and each Pallas kernel carry their names into the lowered programs.  Nothing
+the program emits is called ``epoch_boundary``: the benchmark counts marks
+of that name to find whole epochs.
 """
 
 import glob
@@ -271,8 +271,8 @@ def lowered_steps():
 @pytest.mark.parametrize("program,scope", [
     ("train", "jvp(fc1)"), ("train", "jvp(out)"), ("train", "jvp(ev)"),
     ("train", "transpose(jvp(fc1))"), ("train", "transpose(jvp(out))"),
-    ("train", "/optimizer/"), ("train", "jvp(metrics)"),
-    ("eval", "/fc1/"), ("eval", "/out/"), ("eval", "/metrics/")])
+    ("train", "/optimizer/"), ("train", "transpose(jvp(ev))"),
+    ("eval", "/fc1/"), ("eval", "/out/"), ("eval", "/ev/")])
 def test_lowered_step_names_units_and_phases(lowered_steps, program, scope):
     assert scope in lowered_steps[program]
 

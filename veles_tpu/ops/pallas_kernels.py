@@ -1085,6 +1085,144 @@ fused_dropout.defvjp(_dropout_vjp_fwd, _dropout_vjp_bwd)
 
 
 # ---------------------------------------------------------------------------
+# Softmax cross-entropy by rows: one sweep of the logits forward
+# ---------------------------------------------------------------------------
+#
+# What the loss needs of a row of logits is four numbers, all functions of
+# one pass over it: the maximum, the sum of exponentials (together the
+# logsumexp), the label's logit and the first index of the maximum.  The
+# forward kernel sweeps a row block class block by class block with the
+# running maximum and sum in VMEM (the flash kernels' recurrence, without
+# the products).  The backward needs no kernel: ``(exp(x - lse) - onehot)
+# * g`` is elementwise in the logits and the saved logsumexp, and XLA
+# computes it inside the operations that consume it (the head's two
+# backward products and its bias sum read the logits and never see a
+# gradient array; PERF.md section 6, PR 30).  Nothing else of the logits'
+# size exists: no log-probabilities, no one-hot, no second layout.
+
+#: requested (classes, rows) of a tile: 2 MB of float32
+_XENT_BLOCK = (1024, 512)
+
+
+def _xent_fwd_kernel(x_ref, lab_ref, lse_ref, pick_ref, pred_ref, m_ref,
+                     l_ref, *, n_classes, block_c):
+    """One (block_c, block_r) tile of the transposed logits: classes down
+    the sublanes, rows along the lanes, so a row's state is one lane and
+    the reductions over classes are elementwise between registers."""
+    j = pl.program_id(1)
+    n_j = pl.num_programs(1)
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        pick_ref[:] = jnp.zeros_like(pick_ref)
+        pred_ref[:] = jnp.zeros_like(pred_ref)
+
+    def sweep(padded):
+        x = x_ref[...].astype(jnp.float32)
+        # classes count from the block's first: the label and the array's
+        # edge are shifted once a row, not the iota once an element
+        cls = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+        if padded:
+            x = jnp.where(cls < n_classes - j * block_c, x, -jnp.inf)
+        m_blk = jnp.max(x, axis=0, keepdims=True)
+        first = jnp.min(jnp.where(x == m_blk, cls, block_c), axis=0,
+                        keepdims=True) + j * block_c
+        m_old = m_ref[:]
+        # a later block wins only with a larger maximum: ties go to the
+        # first index, as jnp.argmax has it
+        pred_ref[:] = jnp.where(m_blk > m_old, first, pred_ref[:])
+        m_new = jnp.maximum(m_old, m_blk)
+        # rows that are -inf so far (a masked vocabulary's first blocks)
+        # shift by 0: exp(-inf - 0) is 0, where -inf - -inf is NaN
+        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        l_ref[:] = (l_ref[:] * jnp.exp(m_old - m_safe)
+                    + jnp.sum(jnp.exp(x - m_safe), axis=0, keepdims=True))
+        m_ref[:] = m_new
+        pick_ref[:] += jnp.sum(
+            jnp.where(cls == lab_ref[:] - j * block_c, x, 0.0), axis=0,
+            keepdims=True)
+
+    if n_classes % block_c:
+        pl.when(j < n_j - 1)(lambda: sweep(False))
+        pl.when(j == n_j - 1)(lambda: sweep(True))
+    else:
+        sweep(False)
+
+    @pl.when(j == n_j - 1)
+    def _():
+        lse_ref[:] = m_ref[:] + jnp.log(l_ref[:])
+
+
+def _xent_fwd(logits, labels, interpret):
+    """(ce, pred, lse) of (rows, classes) logits, each of (rows,).
+
+    The kernel reads the logits transposed, (classes, rows).  That is the
+    layout XLA itself gives the logits of a wide head (rows minor: the
+    head's three products want it, PERF.md section 6, PR 30), so the
+    transpose is a change of name and no copy; a kernel over (rows,
+    classes) made XLA copy the logits where the products kept theirs."""
+    rows, classes = logits.shape
+    # the requested tile, cut to the array where it is smaller (a block
+    # may equal a whole axis whatever its size; else classes go by 8 or
+    # 16 sublanes and rows by the lane width)
+    block_c = min(_XENT_BLOCK[0], classes)
+    block_r = min(_XENT_BLOCK[1], rows)
+    row = pl.BlockSpec((1, block_r), lambda i, j: (0, i))
+    lse, pick, pred = pl.pallas_call(
+        functools.partial(_xent_fwd_kernel, n_classes=classes,
+                          block_c=block_c),
+        grid=(pl.cdiv(rows, block_r), pl.cdiv(classes, block_c)),
+        in_specs=[pl.BlockSpec((block_c, block_r), lambda i, j: (j, i)),
+                  row],
+        out_specs=[row, row, row],
+        out_shape=[jax.ShapeDtypeStruct((1, rows), jnp.float32),
+                   jax.ShapeDtypeStruct((1, rows), jnp.float32),
+                   jax.ShapeDtypeStruct((1, rows), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((1, block_r), jnp.float32),
+                        pltpu.VMEM((1, block_r), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(interpret),
+        name="softmax_xent_fwd",
+    )(logits.T, labels.reshape(1, rows).astype(jnp.int32))
+    return (lse - pick)[0], pred[0], lse[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def softmax_xent_rows(logits, labels, interpret=None):
+    """Per-row softmax cross-entropy and prediction of ``logits`` (rows,
+    classes) against integer ``labels`` (rows,), in one sweep of the
+    logits: ``(ce, pred)``, float32 and int32, ``pred`` the first index of
+    the row's maximum.  All arithmetic is float32 whatever the logits'
+    dtype.  The residuals are the logits and one float32 logsumexp a row;
+    the logits' gradient is an elementwise expression of the two, in the
+    logits' dtype, left to XLA to compute where it is consumed; ``pred``
+    has no gradient."""
+    ce, pred, _ = _xent_fwd(logits, labels, interpret)
+    return ce, pred
+
+
+def _xent_vjp_fwd(logits, labels, interpret):
+    ce, pred, lse = _xent_fwd(logits, labels, interpret)
+    return (ce, pred), (logits, labels, lse)
+
+
+def _xent_vjp_bwd(interpret, res, g):
+    logits, labels, lse = res
+    with jax.named_scope("softmax_xent_bwd"):
+        p = jnp.exp(logits.astype(jnp.float32) - lse[:, None])
+        hit = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) \
+            == labels[:, None]
+        return (jnp.where(hit, p - 1.0, p) * g[0][:, None]).astype(
+            logits.dtype), None
+
+
+softmax_xent_rows.defvjp(_xent_vjp_fwd, _xent_vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
 # Mean/dispersion normalize
 # ---------------------------------------------------------------------------
 

@@ -748,12 +748,23 @@ class Reshape(Unit):
 class Evaluator(Unit):
     """Base loss unit: consumes (output, labels/targets); its output is the
     scalar loss; metrics are returned via state-free aux (collected by the
-    Workflow). Reference: Znicz evaluator units feeding Decision."""
+    Workflow). Reference: Znicz evaluator units feeding Decision.
+
+    A subclass defines ``evaluate``: one pass over its inputs that yields
+    the step's metrics, ``"loss"`` among them.  ``apply`` and ``metrics``
+    are views of it, and ``Workflow.forward`` calls it once a step and
+    keeps both."""
 
     is_evaluator = True
 
-    def metrics(self, params, state, xs, ctx) -> dict:
+    def evaluate(self, params, state, xs, ctx) -> dict:
         raise NotImplementedError
+
+    def apply(self, params, state, xs, ctx):
+        return self.evaluate(params, state, xs, ctx)["loss"], state
+
+    def metrics(self, params, state, xs, ctx) -> dict:
+        return self.evaluate(params, state, xs, ctx)
 
 
 class EvaluatorSoftmax(Evaluator):
@@ -764,7 +775,11 @@ class EvaluatorSoftmax(Evaluator):
 
     Sequence form: logits (B, T, V) with labels (B, T) compute the
     per-position loss (next-token LM training); the per-sample mask
-    broadcasts across positions and metrics count positions."""
+    broadcasts across positions and metrics count positions.
+
+    Large logits on a TPU go through the loss in one sweep
+    (``ops.losses.softmax_loss_path``); which path a call took is the
+    gauge ``vt_softmax_loss_path{unit, path}``, set when traced."""
 
     def __init__(self, name=None, inputs=("@input", "@labels", "@mask")):
         super().__init__(name, inputs)
@@ -782,13 +797,23 @@ class EvaluatorSoftmax(Evaluator):
                 labels.shape)
         return m
 
-    def apply(self, params, state, xs, ctx):
-        loss, _ = ops.softmax_cross_entropy(xs[0], xs[1], mask=self._mask(xs))
-        return loss, state
+    def _note_path(self, path):
+        from ..runtime.metrics import registry
+        gauge = registry().gauge(
+            "vt_softmax_loss_path",
+            "1 on the path the softmax loss took when last traced",
+            labels=("unit", "path"))
+        for p in ("swept", "plain"):
+            gauge.labels(unit=self.name, path=p).set(float(p == path))
 
-    def metrics(self, params, state, xs, ctx):
+    def evaluate(self, params, state, xs, ctx):
         mask = self._mask(xs)
-        loss, n_err = ops.softmax_cross_entropy(xs[0], xs[1], mask=mask)
+        # inside a schedule's shard_map the call is already per shard
+        mesh = None if ctx is None or ctx.manual_axes is not None \
+            else ctx.mesh
+        self._note_path(ops.losses.softmax_loss_path(xs[0].shape, mesh))
+        loss, n_err = ops.softmax_cross_entropy(xs[0], xs[1], mask=mask,
+                                                mesh=mesh)
         n = mask.sum() if mask is not None else jnp.asarray(
             float(np.prod(xs[1].shape)), jnp.float32)
         return {"loss": loss, "n_err": n_err, "n_samples": n}
@@ -807,11 +832,7 @@ class EvaluatorMSE(Evaluator):
     def _mask(xs):
         return xs[2] if len(xs) > 2 else None
 
-    def apply(self, params, state, xs, ctx):
-        loss, _ = ops.mse_loss(xs[0], xs[1], mask=self._mask(xs))
-        return loss, state
-
-    def metrics(self, params, state, xs, ctx):
+    def evaluate(self, params, state, xs, ctx):
         mask = self._mask(xs)
         loss, agg = ops.mse_loss(xs[0], xs[1], mask=mask)
         n = mask.sum() if mask is not None else jnp.asarray(

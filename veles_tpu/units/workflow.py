@@ -40,6 +40,11 @@ class WorkflowError(Exception):
     pass
 
 
+#: where ``forward`` leaves the evaluator's metrics among its outputs (no
+#: unit can take the name: "@" marks a batch key)
+_EVALUATED = "@evaluated"
+
+
 def new_state(params, state, opt_state, step, key):
     """The workflow state pytree: everything that is sharded, donated and
     checkpointed. Replaces the reference's pickled live-object graph
@@ -172,6 +177,13 @@ class Workflow(Logger):
             xs = [outputs[s] for s in u.inputs]
             up = params.get(u.name, {})
             us = state.get(u.name, {})
+            if u is self.evaluator:
+                # one pass over the evaluator's inputs a step: the loss
+                # is the unit's output, the rest waits for ``_metrics``
+                with jax.named_scope(u.name):
+                    mets = u.evaluate(up, us, xs, ctx)
+                outputs[u.name], outputs[_EVALUATED] = mets["loss"], mets
+                continue
             # the unit's name scopes its operations in the compiled
             # program (jvp(name) / transpose(jvp(name)) under AD), which
             # is how a profile tells conv2's backward from lrn1's
@@ -207,14 +219,11 @@ class Workflow(Logger):
             stack.extend(self._by_name[n].inputs)
         return need
 
-    def _metrics(self, params, state, outputs, ctx) -> Dict[str, jax.Array]:
-        if self.evaluator is None:
-            return {}
-        ev = self.evaluator
-        xs = [outputs[s] for s in ev.inputs]
-        with jax.named_scope("metrics"):
-            return ev.metrics(params.get(ev.name, {}),
-                              state.get(ev.name, {}), xs, ctx)
+    @staticmethod
+    def _metrics(outputs) -> Dict[str, jax.Array]:
+        """The step's metrics: what the evaluator's one ``evaluate`` of
+        ``forward`` yielded beside the loss."""
+        return outputs.get(_EVALUATED, {})
 
     @staticmethod
     def _unit_counters(nstate) -> Dict[str, jax.Array]:
@@ -261,7 +270,7 @@ class Workflow(Logger):
                     outputs, nstate = self.forward(
                         params, wstate["state"], batch, ctx)
                     loss = outputs[self.evaluator.name]
-                    mets = self._metrics(params, wstate["state"], outputs, ctx)
+                    mets = self._metrics(outputs)
                     # auxiliary losses (e.g. MoE load balance) ride the
                     # unit-state channel and are summed into the training
                     # loss with per-unit weights
@@ -393,8 +402,7 @@ class Workflow(Logger):
             ctx = Context(train=False, key=None, mesh=self.mesh)
             outputs, nstate = self.forward(wstate["params"],
                                            wstate["state"], batch, ctx)
-            return {**self._metrics(wstate["params"], wstate["state"],
-                                    outputs, ctx),
+            return {**self._metrics(outputs),
                     **self._unit_counters(nstate)}
 
         return jax.jit(step, in_shardings=(state_sh, batch_sh),
@@ -408,8 +416,7 @@ class Workflow(Logger):
             ctx = Context(train=False, key=None, mesh=self.mesh)
             outputs, nstate = self.forward(wstate["params"],
                                            wstate["state"], batch, ctx)
-            return {**self._metrics(wstate["params"], wstate["state"],
-                                    outputs, ctx),
+            return {**self._metrics(outputs),
                     **self._unit_counters(nstate)}
 
         return jax.jit(step) if jit else step
