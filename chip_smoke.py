@@ -53,7 +53,6 @@ REAL = dict(
            # the serve phase's own geometry (slots x l_max/page pages)
            dict(B=8, H=8, Hk=8, D=64, psz=16, n_ptab=128, dt="float32")],
     dropout=[((4096, 4096), "float32", 0.3), ((512, 4096), "bfloat16", 0.5)],
-    mean_disp=(512, 227 * 227 * 3),
     gather=(60000, 784, 512),
     train=dict(batch=512, n_train=2048, n_valid=512, epochs=3),
     lm=None,                          # bench_lm.SHAPE, in both phases
@@ -67,7 +66,6 @@ TOY = dict(
     paged=[dict(B=3, H=4, Hk=2, D=8, psz=4, n_ptab=5, dt="float32"),
            dict(B=2, H=2, Hk=2, D=8, psz=4, n_ptab=4, dt="bfloat16")],
     dropout=[((64, 256), "float32", 0.3), ((16, 128), "bfloat16", 0.5)],
-    mean_disp=(8, 3 * 40),
     gather=(200, 784, 16),
     train=dict(batch=4, n_train=8, n_valid=4, epochs=3),
     lm=dict(B=2, T=64, E=32, LAYERS=2, HEADS=2, VOCAB=64),
@@ -126,8 +124,8 @@ def check(cond, what):
 
 
 def rel_err(got, ref):
-    """Largest absolute difference over the reference's largest magnitude
-    (the measure ``bench_tpu.py`` records)."""
+    """Largest absolute difference over the reference's largest
+    magnitude."""
     got = np.asarray(got, np.float64)
     ref = np.asarray(ref, np.float64)
     check(np.isfinite(got).all(), "non-finite kernel output")
@@ -242,16 +240,6 @@ def phase_kernels(size, seed, on_tpu):
         worst["fused_dropout"] = max(worst.get("fused_dropout", 0.0),
                                      abs(float(kept.mean()) - (1 - rate)))
 
-    # mean/dispersion normalize of a uint8 image block against jnp
-    n, f = size["mean_disp"]
-    xb = jnp.asarray(rng.integers(0, 256, (n, f)), jnp.uint8)
-    mean = jnp.asarray(rng.uniform(100, 150, f), jnp.float32)
-    rd = jnp.asarray(rng.uniform(0.01, 0.02, f), jnp.float32)
-    got = run_compiled(lambda x, m, r: pk.mean_disp_normalize(x, m, r),
-                       xb, mean, rd, on_tpu=on_tpu)
-    note("mean_disp_normalize",
-         rel_err(got, (xb.astype(jnp.float32) - mean[None]) * rd[None]), 1e-5)
-
     # the loader's per-index DMA gather against jnp.take
     n, f, m = size["gather"]
     data = jnp.asarray(rng.standard_normal((n, f)), jnp.float32)
@@ -307,7 +295,6 @@ def phase_train(size, seed, on_tpu, kind):
     picks = {
         "lrn": wf["lrn1"].method,
         "dropout": "pallas" if wf["drop6"].uses_kernel() else "xla",
-        "norm": "pallas" if wf["norm0"]._resolved else "xla",
         # FullBatchAugmentedLoader fuses its own take+crop: no candidates
         "gather": "take+crop",
     }
@@ -315,7 +302,7 @@ def phase_train(size, seed, on_tpu, kind):
         kernel_chosen = "pallas" in picks.values()
         check(("tpu_custom_call" in trainer._train_step.as_text())
               == kernel_chosen,
-              f"train step and autotune picks disagree on kernels: {picks}")
+              f"train step and picks disagree on kernels: {picks}")
     trainer.run()
     losses = trainer.recorder.series["train_loss"]
     check(len(losses) == s["epochs"] and np.isfinite(losses).all(),

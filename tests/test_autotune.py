@@ -90,37 +90,11 @@ def test_pick_failure_names_the_candidate(tuned):
                       default="ok")
 
 
-def test_lrn_auto_resolves_via_autotune(tuned):
-    """LRN method='auto' resolves to a concrete formulation at build time
-    and the concrete name (never 'auto') is what export would see."""
-    import veles_tpu as vt
-    from veles_tpu.units import nn
-
-    u = nn.LRN(method="auto", name="lrn")
-    spec = vt.Spec((4, 6, 6, 32), jnp.float32)
-    u.prepare([spec])
-    assert u.method in ("cumsum", "band", "band_bf16")
-    assert u._resolved == u.method
-
-    # winner persisted; a second unit with the same shape reuses it
-    u2 = nn.LRN(method="auto", name="lrn2")
-    u2.prepare([spec])
-    assert u2.method == u.method
-
-
-def test_lrn_auto_disabled_uses_default():
-    from veles_tpu.units import nn
-    import veles_tpu as vt
-
-    u = nn.LRN(method="auto", name="lrn")
-    u.prepare([vt.Spec((2, 4, 4, 16), jnp.float32)])
-    assert u.method == "cumsum"  # autotune off under test -> default
-
-
 def test_pipeline_stack_propagates_prepare(tuned):
-    """Composite units must forward prepare() to sub-units: an LRN with
-    method='auto' inside a pipeline stage resolves at build time (never
-    reaching trace or export as 'auto')."""
+    """Composite units must forward prepare() to sub-units: an attention
+    unit inside a pipeline stage resolves its measured pick at build
+    time.  The stage's LRN needs no prepare: "auto" became "band" in its
+    constructor (never reaching trace or export as 'auto')."""
     import veles_tpu as vt
     from veles_tpu.units.parallel_nn import PipelineStack
 
@@ -129,8 +103,127 @@ def test_pipeline_stack_propagates_prepare(tuned):
         [{"type": "layer_norm"}],
     ], name="stack")
     st.prepare([vt.Spec((4, 6, 6, 32), jnp.float32)])
-    lrn = st._stage_units[0][0]
-    assert lrn.method in ("cumsum", "band", "band_bf16")
+    assert st._stage_units[0][0].method == "band"
+
+    st = PipelineStack(stages=[
+        [{"type": "attention", "n_heads": 2, "residual": True}],
+        [{"type": "layer_norm"}],
+    ], name="attn_stack")
+    attn = st._stage_units[0][0]
+    attn._resolved_flash = "untouched"
+    st.prepare([vt.Spec((2, 16, 16), jnp.float32)])
+    # off-TPU prepare() resolves measurement-free to the XLA form
+    assert attn._resolved_flash in (True, False)
+
+
+# -- formulations chosen without a measurement --------------------------------
+
+def _run_dropout():
+    import jax
+    from veles_tpu.units import nn
+    from veles_tpu.units.base import Context
+    u = nn.Dropout(0.3, name="drop")
+    u.prepare([None])
+    x = jnp.ones((64, 256), jnp.float32)
+    y, _ = u.apply({}, {}, [x], Context(train=True, key=jax.random.key(0),
+                                        mesh=None))
+    kept = np.asarray(y) != 0
+    assert abs(kept.mean() - 0.7) < 0.05
+    np.testing.assert_allclose(np.asarray(y)[kept], 1 / 0.7, rtol=1e-6)
+    return u.uses_kernel()
+
+
+def _run_lrn():
+    import veles_tpu as vt
+    from veles_tpu.units import nn
+    u = nn.LRN(method="auto", name="lrn")
+    spec = vt.Spec((2, 4, 4, 16), jnp.float32)
+    u.prepare([spec])
+    x = jnp.asarray(np.random.default_rng(0).standard_normal(spec.shape),
+                    jnp.float32)
+    y, _ = u.apply({}, {}, [x], None)
+    assert y.shape == x.shape and np.isfinite(np.asarray(y)).all()
+    assert u.method == "band"
+    return False  # no kernel on any platform
+
+
+def _run_mean_disp():
+    import jax
+    import veles_tpu as vt
+    from veles_tpu.units import nn
+    u = nn.MeanDispNormalizer(mean=np.full((4, 4, 3), 100.0),
+                              rdisp=np.full((4, 4, 3), 0.5), name="norm")
+    spec = vt.Spec((8, 4, 4, 3), jnp.uint8)
+    u.prepare([spec])
+    _, state = u.init(jax.random.key(0), [spec])
+    x = np.random.default_rng(0).integers(0, 256, spec.shape, np.uint8)
+    y, _ = u.apply({}, state, [jnp.asarray(x)], None)
+    np.testing.assert_allclose(np.asarray(y),
+                               (x.astype(np.float32) - 100.0) * 0.5)
+    return False
+
+
+def _run_gather():
+    """Default policy (no ``use_pallas_gather``): rows inside the static
+    envelope are packed on a TPU, ``jnp.take`` elsewhere; a class smaller
+    than the minibatch gathers through its own jit either way."""
+    from veles_tpu.loader.fullbatch import FullBatchLoader
+    from veles_tpu.loader.base import TRAIN, VALID
+    rng = np.random.default_rng(1)
+    X = {TRAIN: rng.standard_normal((300, 1024)).astype(np.float32),
+         VALID: rng.standard_normal((7, 1024)).astype(np.float32)}
+    ld = FullBatchLoader({k: v.copy() for k, v in X.items()},
+                         minibatch_size=16)
+    ld.initialize()
+    assert ld.on_device
+    for klass in (TRAIN, VALID):
+        for i, b in enumerate(ld.iter_epoch(klass, 0)):
+            perm = ld.epoch_permutation(klass, 0)[i * 16:(i + 1) * 16]
+            got = np.asarray(b["@input"])[: len(perm)]
+            np.testing.assert_allclose(got, X[klass][perm])
+    # packed rows are stored (N, f_pad / 128, 128)-tiled, plain ones as given
+    return ld._dev_data[TRAIN]["@input"].shape != X[TRAIN].shape
+
+
+@pytest.mark.parametrize("platform", ["tpu", "cpu"])
+@pytest.mark.parametrize("what", ["dropout", "lrn", "mean_disp", "gather"])
+def test_formulation_needs_no_measurement(tuned, monkeypatch, what,
+                                          platform):
+    """Dropout, LRN, mean/dispersion normalize and the fullbatch gather
+    choose their formulation from the platform (``use_pallas_default``)
+    or have only one: with autotune ON nothing is measured and no DB
+    record is written, on a TPU and off it."""
+    import veles_tpu.ops as vops
+    from veles_tpu.loader import fullbatch
+    from veles_tpu.ops import pallas_kernels as pk
+
+    def never(*a, **kw):
+        raise AssertionError("a formulation was measured")
+
+    on_tpu = platform == "tpu"
+    monkeypatch.setattr(autotune, "measure", never)
+    # the units' and the loader's policy says "TPU"; the kernels' own
+    # binding keeps saying CPU, so they run in interpret mode
+    monkeypatch.setattr(vops, "use_pallas_default", lambda *a: on_tpu)
+    monkeypatch.setattr(fullbatch, "use_pallas_default", lambda *a: on_tpu)
+    monkeypatch.setattr(pk, "use_pallas_default", lambda *a: False)
+    run = {"dropout": _run_dropout, "lrn": _run_lrn,
+           "mean_disp": _run_mean_disp, "gather": _run_gather}[what]
+    used_kernel = run()
+    assert used_kernel == (on_tpu and what in ("dropout", "gather"))
+    assert not os.path.exists(os.path.join(tuned, "device_infos.json"))
+
+
+def test_only_attention_measures():
+    """One rule picks a formulation everywhere but in attention: the
+    package has one caller of ``autotune.pick``."""
+    import pathlib
+    import veles_tpu
+    pkg = pathlib.Path(veles_tpu.__file__).parent
+    callers = sorted(
+        str(f.relative_to(pkg)) for f in pkg.rglob("*.py")
+        if "autotune.pick(" in f.read_text())
+    assert callers == ["units/parallel_nn.py"]
 
 
 def test_new_candidate_triggers_remeasure(tuned):
@@ -160,94 +253,6 @@ def test_new_candidate_triggers_remeasure(tuned):
            if k.startswith("grow_op")][0]
     assert set(rec["ms"]) == {"a", "b", "c"}  # re-measured with all three
     assert w in ("a", "c")
-
-
-def test_fullbatch_gather_decision_measured(tuned):
-    """With autotune on, the loader's pack-vs-take choice is measured on
-    the actual dataset shape and persisted; batches stay exact either
-    way."""
-    from veles_tpu.loader.fullbatch import FullBatchLoader
-    from veles_tpu.loader.base import TRAIN
-
-    X = np.random.default_rng(0).standard_normal((256, 1024)) \
-        .astype(np.float32)
-    ld = FullBatchLoader({TRAIN: X}, minibatch_size=16,
-                         use_pallas_gather=True)
-    ld.initialize()
-    assert ld.on_device
-    b = next(ld.iter_epoch(TRAIN, 0))
-    perm = ld.epoch_permutation(TRAIN, 0)[:16]
-    np.testing.assert_allclose(np.asarray(b["@input"]), X[perm])
-
-    db = json.load(open(os.path.join(tuned, "device_infos.json")))
-    (kind,) = db.keys()
-    keys = [k for k in db[kind]["autotune"]
-            if k.startswith("fullbatch_gather_f1024")]
-    assert keys, db[kind]["autotune"].keys()
-    assert db[kind]["autotune"][keys[0]]["winner"] in ("packed", "take")
-
-
-def test_fullbatch_gather_per_class_consistency(tuned):
-    """The pack decision is uniform across classes of one shape (keyed on
-    the full minibatch size, not the class length), and a class smaller
-    than the minibatch still gathers correctly through its own jit."""
-    from veles_tpu.loader.fullbatch import FullBatchLoader
-    from veles_tpu.loader.base import TRAIN, VALID
-
-    rng = np.random.default_rng(1)
-    X = {TRAIN: rng.standard_normal((300, 1024)).astype(np.float32),
-         VALID: rng.standard_normal((7, 1024)).astype(np.float32)}
-    ld = FullBatchLoader({k: v.copy() for k, v in X.items()},
-                         minibatch_size=16, use_pallas_gather=True)
-    ld.initialize()
-    assert ld.on_device
-    for klass in (TRAIN, VALID):
-        for i, b in enumerate(ld.iter_epoch(klass, 0)):
-            perm = ld.epoch_permutation(klass, 0)[i * 16:(i + 1) * 16]
-            got = np.asarray(b["@input"])[: len(perm)]
-            np.testing.assert_allclose(got, X[klass][perm])
-
-
-def test_dropout_and_meandisp_resolve_via_autotune(tuned):
-    """The remaining Pallas-vs-XLA switches resolve by measurement when
-    autotune is on — but ONLY where the Pallas candidate actually
-    compiles (TPU). Off-TPU it would run in interpret mode, so the build
-    stays measurement-free and resolves straight to the XLA formulation
-    (no DB entry)."""
-    import jax
-    import veles_tpu as vt
-    from veles_tpu.units import nn
-
-    d = nn.Dropout(0.3, name="drop")
-    d.prepare([vt.Spec((64, 256), jnp.float32)])
-    assert d._resolved in (True, False)
-
-    m = nn.MeanDispNormalizer(name="norm")
-    m.prepare([vt.Spec((32, 12, 12, 3), jnp.uint8)])
-    assert m._resolved in (True, False)
-
-    on_tpu = jax.devices()[0].platform == "tpu"
-    db_path = os.path.join(tuned, "device_infos.json")
-    if on_tpu:
-        db = json.load(open(db_path))
-        (kind,) = db.keys()
-        ops_seen = {k.split("|")[0] for k in db[kind]["autotune"]}
-        assert "dropout_fwd_bwd_r0.3" in ops_seen
-        assert "mean_disp_normalize" in ops_seen
-    else:
-        # foregone conclusion: XLA wins, nothing measured or persisted
-        assert d._resolved is False and m._resolved is False
-        if os.path.exists(db_path):
-            db = json.load(open(db_path))
-            ops_seen = {k.split("|")[0] for kind in db
-                        for k in db[kind].get("autotune", {})}
-            assert "dropout_fwd_bwd_r0.3" not in ops_seen
-            assert "mean_disp_normalize" not in ops_seen
-
-    root.common.autotune = False
-    d2 = nn.Dropout(0.3, name="d2")
-    d2.prepare([vt.Spec((64, 256), jnp.float32)])
-    assert d2._resolved is None  # static platform default at apply time
 
 
 def test_attention_flash_choice_via_autotune(tuned):

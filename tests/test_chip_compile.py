@@ -110,14 +110,6 @@ def _dropout(shape, dtype, rate):
     return case
 
 
-def _mean_disp(n, f):
-    def case(sh):
-        return (lambda x, m, r: pk.mean_disp_normalize(x, m, r),
-                [_sds((n, f), "uint8", sh), _sds((f,), "float32", sh),
-                 _sds((f,), "float32", sh)], 1)
-    return case
-
-
 def _gather(n, f, m):
     def case(sh):
         return (lambda d, i: pk.gather_rows(d, i),
@@ -175,7 +167,6 @@ ONE_CHIP = {
     "paged_decode_serve_geometry": _paged(8, 8, 8, 64, "float32"),
     "fused_dropout_f32_4096sq": _dropout((4096, 4096), "float32", 0.3),
     "fused_dropout_bf16_alexnet_fc": _dropout((512, 4096), "bfloat16", 0.5),
-    "mean_disp_normalize_alexnet_batch": _mean_disp(512, 227 * 227 * 3),
     "gather_rows_60000x784": _gather(60000, 784, 512),
     "softmax_xent_opt_8192x50272": _xent(8192, 50272),
     "softmax_xent_trinity_4096x25024": _xent(4096, 25024),

@@ -76,18 +76,23 @@ def test_max_unpool_roundtrip(rng):
                                np.asarray(pooled).sum(), rtol=1e-5)
 
 
-def test_lrn_reference(rng):
-    x = rng.standard_normal((2, 4, 4, 8)).astype(np.float32)
-    n, k, alpha, beta = 5, 2.0, 1e-4, 0.75
-    got = np.asarray(ops.local_response_norm(x, n=n, k=k, alpha=alpha,
-                                             beta=beta))
+def _lrn_numpy(x, n, k=2.0, alpha=1e-4, beta=0.75):
     ref = np.empty_like(x)
     C = x.shape[-1]
     for c in range(C):
         lo, hi = max(0, c - n // 2), min(C, c - n // 2 + n)
         s = np.square(x[..., lo:hi]).sum(axis=-1)
         ref[..., c] = x[..., c] / np.power(k + alpha / n * s, beta)
-    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-5)
+    return ref
+
+
+def test_lrn_reference(rng):
+    x = rng.standard_normal((2, 4, 4, 8)).astype(np.float32)
+    n, k, alpha, beta = 5, 2.0, 1e-4, 0.75
+    got = np.asarray(ops.local_response_norm(x, n=n, k=k, alpha=alpha,
+                                             beta=beta))
+    np.testing.assert_allclose(got, _lrn_numpy(x, n, k, alpha, beta),
+                               rtol=1e-4, atol=1e-5)
 
 
 def test_softmax_ce_and_mask(rng):
@@ -251,28 +256,49 @@ def test_precision_level_config_mapping():
 
 
 def test_lrn_window_methods_agree():
-    """cumsum (default), band-matmul and the reduce_window fallback must
-    agree for EVEN n (asymmetric window) as well as odd."""
+    """The band matmul and its reduce_window guard (above
+    ``_BAND_MATMUL_MAX_C`` channels) must agree with the numpy window sum
+    for EVEN n (asymmetric window) as well as odd."""
     import veles_tpu.ops.lrn as lrn_mod
     rng = np.random.default_rng(5)
-    x = jnp.asarray(rng.standard_normal((3, 12)), jnp.float32)
+    x = rng.standard_normal((3, 12)).astype(np.float32)
     for n in (2, 3, 4, 5):
-        cum = lrn_mod.local_response_norm(x, n=n)  # cumsum default
-        band = lrn_mod.local_response_norm(x, n=n, method="band")
+        ref = _lrn_numpy(x, n)
+        band = lrn_mod.local_response_norm(jnp.asarray(x), n=n)
         orig = lrn_mod._BAND_MATMUL_MAX_C
         try:
             lrn_mod._BAND_MATMUL_MAX_C = 0  # force reduce_window path
-            ref = lrn_mod.local_response_norm(x, n=n, method="band")
+            guard = lrn_mod.local_response_norm(jnp.asarray(x), n=n,
+                                                method="band")
         finally:
             lrn_mod._BAND_MATMUL_MAX_C = orig
-        for got, label in ((cum, "cumsum"), (band, "band")):
+        for got, label in ((band, "band"), (guard, "reduce_window")):
             np.testing.assert_allclose(
-                np.asarray(got), np.asarray(ref), rtol=1e-6, atol=1e-7,
+                np.asarray(got), ref, rtol=1e-6, atol=1e-7,
                 err_msg=f"n={n} {label}")
-        # band_bf16 quantizes the squared activations to bf16 before the
-        # MXU pass; the denominator damps that to well under 1% on the
-        # normalized output (the formulation's soundness argument)
-        fast = lrn_mod.local_response_norm(x, n=n, method="band_bf16")
-        np.testing.assert_allclose(
-            np.asarray(fast), np.asarray(ref), rtol=5e-3,
-            err_msg=f"n={n} band_bf16")
+
+
+def test_lrn_auto_and_band_are_one_program():
+    """``"auto"`` (what configuration files carry) and ``"band"`` name
+    the one formulation: the op traces to equal jaxprs, and the unit
+    keeps the concrete name for export."""
+    from veles_tpu.units import nn
+    x = jnp.ones((2, 5, 5, 16), jnp.float32)
+    progs = {m: str(jax.make_jaxpr(
+        lambda x, m=m: ops.local_response_norm(x, method=m))(x))
+        for m in ("auto", "band")}
+    assert progs["auto"] == progs["band"]
+    assert "dot_general" in progs["band"]
+    assert str(jax.make_jaxpr(ops.local_response_norm)(x)) == progs["band"]
+    assert nn.LRN(method="auto").method == nn.LRN().method == "band"
+
+
+@pytest.mark.parametrize("method", ["cumsum", "band_bf16"])
+def test_lrn_rejects_removed_methods(method):
+    from veles_tpu.units import nn
+    x = jnp.ones((2, 16), jnp.float32)
+    with pytest.raises(ValueError, match="'band'"):
+        ops.local_response_norm(x, method=method)
+    with pytest.raises(ValueError, match="'band'"):
+        nn.LRN(method=method)
+

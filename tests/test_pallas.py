@@ -119,18 +119,6 @@ def test_fused_dropout_grad_uses_same_mask(rng):
     np.testing.assert_allclose(np.asarray(g), expect, rtol=1e-6)
 
 
-def test_mean_disp_normalize_matches_jnp(rng):
-    x = rng.integers(0, 256, (10, 3, 5), dtype=np.uint8)
-    mean = rng.standard_normal((3, 5)).astype(np.float32) * 10 + 128
-    rdisp = (1.0 / (rng.standard_normal((3, 5)).astype(np.float32) ** 2
-                    + 1.0))
-    out = pk.mean_disp_normalize(jnp.asarray(x), jnp.asarray(mean),
-                                 jnp.asarray(rdisp), interpret=True)
-    ref = (x.astype(np.float32) - mean) * rdisp
-    np.testing.assert_allclose(np.asarray(out), ref.reshape(10, 3, 5),
-                               rtol=1e-6)
-
-
 def test_gather_rows_matches_take(rng):
     data = rng.standard_normal((40, 3, 7)).astype(np.float32)
     idx = rng.integers(0, 40, 13).astype(np.int32)

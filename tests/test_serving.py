@@ -818,16 +818,15 @@ def test_cpp_ffn_matches_jax(binary, tmp_path, rng):
     np.testing.assert_allclose(got, ref, rtol=1e-3, atol=1e-4)
 
 
-def test_cpp_lrn_band_bf16_within_tolerance(binary, tmp_path, rng):
-    """A model whose JAX forward uses the band_bf16 LRN formulation
-    exports the concrete method and still golden-matches the C++
-    runtime's exact-f32 LRN: the bf16 quantization only perturbs the
-    k + (alpha/n)*ssum denominator (~1e-6 relative at default alpha),
-    far inside the serving tolerance."""
-    wf = build_workflow("lrn_bf16_serve", [
+def test_cpp_lrn_band_within_tolerance(binary, tmp_path, rng):
+    """A model configured with the LRN's ``"auto"`` exports the concrete
+    method, ``"band"``, and its JAX forward (the band matmul) still
+    golden-matches the C++ runtime's windowed-loop LRN inside the
+    serving tolerance."""
+    wf = build_workflow("lrn_band_serve", [
         {"type": "conv_relu", "n_kernels": 8, "kx": 3, "padding": 1,
          "name": "c1"},
-        {"type": "lrn", "method": "band_bf16", "name": "lrn1"},
+        {"type": "lrn", "method": "auto", "name": "lrn1"},
         {"type": "all2all_tanh", "output_size": 16, "name": "fc1"},
         {"type": "softmax", "output_size": 4, "name": "out"},
     ])
@@ -840,7 +839,7 @@ def test_cpp_lrn_band_bf16_within_tolerance(binary, tmp_path, rng):
                    input_spec={"shape": [2, 8, 8, 3], "dtype": "float32"})
     data = load_package(pkg)
     lrn = next(u for u in data["units"] if u["name"] == "lrn1")
-    assert lrn["config"]["method"] == "band_bf16"  # concrete, exported
+    assert lrn["config"]["method"] == "band"  # concrete, exported
 
     x = rng.standard_normal((2, 8, 8, 3)).astype(np.float32)
     np.save(tmp_path / "lx.npy", x)

@@ -339,11 +339,6 @@ def _kernel_case(name):
     if name == "dropout":
         return (lambda x: pk.fused_dropout(x, 3, 0.5, interpret=True),
                 (jnp.ones((256, 128), f32),))
-    if name == "mean_disp_normalize":
-        return (lambda x, m, r: pk.mean_disp_normalize(
-            x, m, r, interpret=True),
-            (jnp.ones((128, 256), f32), jnp.zeros((256,), f32),
-             jnp.ones((256,), f32)))
     if name == "gather_rows_packed":
         packed, _, _ = pk.pack_rows(jnp.ones((16, 1024), f32))
         return (lambda p, i: pk.gather_rows_packed(p, i, interpret=True),
@@ -353,7 +348,7 @@ def _kernel_case(name):
 
 @pytest.mark.parametrize("name", [
     "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_attention_decode",
-    "dropout", "mean_disp_normalize", "gather_rows_packed"])
+    "dropout", "gather_rows_packed"])
 def test_each_pallas_kernel_carries_its_name(name):
     fn, args = _kernel_case(name)
     text = str(jax.make_jaxpr(fn)(*args))

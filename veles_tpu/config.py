@@ -246,7 +246,7 @@ def _defaults():
     root.common.timings = False
     root.common.trace_file = ""              # JSONL event trace target
     root.common.cache_dir = ".veles_tpu"
-    root.common.autotune = True              # measured per-device op picks
+    root.common.autotune = True              # attention's measured per-device pick
     root.common.snapshot_dir = "snapshots"
     # Persistent XLA compilation cache directory: "" = the fixed
     # in-checkout default (runtime/step_cache.py DEFAULT_COMPILE_CACHE);

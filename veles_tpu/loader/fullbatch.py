@@ -27,9 +27,10 @@ import numpy as np
 from ..ops import use_pallas_default
 from .base import ArrayLoader, TEST, TRAIN, VALID
 
-# Packed-DMA-gather eligibility, calibrated to the on-chip measurement
-# (bench_tpu.py gather row: 3,136-byte rows at 30% pad overhead won 1.42x
-# vs jnp.take on v5e) — don't pack below the measured-winning envelope.
+# Packed-DMA-gather eligibility, calibrated to an on-chip measurement of
+# the loader's pack→gather→unpack path (3,136-byte rows at 30% pad
+# overhead won 1.42x vs jnp.take on v5e) — don't pack below the
+# measured-winning envelope.
 _PACK_MIN_ROW_BYTES = 3072
 _PACK_MAX_PAD = 1.35
 
@@ -101,35 +102,28 @@ class FullBatchLoader(ArrayLoader):
             self._dev_data[klass] = entry
 
         # The Pallas DMA-gather kernel is the TPU default: measured on-chip
-        # with the optimization_barrier'd harness (bench_tpu.py, v5e,
-        # 512 rows of a 60k x 784 set) the per-index DMA kernel wins —
-        # 0.63 ms vs 0.89 ms for jnp.take (1.42x; gather-only — the
-        # bench row now also folds in the unpack slice, a ~1.6 MB
-        # reshape that cannot flip a 0.26 ms margin).  The earlier
-        # pre-barrier measurement that favored XLA (0.64 vs 0.84) let the
-        # chained harness fuse away XLA's output materialization; with a
-        # fair harness the winner flips, so per the reference's
-        # bench-and-persist-the-winner discipline
-        # (veles/backends.py:672-731) the default follows the platform
-        # policy, and ``use_pallas_gather=False`` forces jnp.take.
+        # with an optimization_barrier'd harness (v5e, 512 rows of a
+        # 60k x 784 set) the per-index DMA kernel wins — 0.63 ms vs
+        # 0.89 ms for jnp.take (1.42x).  So the default follows the
+        # platform policy, and ``use_pallas_gather=False`` forces
+        # jnp.take.
         use_pallas = allow_pallas and self._want_pallas()
         if use_pallas:
             # Per-index HBM→HBM DMA kernel (parity:
             # ocl/fullbatch_loader.cl fill_minibatch_data_labels).  Big
             # arrays are packed into the kernel's tiled row layout ONCE
-            # here.  Eligibility mirrors the measured winning envelope
-            # (bench_tpu.py gather row, which times the loader's full
-            # pack→gather→unpack path): the 784-feature f32 case (3.1 KB
-            # rows, padded to 1024 features = 30% HBM overhead) still won
-            # 1.42x, so rows of >= _PACK_MIN_ROW_BYTES with padding
-            # overhead <= _PACK_MAX_PAD are packed; labels, small and
-            # awkward rows stay on jnp.take.
+            # here.  Eligibility is the measured winning envelope alone:
+            # the 784-feature f32 case (3.1 KB rows, padded to 1024
+            # features = 30% HBM overhead) still won 1.42x, so rows of
+            # >= _PACK_MIN_ROW_BYTES with padding overhead
+            # <= _PACK_MAX_PAD are packed; labels, small and awkward
+            # rows stay on jnp.take.
             from ..ops.pallas_kernels import (pack_rows, gather_rows_packed,
                                               unpack_rows)
-            # packed_meta is PER (class, key): the measured decision (and
-            # even eligibility, via dtype) can differ between classes of
-            # one dataset, and the gather jit below must exactly match
-            # what its own class's arrays look like.
+            # packed_meta is PER (class, key): eligibility (via dtype)
+            # can differ between classes of one dataset, and the gather
+            # jit below must exactly match what its own class's arrays
+            # look like.
             packed_meta = {}
             for klass, entry in self._dev_data.items():
                 for key, arr in entry.items():
@@ -140,8 +134,7 @@ class FullBatchLoader(ArrayLoader):
                     # dtypes tile differently and were never benched.
                     if (arr.dtype.itemsize == 4
                             and f * 4 >= _PACK_MIN_ROW_BYTES
-                            and f_pad <= f * _PACK_MAX_PAD
-                            and self._gather_pack_wins(arr)):
+                            and f_pad <= f * _PACK_MAX_PAD):
                         packed, f, sshape = pack_rows(arr)
                         entry[key] = packed
                         packed_meta[(klass, key)] = (f, tuple(sshape))
@@ -173,49 +166,6 @@ class FullBatchLoader(ArrayLoader):
 
             self._gather = {klass: take_gather
                             for klass in self._dev_data}
-
-    def _gather_pack_wins(self, arr) -> bool:
-        """Measured per-dataset-shape decision: time the full
-        pack→gather→unpack path vs jnp.take on a sample slice of the
-        uploaded array (per-row DMA cost is row-count independent, so a
-        slice is representative) and persist the winner in the autotune
-        DB. With autotune disabled the static envelope above decides
-        alone (returns True). The decision uses the FULL minibatch size
-        even for smaller classes so every class of one dataset shape
-        agrees (the gather jits are per class, but a uniform verdict
-        keeps behavior predictable)."""
-        from ..config import root
-        if not bool(root.common.autotune):
-            return True
-        from ..runtime import autotune
-        f = int(np.prod(arr.shape[1:]))
-        bs = self.minibatch_size
-        op = f"fullbatch_gather_f{f}_{arr.dtype}_bs{bs}"
-        idx = jnp.arange(bs, dtype=jnp.int32)
-        names = ("packed", "take")
-        cached = autotune.lookup(op, names, [idx])
-        if cached is not None:  # warm start: no sample pack at all
-            return cached == "packed"
-        from ..ops.pallas_kernels import (pack_rows, gather_rows_packed,
-                                          unpack_rows)
-        n = int(min(len(arr), 4096))
-        sample = arr[:n]
-        packed, fp, sshape = pack_rows(sample)
-        # Time with a shuffled permutation, matching the production
-        # access pattern (epoch shuffles): sequential indices have a
-        # locality jnp.take can exploit that a real gather never sees,
-        # which would bias the persisted winner.
-        idx = jnp.asarray(
-            np.random.default_rng(0).permutation(n)[:bs] if n >= bs
-            else np.random.default_rng(0).integers(0, n, bs),
-            jnp.int32)
-        winner = autotune.pick(
-            op,
-            {"packed": lambda i: unpack_rows(
-                gather_rows_packed(packed, i), fp, sshape),
-             "take": lambda i: jnp.take(sample, i, axis=0)},
-            [idx], default="packed")
-        return winner == "packed"
 
     def make_batch(self, chunk: np.ndarray, klass: int):
         if not self.on_device:

@@ -3,15 +3,15 @@ docs manualrst_veles_algorithms.rst:31-60; AlexNet-style).
 
 y = x / (k + alpha/n * sum_{j in window} x_j^2)^beta over the channel axis.
 
-TPU-first implementation: the channel-window sum defaults to an **exact
-f32 cumsum difference** (two VPU passes, zero MXU time, no precision
-knob); the round-1 design — a (C, C) 0/1 **band-matrix matmul on the
-MXU** — stays selectable (``method="band"``) for A/B and for the
-reduce_window fallback above ``_BAND_MATMUL_MAX_C`` channels. A naive
-windowed reduction over the minor (lane) axis is the VPU's worst case
-(`reduce_window` measured ~1.5x slower end-to-end on AlexNet's LRN
-layers). The beta=0.75 power runs as rsqrt(y*sqrt(y)) — two sqrts
-instead of exp+log."""
+The channel-window sum is ONE formulation, ``band``: a (C, C) 0/1
+band-matrix matmul on the MXU at >= HIGH precision, with a
+``reduce_window`` guard above ``_BAND_MATMUL_MAX_C`` channels (a naive
+windowed reduction over the minor (lane) axis is the VPU's worst case).
+It won every measurement against a bf16-operand band and an f32 cumsum
+difference (TPU v5 lite, forward + backward, ms a call): 3.15 / 8.72 /
+13.63 at 512 x 55 x 55 x 96, 1.92 / 2.76 / 11.12 at 512 x 27 x 27 x 256;
+on the CPU the cumsum lost too (docs/autotune.md).  The beta=0.75 power
+runs as rsqrt(y*sqrt(y)) — two sqrts instead of exp+log."""
 
 from __future__ import annotations
 
@@ -25,30 +25,19 @@ from .linear import config_precision
 _BAND_MATMUL_MAX_C = 2048
 
 
-def _window_sum_cumsum(sq, n: int):
-    """Windowed channel sum as a cumsum difference: two exact f32 VPU
-    passes instead of a C×C matmul — no MXU time and no precision knob
-    (measured A/B against the band matmul in bench_tpu.py/profiling; the
-    band form cost ~HIGH-precision matmul FLOPs on AlexNet's LRN layers).
-    Cancellation error is O(C·eps) — negligible inside k + alpha/n·sum."""
-    half = n // 2
-    up = n - 1 - half   # window: j - i in [-half, up] (same as the band)
-    cs = jnp.cumsum(sq.astype(jnp.float32), axis=-1)
-    pads = [(0, 0)] * (sq.ndim - 1)
-    # sum_{j=i-half}^{i+up} sq[j] = cs[min(i+up, C-1)] - cs[i-half-1]
-    hi = jnp.pad(cs, pads + [(0, up)], mode="edge")[..., up:]
-    lo = jnp.pad(cs, pads + [(half + 1, 0)])[..., :cs.shape[-1]]
-    return hi - lo
+def resolve_method(method: str) -> str:
+    """The concrete name of a configured ``method``: configuration files
+    carry ``"auto"`` and ``"band"``, and both mean the one formulation."""
+    if method not in ("auto", "band"):
+        raise ValueError(
+            f"LRN has one formulation, method='band' ('auto' means the "
+            f"same); got {method!r}")
+    return "band"
 
 
-def _window_sum(sq, n: int, method: str = "cumsum"):
-    if method not in ("cumsum", "band", "band_bf16"):
-        raise ValueError(f"LRN method must be 'cumsum', 'band' or "
-                         f"'band_bf16', got {method!r}")
+def _window_sum(sq, n: int):
     c = sq.shape[-1]
     half = n // 2
-    if method == "cumsum":
-        return _window_sum_cumsum(sq, n)
     if c <= _BAND_MATMUL_MAX_C:
         idx = jnp.arange(c)
         # Asymmetric window of exactly n: out_i sums sq[j] for
@@ -57,31 +46,15 @@ def _window_sum(sq, n: int, method: str = "cumsum"):
         # output i, and (idx[None,:]-idx[:,None])[j, i] = i - j.
         diff = idx[None, :] - idx[:, None]
         mask = (diff >= -(n - 1 - half)) & (diff <= half)
-        if method == "band_bf16":
-            # Single-pass MXU rate: squared activations quantized to
-            # bf16 (~0.4% relative), 0/1 band exact in bf16, f32
-            # accumulation. Sound for LRN because the window sum only
-            # perturbs the denominator k + (alpha/n)·ssum — at AlexNet's
-            # alpha=1e-4 a 0.4% error on ssum moves y by ~1e-6 relative.
-            # This is the round-1 formulation that measured +22% AlexNet
-            # throughput before the precision floor below made the f32
-            # band cost 3 MXU passes (BASELINE.md AlexNet r3 row).
-            operand = sq.reshape(-1, c).astype(jnp.bfloat16)
-            band = mask.astype(jnp.bfloat16)
-            prec = None
-        else:
-            # The f32 C×C band contraction must not let a DEFAULT bf16
-            # MXU pass truncate the f32 squared activations SILENTLY
-            # (advisor r1): honour the precision_level knob but floor it
-            # at HIGH. Callers who accept the (benign, see above) bf16
-            # quantization say so explicitly with method="band_bf16".
-            operand = sq.reshape(-1, c)
-            band = mask.astype(sq.dtype)
-            prec = config_precision()
-            if prec == jax.lax.Precision.DEFAULT:
-                prec = jax.lax.Precision.HIGH
+        # The f32 C×C band contraction must not let a DEFAULT bf16 MXU
+        # pass truncate the f32 squared activations SILENTLY (advisor
+        # r1): honour the precision_level knob but floor it at HIGH.
+        prec = config_precision()
+        if prec == jax.lax.Precision.DEFAULT:
+            prec = jax.lax.Precision.HIGH
         return jax.lax.dot_general(
-            operand, band, (((1,), (0,)), ((), ())), precision=prec,
+            sq.reshape(-1, c), mask.astype(sq.dtype),
+            (((1,), (0,)), ((), ())), precision=prec,
             preferred_element_type=jnp.float32).reshape(sq.shape)
     pads = [(0, 0)] * (sq.ndim - 1) + [(half, n - 1 - half)]
     return jax.lax.reduce_window(
@@ -90,13 +63,11 @@ def _window_sum(sq, n: int, method: str = "cumsum"):
 
 
 def local_response_norm(x, *, n=5, k=2.0, alpha=1e-4, beta=0.75,
-                        method="cumsum"):
+                        method="band"):
     """x: (..., C). AlexNet semantics: alpha is divided by window size n.
-    ``method``: "cumsum" (default; exact f32, VPU-only), "band" (C×C 0/1
-    matmul on the MXU at >=HIGH precision) or "band_bf16" (same band at
-    single-pass MXU rate with bf16-quantized inputs + f32 accumulation —
-    benign for the LRN denominator, see _window_sum)."""
-    ssum = _window_sum(jnp.square(x), n, method)
+    ``method``: "band" (or "auto", the same): see ``resolve_method``."""
+    resolve_method(method)
+    ssum = _window_sum(jnp.square(x), n)
     y = k + (alpha / n) * ssum
     if beta == 0.75:
         out = x * jax.lax.rsqrt(y * jnp.sqrt(y))

@@ -2,11 +2,9 @@
 ocl/mean_disp_normalizer.cl + veles/mean_disp_normalizer.py:50-138 —
 (x - mean) * rdisp elementwise on uint8 input).
 
-Default path is one fused jnp expression — XLA folds the cast+sub+mul into
-surrounding ops, so a hand kernel buys nothing in a fused graph.  The
-explicit Pallas kernel (ops/pallas_kernels.mean_disp_normalize) is the
-standalone-VMEM variant for callers normalizing outside a larger jit;
-``use_pallas=True`` selects it.  Changes to the math must land in BOTH.
+One jnp expression: XLA folds the cast+sub+mul into the surrounding ops.
+A standalone Pallas kernel lost to it on the chip (512 x 227 x 227 x 3
+uint8, TPU v5 lite: XLA 1.49 ms, Pallas 5.21 ms) and is gone.
 """
 
 from __future__ import annotations
@@ -14,9 +12,5 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 
-def mean_disp_normalize(x, mean, rdisp, dtype=jnp.float32,
-                        use_pallas: bool = False):
-    if use_pallas:
-        from .pallas_kernels import mean_disp_normalize as _pallas_impl
-        return _pallas_impl(x, mean, rdisp, dtype=dtype)
+def mean_disp_normalize(x, mean, rdisp, dtype=jnp.float32):
     return (x.astype(dtype) - mean.astype(dtype)) * rdisp.astype(dtype)

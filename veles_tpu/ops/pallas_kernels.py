@@ -13,8 +13,6 @@ Reference parity (each kernel names its OpenCL/CUDA counterpart):
   splitmix32 hash of (seed, linear element index) generated *inside* the
   kernel, so mask bits never touch HBM and the backward pass can regenerate
   them exactly instead of storing the mask.
-* ``mean_disp_normalize``    — reference: ``ocl/mean_disp_normalizer.cl`` /
-  ``cuda/mean_disp_normalizer.cu`` ((uint8 x − mean) · rdisp elementwise).
 * ``gather_rows``            — reference: ``ocl/fullbatch_loader.cl``
   ``fill_minibatch_data_labels`` (minibatch gather from the on-device
   dataset by shuffled indices).  TPU version: scalar-prefetched indices
@@ -1000,7 +998,7 @@ def _dropout_kernel(seed_ref, x_ref, o_ref, *, rate, block_rows, block_cols,
     # One fmix32-style finalizer pass (add-xorshift-mul x2) is already a
     # full-avalanche mixer for counter inputs; u32 multiplies are the
     # VPU's slow op, and a second pass measurably lost to XLA's threefry
-    # on-chip (bench_tpu).  Seed is pre-whitened so consecutive seeds
+    # on-chip.  Seed is pre-whitened so consecutive seeds
     # don't produce correlated streams.
     bits = _splitmix32(lin ^ _splitmix32(seed_ref[0, 0]))
     # top 24 bits -> uniform in [0, 1); Mosaic lacks uint32->f32 casts, so
@@ -1220,53 +1218,6 @@ def _xent_vjp_bwd(interpret, res, g):
 
 
 softmax_xent_rows.defvjp(_xent_vjp_fwd, _xent_vjp_bwd)
-
-
-# ---------------------------------------------------------------------------
-# Mean/dispersion normalize
-# ---------------------------------------------------------------------------
-
-def _mean_disp_kernel(x_ref, mean_ref, rdisp_ref, o_ref):
-    o_ref[:] = ((x_ref[:].astype(jnp.float32) - mean_ref[:])
-                * rdisp_ref[:]).astype(o_ref.dtype)
-
-
-def mean_disp_normalize(x, mean, rdisp, *, block_rows=128, block_cols=4096,
-                        interpret=None, dtype=jnp.float32):
-    """(x - mean) * rdisp with x typically uint8; tiled elementwise kernel
-    (reference: ocl/mean_disp_normalizer.cl).  Columns are tiled too so
-    image-scale feature counts (e.g. 224·224·3) never exceed VMEM."""
-    orig_shape = x.shape
-    flat = x.reshape(orig_shape[0], -1)
-    if jnp.issubdtype(flat.dtype, jnp.unsignedinteger):
-        # Mosaic has no unsigned->float casts; widen outside (XLA fuses the
-        # widening into the producing gather/copy).
-        flat = flat.astype(jnp.int32)
-    rows, cols = flat.shape
-    mean_f = mean.reshape(1, -1).astype(jnp.float32)
-    rdisp_f = rdisp.reshape(1, -1).astype(jnp.float32)
-    block_rows = min(block_rows, rows)
-    block_cols = min(block_cols, _round_up(cols, 128))
-    rows_p = _round_up(rows, block_rows)
-    cols_p = _round_up(cols, block_cols)
-    flat = jnp.pad(flat, ((0, rows_p - rows), (0, cols_p - cols)))
-    mean_f = jnp.pad(mean_f, ((0, 0), (0, cols_p - cols)))
-    rdisp_f = jnp.pad(rdisp_f, ((0, 0), (0, cols_p - cols)))
-    out = pl.pallas_call(
-        _mean_disp_kernel,
-        grid=(rows_p // block_rows, cols_p // block_cols),
-        in_specs=[
-            pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block_cols), lambda i, j: (0, j)),
-            pl.BlockSpec((1, block_cols), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, block_cols),
-                               lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((rows_p, cols_p), dtype),
-        interpret=_interpret(interpret),
-        name="mean_disp_normalize",
-    )(flat, mean_f, rdisp_f)
-    return out[:rows, :cols].reshape(orig_shape)
 
 
 # ---------------------------------------------------------------------------

@@ -3,18 +3,19 @@
 Reference parity: veles/backends.py:672-731 — the OpenCL backend swept
 gemm block sizes (3 reps, size 3001) per device and persisted the winner
 to ``devices/device_infos.json``, reused on every later run. Generalized
-here for the TPU build: any op with several mathematically-equivalent
-formulations (LRN band-matmul vs cumsum-difference, Pallas kernel vs XLA
-expression, ...) asks :func:`pick` for the measured winner on THIS device
+here for the TPU build: an op with several mathematically-equivalent
+formulations asks :func:`pick` for the measured winner on THIS device
 for THIS shape class; winners persist under the ``autotune`` key of the
 same per-device-kind DB the gemm benchmark uses
-(``runtime/benchmark.py``).
+(``runtime/benchmark.py``).  One op does (docs/autotune.md): attention,
+flash kernel and block shape against XLA (``MultiHeadAttention.prepare``).
+Every other Pallas-vs-XLA choice follows ``ops.use_pallas_default``.
 
-Measurement methodology matches ``bench_tpu.py``: repetitions are chained
-INSIDE one jit with an ``optimization_barrier`` and a denormal feedback
-term, so the launch cost is amortized and XLA can neither fold
-repetitions nor skip materializing outputs (a harness without the
-barrier once mis-decided two kernel defaults — BASELINE.md).
+Measurement methodology: repetitions are chained INSIDE one jit with an
+``optimization_barrier`` and a denormal feedback term, so the launch
+cost is amortized and XLA can neither fold repetitions nor skip
+materializing outputs (a harness without the barrier once mis-decided
+two kernel defaults — BASELINE.md).
 """
 
 from __future__ import annotations
@@ -56,8 +57,7 @@ def measure(fn: Callable, args: Sequence, reps: int = 4,
 
     # Chain the inter-rep data dependence through the SMALLEST argument
     # so the chain edge itself is nearly free (threading it through a
-    # large operand would add a full HBM pass per repetition —
-    # bench_tpu.py's harness note).
+    # large operand would add a full HBM pass per repetition).
     j = int(np.argmin([int(np.prod(getattr(a, "shape", ()) or (1,)))
                        for a in args]))
 

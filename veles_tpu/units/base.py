@@ -156,9 +156,9 @@ class Unit(Logger, metaclass=UnitMeta):
     def prepare(self, in_specs: Sequence[Spec]) -> None:
         """Build-time hook: called once by Workflow.build with resolved
         input specs, OUTSIDE any jit trace — the place for shape-dependent
-        decisions that must not happen during tracing (e.g. resolving an
-        ``"auto"`` formulation via runtime.autotune, which times real
-        device executions). Default: nothing."""
+        decisions that must not happen during tracing (attention's
+        measured pick via runtime.autotune, which times real device
+        executions). Default: nothing."""
 
     def output_spec(self, in_specs: Sequence[Spec]) -> Spec:
         """Shape/dtype inference. Default: identity on the first input."""

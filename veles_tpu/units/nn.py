@@ -267,10 +267,12 @@ class Dropout(Unit):
     RNG = jax threefry via ctx.unit_key, replacing ocl/random.cl's
     xorshift1024* states).
 
-    use_pallas: True/False forces a formulation; None = measure both
-    fwd+bwd at the build shape and persist the winner (autotune;
-    barrier'd v5e measurement at 4096x4096: Pallas 1.13x), falling back
-    to the static platform default when autotune is disabled."""
+    use_pallas: True/False forces a formulation; None follows the
+    platform (``ops.use_pallas_default``): the fused kernel on a TPU,
+    ``jax.random.bernoulli`` elsewhere.  Nothing is measured: on the
+    chip the kernel ties or wins (0.1645 against 0.1651 ms forward +
+    backward at 512 x 4096 in one checkout, 0.1145 against 0.176 in
+    another; TPU v5 lite, docs/autotune.md)."""
 
     stochastic = True
 
@@ -279,64 +281,12 @@ class Dropout(Unit):
         super().__init__(name, inputs)
         self.ratio = float(dropout_ratio)
         self.use_pallas = use_pallas
-        self._resolved = use_pallas
-
-    def prepare(self, in_specs):
-        from ..config import root
-        if self.use_pallas is not None:
-            self._resolved = self.use_pallas
-            return
-        if not bool(root.common.autotune):
-            self._resolved = None  # static platform default at apply
-            return
-        if not ops.use_pallas_default():
-            # Off-TPU the Pallas candidate runs in interpret mode — timing
-            # it is a foregone conclusion; keep off-TPU builds
-            # measurement-free.
-            self._resolved = False
-            return
-        from ..runtime import autotune
-        spec = in_specs[0]
-        ratio, keep = self.ratio, 1.0 - self.ratio
-        op = f"dropout_fwd_bwd_r{ratio}"
-        specs = [jax.ShapeDtypeStruct(spec.shape, spec.dtype),
-                 jax.ShapeDtypeStruct((), jnp.uint32)]
-        names = ("pallas", "xla")
-        cached = autotune.lookup(op, names, specs)
-        if cached is not None:  # warm start: no arrays materialized
-            self._resolved = cached == "pallas"
-            return
-        x = jnp.asarray(np.random.default_rng(0).standard_normal(
-            spec.shape), spec.dtype)
-        seed = jnp.uint32(123)
-        key = jax.random.key(0)
-
-        def g(f):
-            # value_and_grad, both outputs returned: plain grad discards
-            # the primal and the fused kernel's forward would be
-            # dead-code-eliminated (its vjp residual is just the seed),
-            # timing half the real training cost.
-            def timed(x, s):
-                v, gx = jax.value_and_grad(
-                    lambda x: jnp.sum(f(x, s).astype(jnp.float32)))(x)
-                return v, gx
-            return timed
-
-        winner = autotune.pick(
-            op,
-            {"pallas": g(lambda x, s: ops.fused_dropout(x, s, ratio)),
-             "xla": g(lambda x, s: jnp.where(
-                 jax.random.bernoulli(jax.random.fold_in(key, s), keep,
-                                      x.shape),
-                 x / keep, 0.0).astype(x.dtype))},
-            [x, seed], default="pallas")
-        self._resolved = winner == "pallas"
 
     def uses_kernel(self) -> bool:
         """The fused Pallas kernel (True) or ``jax.random`` (False): the
-        forced or measured pick, else the platform default."""
-        return ops.use_pallas_default() if self._resolved is None \
-            else bool(self._resolved)
+        forced choice, else the platform default."""
+        return ops.use_pallas_default() if self.use_pallas is None \
+            else bool(self.use_pallas)
 
     def apply(self, params, state, xs, ctx):
         x = xs[0]
@@ -376,134 +326,31 @@ class Dropout(Unit):
 class LRN(Unit):
     """Local response normalization across channels.
 
-    method: "cumsum" (default — stable across devices, keeps test
-    numerics fixed) | "band" (see ops/lrn.py) | "auto" — measure both
-    formulations fwd+bwd on the actual device at build time and persist
-    the winner per (device kind, shape) in the autotune DB (the
-    reference's per-device bench-and-persist discipline,
-    veles/backends.py:672-731; motivated by a real regression where a
-    hand-picked default cost ~40% AlexNet throughput on v5e —
-    BASELINE.md AlexNet r3 row)."""
+    method: "band", the one formulation (ops/lrn.py: a C x C 0/1 matmul
+    at >= HIGH precision).  "auto" is accepted because configuration
+    files carry it and means the same; the unit keeps the concrete name,
+    so an exported package never carries "auto"."""
 
     def __init__(self, n=5, k=2.0, alpha=1e-4, beta=0.75, name=None,
-                 inputs=("@input",), method="cumsum"):
+                 inputs=("@input",), method="band"):
         super().__init__(name, inputs)
         self.n, self.k, self.alpha, self.beta = n, k, alpha, beta
-        self.method = method
-        self._resolved = method if method != "auto" else None
-
-    def prepare(self, in_specs):
-        if self.method != "auto":
-            self._resolved = self.method
-            return
-        from ..config import root
-        from ..runtime import autotune
-        spec = in_specs[0]
-        op = f"lrn_fwd_bwd_n{self.n}_b{self.beta}"
-        names = ("cumsum", "band", "band_bf16")
-        if not bool(root.common.autotune):
-            self._resolved = "cumsum"
-            self.method = self._resolved
-            return
-        cached = autotune.lookup(
-            op, names, [jax.ShapeDtypeStruct(spec.shape, spec.dtype)])
-        if cached is not None:  # warm start: no arrays materialized
-            self._resolved = cached
-            self.method = cached
-            return
-        x = jnp.asarray(
-            np.random.default_rng(0).standard_normal(spec.shape),
-            spec.dtype)
-
-        def run(method):
-            # Time the training cost: forward + backward, like the unit
-            # executes inside the train step. value_and_grad (not grad):
-            # returning the primal too keeps the whole forward alive
-            # under DCE.
-            def f(x):
-                return jax.value_and_grad(lambda x: jnp.sum(
-                    ops.local_response_norm(
-                        x, n=self.n, k=self.k, alpha=self.alpha,
-                        beta=self.beta, method=method)
-                    .astype(jnp.float32)))(x)
-            return f
-
-        # n/beta in the key: band's C x C matmul cost is n-independent
-        # while cumsum's isn't, so different windows may have different
-        # winners even at one shape
-        self._resolved = autotune.pick(
-            op,
-            {"cumsum": run("cumsum"), "band": run("band"),
-             "band_bf16": run("band_bf16")},
-            [x], default="cumsum")
-        # expose the concrete choice (export serializes `method`; the
-        # serving runtime must never see "auto")
-        self.method = self._resolved
+        self.method = ops.lrn.resolve_method(method)
 
     def apply(self, params, state, xs, ctx):
-        method = self._resolved or self.method
-        if method == "auto":
-            raise RuntimeError(
-                f"LRN {self.name!r} has method='auto' but prepare() was "
-                "never called — build the workflow (Workflow.build calls "
-                "prepare), or propagate prepare() from the composite "
-                "unit wrapping this one, or set a concrete method")
         return ops.local_response_norm(
             xs[0], n=self.n, k=self.k, alpha=self.alpha, beta=self.beta,
-            method=method), state
+            method=self.method), state
 
 
 class MeanDispNormalizer(Unit):
     """(x - mean) * rdisp with dataset statistics stored in unit state
     (reference: veles/mean_disp_normalizer.py:50-138)."""
 
-    def __init__(self, mean=None, rdisp=None, name=None, inputs=("@input",),
-                 use_pallas=None):
+    def __init__(self, mean=None, rdisp=None, name=None, inputs=("@input",)):
         super().__init__(name, inputs)
         self._mean = mean
         self._rdisp = rdisp
-        # None = autotune at build shape (static XLA default when
-        # disabled — the barrier'd v5e measurement has XLA 2.5x ahead on
-        # this op, but the winner is persisted per shape, not assumed);
-        # True/False forces.
-        self.use_pallas = use_pallas
-        self._resolved = use_pallas
-
-    def prepare(self, in_specs):
-        from ..config import root
-        if self.use_pallas is not None or not bool(root.common.autotune):
-            self._resolved = self.use_pallas
-            return
-        if not ops.use_pallas_default():
-            # interpret-mode Pallas off-TPU: skip the measurement
-            self._resolved = False
-            return
-        from ..runtime import autotune
-        spec = in_specs[0]
-        feat = spec.shape[1:]
-        specs = [jax.ShapeDtypeStruct(spec.shape, spec.dtype),
-                 jax.ShapeDtypeStruct(feat, jnp.float32),
-                 jax.ShapeDtypeStruct(feat, jnp.float32)]
-        names = ("xla", "pallas")
-        cached = autotune.lookup("mean_disp_normalize", names, specs)
-        if cached is not None:  # warm start: no arrays materialized
-            self._resolved = cached == "pallas"
-            return
-        rng = np.random.default_rng(0)
-        x = jnp.asarray(
-            rng.integers(0, 256, spec.shape)
-            if np.issubdtype(np.dtype(spec.dtype), np.integer)
-            else rng.standard_normal(spec.shape), spec.dtype)
-        mean = jnp.asarray(rng.uniform(100, 150, feat), jnp.float32)
-        rdisp = jnp.asarray(rng.uniform(0.01, 0.02, feat), jnp.float32)
-        winner = autotune.pick(
-            "mean_disp_normalize",
-            {"xla": lambda x, m, r: ops.mean_disp_normalize(
-                x, m, r, use_pallas=False),
-             "pallas": lambda x, m, r: ops.mean_disp_normalize(
-                 x, m, r, use_pallas=True)},
-            [x, mean, rdisp], default="xla")
-        self._resolved = winner == "pallas"
 
     def output_spec(self, in_specs):
         return Spec(in_specs[0].shape, jnp.float32)
@@ -518,8 +365,7 @@ class MeanDispNormalizer(Unit):
 
     def apply(self, params, state, xs, ctx):
         return ops.mean_disp_normalize(
-            xs[0], state["mean"], state["rdisp"],
-            use_pallas=bool(self._resolved)), state
+            xs[0], state["mean"], state["rdisp"]), state
 
 
 class Flatten(Unit):

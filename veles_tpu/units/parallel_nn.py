@@ -570,8 +570,8 @@ class PipelineStack(Forward):
     def prepare(self, in_specs):
         # Composite unit: Workflow.build only calls prepare() on
         # top-level units, so the stack must propagate it to its stage
-        # sub-units (an LRN with method="auto" inside a stage resolves
-        # here, never reaching trace/export as "auto").
+        # sub-units (an attention unit inside a stage measures its pick
+        # here).
         if self._stage_units is not None:
             self._thread_stage_specs(
                 in_specs[0], lambda u, s: u.prepare([s]))
