@@ -378,7 +378,7 @@ def _kernel_runs_in_chain(probe, args, monkeypatch):
     text = jax.jit(chain[0]).lower(*args).compile().as_text()
     return collections.Counter(
         name for line in text.splitlines() if " while(" in line
-        for name in re.findall(r"op_name=\"[^\"]*?\((flash_\w+?)\)+/while",
+        for name in re.findall(r"op_name=\"[^\"]*?/(flash_\w+?)/while",
                                line))
 
 

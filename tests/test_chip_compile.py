@@ -77,10 +77,10 @@ def _value_and_grads(f):
     return g
 
 
-def _flash(B, T, H, Hk, D, window=None, grad=True):
+def _flash(B, T, H, Hk, D, window=None, grad=True, blocks=(256, 1024)):
     def case(sh):
         f = lambda q, k, v: pk.flash_attention(  # noqa: E731
-            q, k, v, True, None, window=window)
+            q, k, v, True, None, *blocks, window=window)
         return (_value_and_grads(f) if grad else f,
                 [_sds((B, T, H, D), "bfloat16", sh),
                  _sds((B, T, Hk, D), "bfloat16", sh),
@@ -144,8 +144,16 @@ def _xent(rows, classes, dtype="float32"):
     return case
 
 
-def _flash_gqa_d128(window):
-    return _flash(1, 4096, 32, 4, 128, window=window)
+def _flash_gqa_d128(window, **kw):
+    return _flash(1, 4096, 32, 4, 128, window=window, **kw)
+
+
+def _flash_opt(B, T, blocks):
+    """The OPT cells' attention, two D = 64 heads a grid step out of
+    (B, T, 1024), at a block shape the probe measures."""
+    assert pk.flash_heads_per_step((B, T, 16, 64), (B, T, 16, 64),
+                                   *blocks) == 2
+    return _flash(B, T, 16, 16, 64, blocks=blocks)
 
 
 #: name -> builder(sharding) -> (function, argument shapes, kernels expected)
@@ -158,6 +166,16 @@ ONE_CHIP = {
         34816, 16, 1024, 2048),
     "flash_fwd_bwd_t4096_h32_kv4_d128_window2048": _flash_gqa_d128(2048),
     "flash_fwd_bwd_t4096_h32_kv4_d128_full": _flash_gqa_d128(None),
+    "flash_fwd_bwd_trinity_window_512x512": _flash_gqa_d128(
+        2048, blocks=(512, 512)),
+    "flash_fwd_bwd_trinity_full_1024x1024": _flash_gqa_d128(
+        None, blocks=(1024, 1024)),
+    "flash_fwd_bwd_opt_t2048_256x1024": _flash_opt(4, 2048, (256, 1024)),
+    "flash_fwd_bwd_opt_t2048_512x512": _flash_opt(4, 2048, (512, 512)),
+    "flash_fwd_bwd_opt_t2048_1024x512": _flash_opt(4, 2048, (1024, 512)),
+    "flash_fwd_bwd_opt_t2048_1024x1024": _flash_opt(4, 2048, (1024, 1024)),
+    "flash_fwd_bwd_opt_t512_512x512": _flash_opt(16, 512, (512, 512)),
+    "flash_fwd_bwd_transposed_d80": _flash(2, 1024, 8, 8, 80),
     "flash_fwd_b16_t2048": _flash(16, 2048, 8, 8, 64, grad=False),
     "flash_fwd_bwd_b16_t2048": _flash(16, 2048, 8, 8, 64),
     "flash_fwd_bwd_t4096_d128": _flash(1, 4096, 8, 8, 128),
@@ -184,6 +202,28 @@ def _attention_site(mesh, batch_sh):
     u = MultiHeadAttention(8, name="attn", rope=True, residual=True,
                            use_flash=True)
     spec = Spec((16, 2048, 512), jnp.bfloat16)
+    params, _ = jax.eval_shape(lambda k: u.init(k, [spec]),
+                               jax.random.key(0))
+
+    def f(params, x):
+        ctx = Context(train=True, key=None, mesh=mesh)
+        return u.apply(params, {}, [x], ctx)[0]
+
+    rep = NamedSharding(mesh, P())
+    return (_value_and_grads(f),
+            [jax.tree.map(lambda s: _sds(s.shape, s.dtype, rep), params),
+             _sds(spec.shape, spec.dtype, batch_sh)], 3)
+
+
+def _attention_gqa_site(mesh, batch_sh):
+    """Grouped queries at D = 128 with a window, QK-norm and a gate, as
+    the Trinity cell's sliding layers: one head a grid step over
+    transposed copies, K/V's index map names the shared kv head's row
+    (under ``model=2`` each device holds 4 q heads and 1 kv head)."""
+    u = MultiHeadAttention(8, head_dim=128, n_kv_heads=2, name="attn",
+                           rope=True, window=1024, qk_norm=True, gate=True,
+                           use_flash=True)
+    spec = Spec((8, 2048, 512), jnp.bfloat16)
     params, _ = jax.eval_shape(lambda k: u.init(k, [spec]),
                                jax.random.key(0))
 
@@ -230,6 +270,9 @@ CALL_SITES = {
     "attention_data4": (MeshSpec(data=4), _attention_site),
     "attention_data2_fsdp2": (MeshSpec(data=2, fsdp=2), _attention_site),
     "attention_data2_model2": (MeshSpec(data=2, model=2), _attention_site),
+    "attention_gqa_data4": (MeshSpec(data=4), _attention_gqa_site),
+    "attention_gqa_data2_model2": (MeshSpec(data=2, model=2),
+                                   _attention_gqa_site),
     "dropout_data4": (MeshSpec(data=4), _dropout_site),
     "dropout_data2_fsdp2": (MeshSpec(data=2, fsdp=2), _dropout_site),
     "evaluator_data4": (MeshSpec(data=4), _evaluator_site),

@@ -369,6 +369,143 @@ def test_flash_tile_classes(rng, case):
                                    rtol=2e-3, atol=2e-4)
 
 
+# -- flash attention in the projections' own layout ---------------------------
+
+# ((B, T, H, H_kv, D), (block_q, block_k), causal, window) -> heads a grid
+# step takes out of (B, T, H * D); 0: the transposed copies
+_LAYOUT_CASES = {
+    "d64_mha_two_heads": ((2, 64, 4, 4, 64), (16, 32), True, None, 2),
+    "d64_mha_noncausal": ((1, 32, 2, 2, 64), (16, 16), False, None, 2),
+    "d32_mha_four_heads": ((1, 32, 4, 4, 32), (16, 16), True, None, 4),
+    "d16_mha_eight_heads": ((1, 32, 8, 8, 16), (16, 16), True, None, 8),
+    "d128_gqa_32_4": ((1, 32, 32, 4, 128), (16, 16), True, None, 0),
+    "d128_gqa_32_4_window": ((1, 48, 32, 4, 128), (16, 16), True, 24, 0),
+    "d128_mha_noncausal": ((1, 32, 2, 2, 128), (16, 16), False, None, 0),
+    "d80": ((1, 32, 2, 2, 80), (16, 16), True, None, 0),
+    "d64_odd_heads": ((1, 32, 3, 3, 64), (16, 16), True, None, 0),
+    "d64_gqa": ((1, 32, 4, 2, 64), (16, 16), True, None, 0),
+    "d64_ragged_t": ((1, 40, 2, 2, 64), (16, 16), True, None, 0),
+    "d128_ragged_t": ((1, 40, 2, 1, 128), (16, 16), True, 16, 0),
+}
+
+
+@pytest.fixture
+def transposed_path(monkeypatch):
+    """Steer a call the lanes path would take onto the transposed copies,
+    to compare the two: nothing in the program selects the layout but the
+    shapes, so the test replaces the rule (and drops the jitted calls'
+    traces, which hold the path they were traced with)."""
+    def clear():
+        pk._flash_fwd.clear_cache()
+        pk._flash_bwd.clear_cache()
+
+    def steer():
+        monkeypatch.setattr(pk, "flash_heads_per_step", lambda *a: 0)
+        clear()
+    yield steer
+    monkeypatch.undo()
+    clear()
+
+
+@pytest.mark.parametrize("case", sorted(_LAYOUT_CASES))
+def test_flash_layout_rule(case):
+    (B, T, H, Hk, D), blocks, _, _, heads = _LAYOUT_CASES[case]
+    assert pk.flash_heads_per_step((B, T, H, D), (B, T, Hk, D),
+                                   *blocks) == heads
+
+
+@pytest.mark.parametrize("shape,blocks,heads", [
+    ((4, 2048, 16, 16, 64), (1024, 1024), 2),     # opt350m_train_t2048
+    ((16, 512, 16, 16, 64), (512, 512), 2),       # opt350m_train_t512
+    ((1, 4096, 32, 4, 128), (512, 512), 0),       # trinity_mini_train_t4096
+    ((4, 2048, 16, 16, 64), (256, 1024), 2),      # the default blocks
+    ((4, 2000, 16, 16, 64), (256, 1024), 0),      # T the blocks do not divide
+    ((4, 2048, 16, 8, 64), (512, 512), 0),        # GQA below 128 lanes
+    ((4, 2048, 20, 20, 96), (512, 512), 0),       # D neither divides 128
+    ((1, 4096, 8, 8, 256), (512, 512), 0),        # whole lane blocks a head
+    ((2, 1024, 8, 8, 32), (512, 512), 4),         # four heads a lane block
+])
+def test_flash_layout_rule_at_the_cells_shapes(shape, blocks, heads):
+    B, T, H, Hk, D = shape
+    assert pk.flash_heads_per_step((B, T, H, D), (B, T, Hk, D),
+                                   *blocks) == heads
+
+
+@pytest.mark.parametrize("case", sorted(_LAYOUT_CASES))
+def test_flash_layout_matches_reference(rng, case, transposed_path):
+    """Forward, dq, dk and dv of a call on the path its shapes give it:
+    against the dense float32 reference, and, where it reads the
+    projections' own layout, against the same call over transposed
+    copies; ``vt_flash_layout`` says which path it took."""
+    from veles_tpu.runtime.metrics import registry
+    (B, T, H, Hk, D), (bq, bk), causal, window, heads = _LAYOUT_CASES[case]
+    q = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((B, T, Hk, D)), jnp.float32)
+            for _ in range(2))
+
+    def loss(attend):
+        return lambda q, k, v: jnp.sum(jnp.square(attend(q, k, v)))
+
+    def flash(q, k, v):
+        return pk.flash_attention(q, k, v, causal, None, bq, bk, True,
+                                  window)
+
+    def dense(q, k, v):
+        return _dense_reference(q, k, v, causal, window)
+
+    def run():
+        return (flash(q, k, v),
+                *jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v))
+
+    got = run()
+    layout = registry().get("vt_flash_layout")
+    per_step = registry().get("vt_flash_heads_per_step")
+    for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert layout.labels(kernel=kernel, layout="lanes").value \
+            == int(heads > 0)
+        assert layout.labels(kernel=kernel, layout="transposed").value \
+            == int(heads == 0)
+        assert per_step.labels(kernel=kernel).value == max(heads, 1)
+    want = (dense(q, k, v),
+            *jax.grad(loss(dense), argnums=(0, 1, 2))(q, k, v))
+    for a, b, tol in zip(got, want, (2e-4, 2e-3, 2e-3, 2e-3)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=tol, atol=tol / 10)
+    if not heads:
+        return
+    transposed_path()
+    for a, b in zip(got, run()):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def _tpu_custom_calls(fn, *shapes, grad=True):
+    """``tpu_custom_call``s in the module ``fn`` (its gradient) lowers to
+    for a TPU, on this CPU: each is one Mosaic lowering."""
+    if grad:
+        fn = jax.grad(lambda *a, _f=fn: jnp.sum(_f(*a).astype(jnp.float32)),
+                      argnums=(0, 1, 2))
+    args = [jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes]
+    return jax.jit(fn).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("grad,distinct,calls", [
+    (True, 1, 3), (False, 1, 1), (True, 2, 6)])
+def test_flash_equal_layers_lower_once(grad, distinct, calls):
+    """Twelve equal attention layers hold one lowering of each kernel,
+    not twelve (0.1 s each at every start: PERF.md section 6, PR 33); a
+    second shape (a window) brings its own three."""
+    def stack(q, k, v):
+        for i in range(12):
+            window = 128 if distinct == 2 and i % 2 else None
+            q = q + pk.flash_attention(q, k, v, True, None, 128, 128,
+                                       False, window)
+        return q
+    shape = (1, 256, 4, 64)
+    assert _tpu_custom_calls(stack, shape, shape, shape, grad=grad) == calls
+
+
 # -- paged-attention decode kernel -------------------------------------------
 
 def _paged_reference(q, pool_k, pool_v, ptab, pos, window=None):

@@ -249,6 +249,40 @@ def test_rope_properties(rng):
                                rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("D,lanes", [(8, 16), (64, 2), (128, 0), (80, 0),
+                                     (256, 0)])
+def test_rope_formulations_agree(rng, D, lanes):
+    """Heads that share a 128-lane block rotate on (B, T, H * D) by a lane
+    roll, the others with their pairs split out: both are the rotation of
+    pairs (x[2i], x[2i+1]) by pos / base^(2i/D), value and gradient."""
+    import jax
+    from veles_tpu.ops import rotary_embedding
+    from veles_tpu.ops.activations import heads_per_lane_block
+    assert heads_per_lane_block(D) == lanes
+    x = jnp.asarray(rng.standard_normal((2, 12, 3, D)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal(x.shape), jnp.float32)
+
+    def plain(x, offset):
+        half = D // 2
+        ang = (offset + np.arange(12, dtype=np.float32))[:, None] \
+            * (10000.0 ** (-np.arange(half, dtype=np.float32) / half))
+        cos, sin = np.cos(ang)[None, :, None], np.sin(ang)[None, :, None]
+        pairs = x.reshape(2, 12, 3, half, 2)
+        a, b = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                         axis=-1).reshape(x.shape)
+
+    for offset in (0, 5):
+        np.testing.assert_allclose(
+            np.asarray(rotary_embedding(x, offset=offset)),
+            np.asarray(plain(x, offset)), rtol=1e-5, atol=1e-5)
+        got, want = (jax.grad(lambda x, f=f: jnp.sum(f(x) * w))(x)
+                     for f in (lambda x: rotary_embedding(x, offset=offset),
+                               lambda x: plain(x, offset)))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+
 def test_attention_unit_rope_trains(rng):
     import veles_tpu as vt
     from veles_tpu.models.standard import build_workflow, build_optimizer

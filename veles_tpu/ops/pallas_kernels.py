@@ -28,7 +28,7 @@ from __future__ import annotations
 import functools
 import math
 import operator
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import (use_pallas_default,  # policy lives pallas-free in ops/__init__
                check_attention_window, check_gqa_heads)
+from .activations import heads_per_lane_block
 
 def _interpret(interpret: Optional[bool]) -> bool:
     """``None`` follows the backend: compiled through Mosaic on a TPU, the
@@ -75,17 +76,46 @@ def _lanes(x, n):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
+def _head_lanes(x, h, heads, head_dim):
+    """Tile ``x`` (rows, heads * head_dim), ``heads`` heads side by side in
+    the lanes, with every lane outside head ``h`` zeroed: a product that
+    contracts the whole lane width then sees head ``h`` alone, at the MXU
+    passes a ``head_dim``-wide product costs anyway (head_dim < 128 fills
+    the array's depth by half).  ``x`` itself when the tile is one head."""
+    if heads == 1:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    keep = (lane >= h * head_dim) & (lane < (h + 1) * head_dim)
+    return jnp.where(keep, x, jnp.zeros_like(x))
+
+
+def _by_head(xs, head_dim):
+    """One (rows, len(xs) * head_dim) tile whose lanes of head ``h`` are
+    ``xs[h]``'s (each ``xs[h]`` that wide, valid in head ``h``'s lanes or
+    lane-replicated row state)."""
+    out = xs[-1]
+    if len(xs) > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+        for h in reversed(range(len(xs) - 1)):
+            out = jnp.where(lane < (h + 1) * head_dim, xs[h], out)
+    return out
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                       acc_ref, *,
                       scale, causal, window, block_q, block_k, tq, tk,
-                      n_kb):
-    """Grid = (BH, n_q_blocks, n_k_blocks); the k dimension is minor, so
-    VMEM holds only one (block_q, D) Q tile and one (block_k, D) K/V tile at
-    a time — the m/l/acc online-softmax state lives in scratch that persists
-    across the sequentially-iterated k steps (long T streams from HBM
-    block-by-block instead of residing whole in VMEM).  ``scale`` is None
-    when the caller folded it into Q (``_flash_scale``)."""
-    qi, kj = pl.program_id(1), pl.program_id(2)
+                      n_kb, heads, head_dim):
+    """Grid = (batch, head step, n_q_blocks, n_k_blocks); the k dimension
+    is minor, so VMEM holds only one (block_q, W) Q tile and one
+    (block_k, W) K/V tile at a time — the m/l/acc online-softmax state
+    lives in scratch that persists across the sequentially-iterated k
+    steps (long T streams from HBM block-by-block instead of residing
+    whole in VMEM).  A tile is ``heads`` heads of ``head_dim`` side by
+    side (W = heads * head_dim; ``_flash_addressing``): each has its own
+    row state, all share ``acc``.  ``scale`` is None when the caller
+    folded it into Q (``_flash_scale``)."""
+    qi, kj = pl.program_id(2), pl.program_id(3)
+    width = acc_ref.shape[-1]
 
     @pl.when(kj == 0)
     def _init():
@@ -98,51 +128,58 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         # the bf16 rate); accumulation is forced to f32 via
         # preferred_element_type — casting to f32 first would silently run
         # the matmuls at the several-times-slower f32 MXU rate.
-        q = q_ref[0]  # (block_q, D)
-        k_blk = k_ref[0]  # (block_k, D)
+        q = q_ref[0]  # (block_q, W)
+        k_blk = k_ref[0]  # (block_k, W)
         v_blk = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if scale is not None:
-            s = s * scale
         mask = _flash_tile_mask(
             qi, kj, causal=causal, window=window, block_q=block_q,
             block_k=block_k, tq=tq, tk=tk, rows=False) if masked else None
-        if mask is not None:
-            s = jnp.where(mask, s, -1e30)
-        # Row state m/l is (block_q, _LANES), lane-replicated (_lanes); the
-        # keepdims reductions broadcast into it.
-        m = m_ref[:]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - _lanes(m_new, block_k))
-        if mask is not None:
-            p = jnp.where(mask, p, 0.0)
-        m_ref[:] = m_new
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * _lanes(alpha, acc_ref.shape[-1]) \
-            + jax.lax.dot_general(
-                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+        alphas, pvs = [], []
+        for h in range(heads):
+            s = jax.lax.dot_general(
+                _head_lanes(q, h, heads, head_dim), k_blk,
+                (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
+            if scale is not None:
+                s = s * scale
+            if mask is not None:
+                s = jnp.where(mask, s, -1e30)
+            # Row state m/l is (block_q, _LANES) a head, lane-replicated
+            # (_lanes); the keepdims reductions broadcast into it.
+            m = m_ref[h]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - _lanes(m_new, block_k))
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            m_ref[h] = m_new
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+            alphas.append(_lanes(alpha, width))
+            # (block_q, W): head h's lanes are its P @ V
+            pvs.append(jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        acc_ref[:] = acc_ref[:] * _by_head(alphas, head_dim) \
+            + _by_head(pvs, head_dim)
 
     _flash_by_tile_class(_step, qi, kj, causal=causal, window=window,
                          block_q=block_q, block_k=block_k, tq=tq, tk=tk)
 
     @pl.when(kj == n_kb - 1)
     def _finalize():
-        denom = jnp.maximum(l_ref[:], 1e-30)
-        o_ref[0] = (acc_ref[:] / _lanes(denom, acc_ref.shape[-1])
-                    ).astype(o_ref.dtype)
+        denoms = [jnp.maximum(l_ref[h], 1e-30) for h in range(heads)]
+        o_ref[0] = (acc_ref[:] / _by_head(
+            [_lanes(d, width) for d in denoms], head_dim)).astype(o_ref.dtype)
         # logsumexp per row, consumed by the Pallas backward kernels, kept
-        # lane-replicated: (BH, T, _LANES) is what a (BH, T, 1) array
+        # lane-replicated: (.., T, _LANES) is what a (.., T, 1) array
         # occupies under the (8, 128) tiling anyway.
-        lse_ref[0] = m_ref[:] + jnp.log(denom)
+        for h in range(heads):
+            lse_ref[0, h] = m_ref[h] + jnp.log(denoms[h])
 
 
-def _flash_layout(x, T, t_p):
+def _flash_layout(x, t_p):
     """(B, T, H, D) -> (B*H, t_p, D) with the T axis zero-padded."""
-    B, _, H, D = x.shape
+    B, T, H, D = x.shape
     return jnp.pad(x.transpose(0, 2, 1, 3).reshape(B * H, T, D),
                    ((0, 0), (0, t_p - T), (0, 0)))
 
@@ -159,75 +196,171 @@ def _gqa_groups(q, k):
     return check_gqa_heads(q.shape[2], k.shape[2])
 
 
-def _kv_row_map(H, H_kv, G):
-    """Grid-index map from q-head row b to its shared kv row — the ONE
-    definition both the forward and the dq kernel use (drift here would
-    make fwd and bwd read different kv blocks)."""
-    if G == 1:
-        return lambda b: b
-    return lambda b: (b // H) * H_kv + (b % H) // G
+def flash_heads_per_step(q_shape, k_shape, block_q=256, block_k=1024):
+    """THE layout rule, by the call's shapes alone: how many heads one
+    grid step of the three kernels takes straight out of the projections'
+    (B, T, H * D) arrays, or 0 where the call runs on transposed
+    (B * H, T, D) copies.
+
+    * D a divisor of 128 and smaller, as many kv heads as q heads, H a
+      multiple of 128 / D, and lengths the blocks divide: 128 / D heads,
+      side by side in one 128-lane block (D = 64: two).
+    * anything else: 0.  D = 80, an odd head count at D = 64 and grouped
+      queries at D < 128 do not tile the lanes; T ragged against the
+      blocks needs the copies' zero padding; at D a multiple of 128 the
+      copies waste no lanes and cost less than what reading (B, T, H * D)
+      does to the neighbours (``ops.activations.heads_per_lane_block``)."""
+    _, Tq, H, D = q_shape
+    _, Tk, H_kv, _ = k_shape
+    _, _, tq_p, tk_p = _flash_blocks(Tq, Tk, block_q, block_k)
+    heads = heads_per_lane_block(D)
+    if heads and H == H_kv and H % heads == 0 and (tq_p, tk_p) == (Tq, Tk):
+        return heads
+    return 0
 
 
+class _FlashAddressing(NamedTuple):
+    """How one call's kernels reach its operands: ``operand`` makes the
+    array a kernel reads from a (B, T, heads, D) one, ``result`` the
+    inverse; blocks are (1, block, ``width``) of it, ``heads`` heads
+    each.  ``grid_q`` / ``grid_kv`` are the grid's (batch, head step)
+    axes over q heads / kv heads, and the three maps turn those two grid
+    indices into the (batch, lane block) block indices of a q-side
+    operand, of K/V under a q-head grid, and of the q-side operand of
+    group member ``g`` under a kv-head grid.  Row state (lse, delta) is
+    (``rows`` + (T, _LANES)) float32, addressed by the same pairs."""
+    heads: int
+    width: int
+    operand: callable
+    result: callable
+    grid_q: tuple
+    grid_kv: tuple
+    rows: tuple
+    q_at: callable
+    kv_at: callable
+    q_of_kv: callable
+
+
+def _flash_addressing(q_shape, k_shape, block_q, block_k):
+    B, _, H, D = q_shape
+    H_kv = k_shape[2]
+    G = H // H_kv
+    heads = flash_heads_per_step(q_shape, k_shape, block_q, block_k)
+    if heads:
+        # the projections' own layout: (B, T, H * D) is a free reshape,
+        # and every kv head is a q head's own
+        return _FlashAddressing(
+            heads=heads, width=heads * D,
+            operand=lambda x, t_p: x.reshape(*x.shape[:2], -1),
+            result=lambda x, T, nh: x.reshape(B, T, nh, D),
+            grid_q=(B, H // heads), grid_kv=(B, H // heads),
+            rows=(B, H),
+            q_at=lambda b, h: (b, h),
+            kv_at=lambda b, h: (b, h),
+            q_of_kv=lambda b, h, g: (b, h))
+    # transposed copies, one head a row of the leading axis (any D, any
+    # GQA factor, T padded to the blocks): q-head row b shares kv row
+    # (b // H) * H_kv + (b % H) // G — the ONE definition the forward and
+    # the dq kernel use (drift here would make them read different
+    # blocks) — and kv row b's group member g is q-head row
+    # (b // H_kv) * H + (b % H_kv) * G + g
+    return _FlashAddressing(
+        heads=1, width=D,
+        operand=_flash_layout,
+        result=lambda x, T, nh: x[:, :T].reshape(B, nh, T, D)
+                                 .transpose(0, 2, 1, 3),
+        grid_q=(B * H, 1), grid_kv=(B * H_kv, 1),
+        rows=(B * H, 1),
+        q_at=lambda b, h: (b, 0),
+        kv_at=lambda b, h: ((b // H) * H_kv + (b % H) // G, 0),
+        q_of_kv=lambda b, h, g: ((b // H_kv) * H + (b % H_kv) * G + g, 0))
+
+
+def _flash_specs(at, block_q, block_k, q_where, q_block, kv_where,
+                 kv_block):
+    """(q-side tile, K/V tile, row state) BlockSpecs of one kernel:
+    ``*_where(*grid)`` names the (batch, lane block) pair of ``at`` and
+    ``*_block(*grid)`` the sequence block that a grid step reads."""
+    def spec(block, where, seq, rows=False):
+        def index(*grid):
+            batch, lanes = where(*grid)
+            if rows:
+                return batch, lanes, seq(*grid), 0
+            return batch, seq(*grid), lanes
+        return pl.BlockSpec((1, at.heads, block, _LANES) if rows
+                            else (1, block, at.width), index)
+    return (spec(block_q, q_where, q_block),
+            spec(block_k, kv_where, kv_block),
+            spec(block_q, q_where, q_block, rows=True))
+
+
+def _flash_k_sweep_specs(at, block_q, block_k, live_k):
+    """``_flash_specs`` of the kernels whose grid is (batch, q head step,
+    q block, k block), ``flash_fwd`` and ``flash_bwd_dq``: K/V sweep,
+    clamped into the q block's live range; with grouped queries their
+    index map names the shared kv head's row (arithmetic on grid
+    indices)."""
+    return _flash_specs(
+        at, block_q, block_k,
+        lambda b, h, i, j: at.q_at(b, h), lambda b, h, i, j: i,
+        lambda b, h, i, j: at.kv_at(b, h), lambda b, h, i, j: live_k(i, j))
+
+
+# Batch, head and block steps are independent; only the minor sweep
+# carries state (the online softmax, an accumulator) — telling Mosaic lets
+# it pipeline DMAs across grid steps instead of serializing.  Two heads a
+# step at 1024 x 1024 tiles hold two heads' score tiles at once: 17.5 MB
+# in the forward, over the 16 MiB a kernel gets unasked.
+_FLASH_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=32 * 1024 * 1024)
+_FLASH_STATICS = ("causal", "scale", "block_q", "block_k", "interpret",
+                  "window")
+
+
+@functools.partial(jax.jit, static_argnames=_FLASH_STATICS)
 def _flash_fwd(q, k, v, *, causal, scale, block_q, block_k, interpret,
-               window=None, return_lse=False):
-    B, Tq, H, D = q.shape
+               window):
+    """(out, lse) of one call.  A ``jax.jit`` of its own, so that the
+    equal calls of a program's layers are one jaxpr, lowered once (each
+    ``pl.pallas_call`` costs about 0.1 s to lower to Mosaic; XLA inlines
+    the calls, so the compiled program is what it would be without).
+    ``q`` carries a folded scale already and ``scale`` is what is left
+    for the score tiles (``_flash_call``)."""
+    _, Tq, H, D = q.shape
     Tk = k.shape[1]
-    H_kv = k.shape[2]
-    G = _gqa_groups(q, k)
-    window = check_attention_window(window, causal)
-    scale_, folded = _flash_scale(scale, D)
-    _note_tile_classes(("flash_fwd",), Tq, Tk, block_q, block_k, causal,
-                       window)
+    at = _flash_addressing(q.shape, k.shape, block_q, block_k)
     block_q, block_k, tq_p, tk_p = _flash_blocks(Tq, Tk, block_q, block_k)
-
-    qm = _flash_layout(q * scale_ if folded else q, Tq, tq_p)
-    km = _flash_layout(k, Tk, tk_p)
-    vm = _flash_layout(v, Tk, tk_p)
-
     n_kb = tk_p // block_k
     geom = dict(causal=causal, window=window, block_q=block_q,
                 block_k=block_k)
     kernel = functools.partial(
-        _flash_fwd_kernel, scale=None if folded else scale_, tq=Tq, tk=Tk,
-        n_kb=n_kb, **geom)
-    # GQA: index-map arithmetic on grid indices is static.
-    kv_row = _kv_row_map(H, H_kv, G)
+        _flash_fwd_kernel, scale=scale, tq=Tq, tk=Tk, n_kb=n_kb,
+        heads=at.heads, head_dim=D, **geom)
     live_k = functools.partial(_flash_live_k, n_kb=n_kb, **geom)
+
+    q_spec, kv_spec, row_spec = _flash_k_sweep_specs(
+        at, block_q, block_k, live_k)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B * H, tq_p // block_q, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda b, i, j: (kv_row(b), live_k(i, j), 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda b, i, j: (kv_row(b), live_k(i, j), 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
-        ],
+        grid=(*at.grid_q, tq_p // block_q, n_kb),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, tq_p, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, tq_p, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct(
+                (at.grid_q[0], tq_p, at.grid_q[1] * at.width), q.dtype),
+            jax.ShapeDtypeStruct((*at.rows, tq_p, _LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((at.heads, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((at.heads, block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, at.width), jnp.float32),
         ],
-        # batch*head and q-block steps are independent; only the k sweep
-        # carries the online-softmax state — telling Mosaic lets it
-        # pipeline DMAs across grid steps instead of serializing.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=_interpret(interpret),
+        compiler_params=_FLASH_COMPILER_PARAMS,
+        interpret=interpret,
         name="flash_fwd",
-    )(qm, km, vm)
-    out = out[:, :Tq].reshape(B, H, Tq, D).transpose(0, 2, 1, 3)
-    if return_lse:
-        return out, lse
-    return out
+    )(at.operand(q, tq_p), at.operand(k, tk_p), at.operand(v, tk_p))
+    return at.result(out, Tq, H), lse
 
 
 def _flash_tile_mask(qi, kj, *, causal, window, block_q, block_k, tq, tk,
@@ -360,66 +493,102 @@ def flash_tile_classes(tq, tk, block_q=256, block_k=1024, causal=False,
             "interior": int(interior.sum())}
 
 
-def _note_tile_classes(kernels, tq, tk, block_q, block_k, causal, window):
-    """Set ``vt_flash_tiles{kernel, class}`` while a call is traced: how
-    often the per-class bodies engage at the shapes the program runs."""
+def _note_flash_call(kernels, q_shape, k_shape, block_q, block_k, causal,
+                     window):
+    """Set, while a call is traced, ``vt_flash_tiles{kernel, class}`` (how
+    often the per-class bodies engage at the shapes the program runs) and
+    ``vt_flash_layout{kernel, layout}`` / ``vt_flash_heads_per_step``
+    (which addressing ``flash_heads_per_step`` gave the call)."""
     from ..runtime.metrics import registry
-    gauge = registry().gauge(
+    reg = registry()
+    tiles = reg.gauge(
         "vt_flash_tiles",
         "flash attention grid steps a head by tile class, as last traced",
         labels=("kernel", "class"))
-    counts = flash_tile_classes(tq, tk, block_q, block_k, causal, window)
+    layout = reg.gauge(
+        "vt_flash_layout",
+        "1 on the addressing the flash kernel's last traced call took: "
+        "lanes (the projections' own layout) or transposed copies",
+        labels=("kernel", "layout"))
+    per_step = reg.gauge(
+        "vt_flash_heads_per_step",
+        "heads one grid step of the flash kernel's last traced call takes",
+        labels=("kernel",))
+    counts = flash_tile_classes(q_shape[1], k_shape[1], block_q, block_k,
+                                causal, window)
+    heads = flash_heads_per_step(q_shape, k_shape, block_q, block_k)
     for kernel in kernels:
         for cls, n in counts.items():
-            gauge.labels(**{"kernel": kernel, "class": cls}).set(n)
+            tiles.labels(**{"kernel": kernel, "class": cls}).set(n)
+        layout.labels(kernel=kernel, layout="lanes").set(int(heads > 0))
+        layout.labels(kernel=kernel, layout="transposed").set(
+            int(heads == 0))
+        per_step.labels(kernel=kernel).set(max(heads, 1))
 
 
 def _flash_scale(scale, D):
     """(scale, folded): a power-of-two scale commutes with every rounding
-    of the score products, so it is applied once to Q in the layout pass
-    and leaves the (block_q, block_k) score tile; any other scale stays
-    on the scores."""
+    of the score products, so it is applied once to Q outside the kernels
+    (``_flash_call``) and leaves the (block_q, block_k) score tile; any
+    other scale stays on the scores."""
     scale = D ** -0.5 if scale is None else float(scale)
     return scale, scale > 0 and math.frexp(scale)[0] == 0.5
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, acc_ref, *, scale, out_scale, causal,
-                         window, block_q, block_k, tq, tk, n_kb):
-    """Grid = (BH, n_q_blocks, n_k_blocks), k minor; dQ accumulates in
-    scratch across the k sweep (two-pass recompute backward: S and P are
-    rebuilt from Q/K and the saved row logsumexp, never materialized).
-    With the scale folded into Q (``scale`` None) the second ``* scale``
-    moves from the score tile to the accumulator (``out_scale``)."""
-    qi, kj = pl.program_id(1), pl.program_id(2)
+def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+                         dq_ref, delta_ref, acc_ref, *, scale, out_scale,
+                         causal, window, block_q, block_k, tq, tk, n_kb,
+                         heads, head_dim):
+    """Grid = (batch, head step, n_q_blocks, n_k_blocks), k minor; dQ
+    accumulates in scratch across the k sweep (two-pass recompute
+    backward: S and P are rebuilt from Q/K and the saved row logsumexp,
+    never materialized).  With the scale folded into Q (``scale`` None)
+    the second ``* scale`` moves from the score tile to the accumulator
+    (``out_scale``).  The sweep's first step takes delta_i =
+    rowsum(dO * O) of its q rows from the tiles it holds anyway, into an
+    output block that stays resident for the sweep and that
+    ``flash_bwd_dkv`` reads afterwards: lane-replicated row state like
+    lse (``_lanes``), written by no pass of its own."""
+    qi, kj = pl.program_id(2), pl.program_id(3)
 
     @pl.when(kj == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
+        prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        for h in range(heads):
+            delta_ref[0, h] = jnp.broadcast_to(
+                jnp.sum(_head_lanes(prod, h, heads, head_dim), axis=-1,
+                        keepdims=True), (block_q, _LANES))
 
     def _step(masked):
         q, k_blk, v_blk, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if scale is not None:
-            s = s * scale
-        # lse/delta blocks are lane-replicated row state (_lanes)
-        p = jnp.exp(s - _lanes(lse_ref[0], block_k))
-        if masked:
-            p = jnp.where(
-                _flash_tile_mask(qi, kj, causal=causal, window=window,
-                                 block_q=block_q, block_k=block_k,
-                                 tq=tq, tk=tk, rows=True), p, 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - _lanes(delta_ref[0], block_k))
-        if scale is not None:
-            ds = ds * scale
-        acc_ref[:] += jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        mask = _flash_tile_mask(
+            qi, kj, causal=causal, window=window, block_q=block_q,
+            block_k=block_k, tq=tq, tk=tk, rows=True) if masked else None
+        dqs = []
+        for h in range(heads):
+            s = jax.lax.dot_general(
+                _head_lanes(q, h, heads, head_dim), k_blk,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if scale is not None:
+                s = s * scale
+            # lse/delta blocks are lane-replicated row state (_lanes)
+            p = jnp.exp(s - _lanes(lse_ref[0, h], block_k))
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            dp = jax.lax.dot_general(
+                _head_lanes(do, h, heads, head_dim), v_blk,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - _lanes(delta_ref[0, h], block_k))
+            if scale is not None:
+                ds = ds * scale
+            # (block_q, W): head h's lanes are its dS @ K
+            dqs.append(jax.lax.dot_general(
+                ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        acc_ref[:] += _by_head(dqs, head_dim)
 
     _flash_by_tile_class(_step, qi, kj, causal=causal, window=window,
                          block_q=block_q, block_k=block_k, tq=tq, tk=tk)
@@ -434,13 +603,17 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
-                          window, block_q, block_k, tq, tk, n_qb, n_qsweep):
-    """Grid = (B*H_kv, n_k_blocks, n_qsweep), q minor; dK/dV accumulate in
-    scratch across the q sweep.  With GQA, n_qsweep = n_q_blocks * G: the
-    minor axis enumerates (group member g, q block qi) — every q head of
-    the group folds into the same kv-head accumulator.  With the scale
-    folded into Q (``scale`` None) dK's product with that Q carries it."""
-    kj, i = pl.program_id(1), pl.program_id(2)
+                          window, block_q, block_k, tq, tk, n_qb, n_qsweep,
+                          heads, head_dim):
+    """Grid = (batch, kv head step, n_k_blocks, n_qsweep), q minor; dK/dV
+    accumulate in scratch across the q sweep.  With GQA, n_qsweep =
+    n_q_blocks * G: the minor axis enumerates (group member g, q block
+    qi) — every q head of the group folds into the same kv-head
+    accumulator.  With the scale folded into Q (``scale`` None) dK's
+    product with that Q carries it.  The products with a head's own
+    lanes of dO and Q (``_head_lanes``) are zero in the other heads'
+    lanes, so the heads of a tile add up into one accumulator."""
+    kj, i = pl.program_id(2), pl.program_id(3)
     qi = i % n_qb
 
     @pl.when(i == 0)
@@ -450,29 +623,35 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     def _step(masked):
         q, k_blk, v_blk, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if scale is not None:
-            s = s * scale
-        p = jnp.exp(s - _lanes(lse_ref[0], block_k))
-        if masked:
-            p = jnp.where(
-                _flash_tile_mask(qi, kj, causal=causal, window=window,
-                                 block_q=block_q, block_k=block_k,
-                                 tq=tq, tk=tk, rows=True), p, 0.0)
-        dv_acc[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - _lanes(delta_ref[0], block_k))
-        if scale is not None:
-            ds = ds * scale
-        dk_acc[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        mask = _flash_tile_mask(
+            qi, kj, causal=causal, window=window, block_q=block_q,
+            block_k=block_k, tq=tq, tk=tk, rows=True) if masked else None
+        dks, dvs = [], []
+        for h in range(heads):
+            q_h = _head_lanes(q, h, heads, head_dim)
+            do_h = _head_lanes(do, h, heads, head_dim)
+            s = jax.lax.dot_general(
+                q_h, k_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if scale is not None:
+                s = s * scale
+            p = jnp.exp(s - _lanes(lse_ref[0, h], block_k))
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            dvs.append(jax.lax.dot_general(
+                p.astype(do.dtype), do_h, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+            dp = jax.lax.dot_general(
+                do_h, v_blk, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            ds = p * (dp - _lanes(delta_ref[0, h], block_k))
+            if scale is not None:
+                ds = ds * scale
+            dks.append(jax.lax.dot_general(
+                ds.astype(q.dtype), q_h, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        dv_acc[:] += functools.reduce(operator.add, dvs)
+        dk_acc[:] += functools.reduce(operator.add, dks)
 
     _flash_by_tile_class(_step, qi, kj, causal=causal, window=window,
                          block_q=block_q, block_k=block_k, tq=tq, tk=tk)
@@ -483,109 +662,89 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, out, lse, g, *, causal, scale, block_q, block_k,
-               interpret, window=None):
-    B, Tq, H, D = q.shape
+@functools.partial(jax.jit, static_argnames=_FLASH_STATICS + ("out_scale",))
+def _flash_bwd(q, k, v, out, lse, g, *, causal, scale, out_scale, block_q,
+               block_k, interpret, window):
+    """(dq, dk, dv) of one call; a ``jax.jit`` of its own like
+    ``_flash_fwd``.  ``q`` is the forward's (scale folded in where
+    ``out_scale`` is set)."""
+    _, Tq, H, D = q.shape
     Tk = k.shape[1]
     H_kv = k.shape[2]
-    G = _gqa_groups(q, k)
-    scale_, folded = _flash_scale(scale, D)
-    _note_tile_classes(("flash_bwd_dq", "flash_bwd_dkv"), Tq, Tk, block_q,
-                       block_k, causal, window)
+    G = H // H_kv
+    at = _flash_addressing(q.shape, k.shape, block_q, block_k)
     block_q, block_k, tq_p, tk_p = _flash_blocks(Tq, Tk, block_q, block_k)
     n_qb, n_kb = tq_p // block_q, tk_p // block_k
+    qm, km, vm, dom = (at.operand(q, tq_p), at.operand(k, tk_p),
+                       at.operand(v, tk_p), at.operand(g, tq_p))
 
-    qm = _flash_layout(q * scale_ if folded else q, Tq, tq_p)
-    km = _flash_layout(k, Tk, tk_p)
-    vm = _flash_layout(v, Tk, tk_p)
-    dom = _flash_layout(g, Tq, tq_p)
-    om = _flash_layout(out, Tq, tq_p)
-    # delta_i = rowsum(dO * O) — cheap elementwise+reduce, left to XLA;
-    # lane-replicated like lse (_lanes).
-    delta = jnp.broadcast_to(
-        jnp.sum(dom.astype(jnp.float32) * om.astype(jnp.float32),
-                axis=-1, keepdims=True), lse.shape)
-
-    itp = _interpret(interpret)
     geom = dict(causal=causal, window=window, block_q=block_q,
                 block_k=block_k)
-    common = dict(scale=None if folded else scale_, tq=Tq, tk=Tk, **geom)
-    kv_row = _kv_row_map(H, H_kv, G)
+    common = dict(scale=scale, tq=Tq, tk=Tk, heads=at.heads, head_dim=D,
+                  **geom)
     live_k = functools.partial(_flash_live_k, n_kb=n_kb, **geom)
-    dq = pl.pallas_call(
+
+    q_spec, kv_spec, row_spec = _flash_k_sweep_specs(
+        at, block_q, block_k, live_k)
+    dq, delta = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, n_kb=n_kb,
-                          out_scale=scale_ if folded else None, **common),
-        grid=(B * H, n_qb, n_kb),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda b, i, j: (kv_row(b), live_k(i, j), 0)),
-            pl.BlockSpec((1, block_k, D),
-                         lambda b, i, j: (kv_row(b), live_k(i, j), 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, tq_p, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=itp,
+                          out_scale=out_scale, **common),
+        grid=(*at.grid_q, n_qb, n_kb),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct(qm.shape, q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, at.width), jnp.float32)],
+        compiler_params=_FLASH_COMPILER_PARAMS,
+        interpret=interpret,
         name="flash_bwd_dq",
-    )(qm, km, vm, dom, lse, delta)
+    )(qm, km, vm, dom, at.operand(out, tq_p), lse)
 
     # dK/dV: grid over kv heads; the minor sweep covers (group member g,
     # q block) so all G q heads of a group fold into one accumulator.
-    # q-side rows for kv row b and sweep index i: head (b % H_kv)*G + g.
     # The q block of a dead step is clamped into k tile j's live range
     # (within its group member), so it is not fetched.
     live_q = functools.partial(_flash_live_q, n_qb=n_qb, **geom)
-    if G == 1:
-        def q_row(b, j, i):
-            return b, live_q(j, i)
-    else:
-        def q_row(b, j, i):
-            return ((b // H_kv) * H + (b % H_kv) * G + i // n_qb,
-                    live_q(j, i % n_qb))
+
+    q_spec, kv_spec, row_spec = _flash_specs(
+        at, block_q, block_k,
+        lambda b, h, j, i: at.q_of_kv(b, h, i // n_qb),
+        lambda b, h, j, i: live_q(j, i % n_qb),
+        lambda b, h, j, i: (b, h), lambda b, h, j, i: j)
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, n_qb=n_qb,
                           n_qsweep=n_qb * G, **common),
-        grid=(B * H_kv, n_kb, n_qb * G),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D),
-                         lambda b, j, i: (*q_row(b, j, i), 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_q, D),
-                         lambda b, j, i: (*q_row(b, j, i), 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda b, j, i: (*q_row(b, j, i), 0)),
-            pl.BlockSpec((1, block_q, _LANES),
-                         lambda b, j, i: (*q_row(b, j, i), 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, j, i: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H_kv, tk_p, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H_kv, tk_p, D), v.dtype),
-        ],
+        grid=(*at.grid_kv, n_kb, n_qb * G),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(km.shape, k.dtype),
+                   jax.ShapeDtypeStruct(vm.shape, v.dtype)],
         scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, at.width), jnp.float32),
+            pltpu.VMEM((block_k, at.width), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=itp,
+        compiler_params=_FLASH_COMPILER_PARAMS,
+        interpret=interpret,
         name="flash_bwd_dkv",
     )(qm, km, vm, dom, lse, delta)
+    return (at.result(dq, Tq, H), at.result(dk, Tk, H_kv),
+            at.result(dv, Tk, H_kv))
 
-    def back(x, T, nh):
-        return x[:, :T].reshape(B, nh, T, D).transpose(0, 2, 1, 3)
 
-    return back(dq, Tq, H), back(dk, Tk, H_kv), back(dv, Tk, H_kv)
+def _flash_call(kernels, q, k, causal, scale, block_q, block_k, interpret,
+                window):
+    """What every trace of a call does outside the jitted kernels: the
+    checks, the gauges of the path taken, and the scale's split.  Returns
+    (what multiplies Q outside the kernels and dQ inside, or None; the
+    static arguments of ``_flash_fwd`` / ``_flash_bwd``)."""
+    window = check_attention_window(window, causal)
+    _gqa_groups(q, k)
+    scale, folded = _flash_scale(scale, q.shape[-1])
+    _note_flash_call(kernels, q.shape, k.shape, block_q, block_k, causal,
+                     window)
+    return (scale if folded else None), dict(
+        causal=causal, scale=None if folded else scale, block_q=block_q,
+        block_k=block_k, interpret=_interpret(interpret), window=window)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
@@ -604,6 +763,24 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
 
     ``window=W`` (requires ``causal=True``) restricts each query to keys
     in ``(q - W, q]`` — sliding-window local attention.
+
+    **Layout.**  Where several heads share a 128-lane block (D = 64:
+    two), the kernels read q, k, v, dO, O and write O, dQ, dK, dV in the
+    layout the projections produce: (B, T, H, D) seen as (B, T, H * D),
+    a free reshape, out of which a BlockSpec picks (1, block, 128) tiles
+    by (batch, sequence block, head step).  No transpose, pad or copy of
+    an operand surrounds a call, and a grid step does 128 / D heads,
+    each product over the full lane width with the other heads' lanes
+    zeroed — the MXU passes a D-wide product costs anyway.  One rule on
+    the call's shapes (``flash_heads_per_step``) says which calls: D a
+    divisor of 128 and smaller, plain MHA, H a multiple of 128 / D, T a
+    multiple of the blocks.  Any other call (D = 80, an odd H at D = 64,
+    GQA at D < 128, ragged T, and D a multiple of 128, where the copies
+    waste no lanes) runs the same kernels over transposed (B * H, T, D)
+    copies, T zero-padded to the blocks, one head a step; with grouped
+    queries K/V's index map names the shared kv head's row.  Nothing
+    selects the layout but the shapes; ``vt_flash_layout`` says which a
+    traced call took.
 
     **Tile classes.**  Every (block_q, block_k) grid step of the three
     kernels is classed from its grid indices and the call's static sizes
@@ -625,26 +802,37 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=256,
     ``flash_tile_classes`` counts them for a call; ``vt_flash_tiles``
     reports the counts of the last traced call.  A power-of-two ``scale``
     (D = 64: 0.125) is applied once to Q outside the kernels, where it is
-    exact, instead of to every score tile."""
-    return _flash_fwd(q, k, v, causal=causal, scale=scale, block_q=block_q,
-                      block_k=block_k, interpret=interpret, window=window)
+    exact and fuses into whatever produced Q, instead of to every score
+    tile.
+
+    **Lowered once.**  The ``pl.pallas_call``s sit in two jitted
+    functions (``_flash_fwd``, ``_flash_bwd``) with the geometry static,
+    so a program's equal layers share one lowering of each kernel.
+
+    The default blocks (256, 1024) are what a caller without a measured
+    pick gets; no benchmark cell runs them (``MultiHeadAttention.prepare``
+    measures four shapes and ``xla``; docs/autotune.md)."""
+    fold, statics = _flash_call(("flash_fwd",), q, k, causal, scale,
+                                block_q, block_k, interpret, window)
+    return _flash_fwd(q if fold is None else q * fold, k, v, **statics)[0]
 
 
 def _flash_vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                    window):
-    out, lse = _flash_fwd(q, k, v, causal=causal, scale=scale,
-                          block_q=block_q, block_k=block_k,
-                          interpret=interpret, window=window,
-                          return_lse=True)
+    fold, statics = _flash_call(("flash_fwd",), q, k, causal, scale,
+                                block_q, block_k, interpret, window)
+    q = q if fold is None else q * fold
+    out, lse = _flash_fwd(q, k, v, **statics)
     return out, (q, k, v, out, lse)
 
 
 def _flash_vjp_bwd(causal, scale, block_q, block_k, interpret, window,
                    res, g):
     q, k, v, out, lse = res
-    return _flash_bwd(q, k, v, out, lse, g, causal=causal, scale=scale,
-                      block_q=block_q, block_k=block_k, interpret=interpret,
-                      window=window)
+    fold, statics = _flash_call(("flash_bwd_dq", "flash_bwd_dkv"), q, k,
+                                causal, scale, block_q, block_k, interpret,
+                                window)
+    return _flash_bwd(q, k, v, out, lse, g, out_scale=fold, **statics)
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)

@@ -112,10 +112,6 @@ class MultiHeadAttention(Forward):
         if B * T * (H + 2 * Hk) * D > 10 ** 8:
             self._resolved_flash = None  # platform default
             return
-        # block_size changes the XLA candidate's schedule, so it keys
-        # the persisted winner alongside causal/window/kv-heads
-        op = (f"attention_fwd_bwd_c{int(self.causal)}"
-              f"_w{self.window}_hk{Hk}_bs{self.block_size}")
         shapes = [(B, T, H, D), (B, T, Hk, D), (B, T, Hk, D)]
         specs = [jax.ShapeDtypeStruct(s, dt) for s in shapes]
 
@@ -135,13 +131,21 @@ class MultiHeadAttention(Forward):
         # them large, because at long T the backward kernels are bound
         # by the MXU's half-filled D = 64 passes and fewer, larger steps
         # win (PERF.md section 6, PR 28)
-        from ..ops.pallas_kernels import _flash_blocks
+        from ..ops.pallas_kernels import _flash_blocks, flash_heads_per_step
         cand_blocks, seen = [], set()
         for bq, bk in ((256, 1024), (512, 512), (1024, 512), (1024, 1024)):
             eff = _flash_blocks(T, T, bq, bk)
             if eff not in seen:
                 seen.add(eff)
                 cand_blocks.append((bq, bk))
+        # block_size changes the XLA candidate's schedule, so it keys
+        # the persisted winner alongside causal/window/kv-heads; so do
+        # the heads a grid step of each flash candidate takes (0: over
+        # transposed copies), since they make its program
+        heads = "-".join(str(flash_heads_per_step(*shapes[:2], bq, bk))
+                         for bq, bk in cand_blocks)
+        op = (f"attention_fwd_bwd_c{int(self.causal)}"
+              f"_w{self.window}_hk{Hk}_bs{self.block_size}_l{heads}")
         names = tuple(f"flash_{bq}x{bk}" for bq, bk in cand_blocks) \
             + ("xla",)
         cached = autotune.lookup(op, names, specs)
