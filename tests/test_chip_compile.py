@@ -164,6 +164,14 @@ ONE_CHIP = {
         34816, 16, 2048, 1024),
     "grouped_matmul_fwd_bwd_down_16x1024x2048": _grouped(
         34816, 16, 1024, 2048),
+    # widths that 512-column blocks do not divide: 1856 = 14.5 x 128 goes
+    # whole, 2688 = 21 x 128 in blocks of 384
+    "grouped_matmul_fwd_bwd_up_8x2688x1856": _grouped(5632, 8, 2688, 1856),
+    "grouped_matmul_fwd_bwd_down_8x1856x2688": _grouped(
+        5632, 8, 1856, 2688),
+    "grouped_matmul_fwd_bwd_up_large_8x2688x1856": _grouped(
+        25600, 8, 2688, 1856),
+    "flash_fwd_bwd_t4096_h32_kv2_d128_full": _flash(1, 4096, 32, 2, 128),
     "flash_fwd_bwd_t4096_h32_kv4_d128_window2048": _flash_gqa_d128(2048),
     "flash_fwd_bwd_t4096_h32_kv4_d128_full": _flash_gqa_d128(None),
     "flash_fwd_bwd_trinity_window_512x512": _flash_gqa_d128(

@@ -19,7 +19,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Sequence
 
 from ..ops.optimizers import HyperParams, OPTIMIZERS, Optimizer
-from ..units import nn, parallel_nn, recurrent
+from ..units import nn, parallel_nn, recurrent, ssm
 from ..units.workflow import Workflow
 
 LAYER_TYPES = {
@@ -56,6 +56,8 @@ LAYER_TYPES = {
     "layer_norm": nn.LayerNorm,
     "rms_norm": nn.RMSNorm,
     "gated_mlp": nn.GatedMLP,
+    "add": nn.Add,
+    "mamba2": ssm.Mamba2Mixer,
     "seq_last": nn.SeqLast,
 }
 
@@ -64,7 +66,7 @@ LAYER_TYPES = {
 # switch); shared with PipelineStack's stage-config builder
 COMPUTE_DTYPE_TYPES = ("all2all", "softmax", "conv", "deconv", "rnn",
                        "gru", "lstm", "attention", "ffn", "gated_mlp",
-                       "routed_experts")
+                       "routed_experts", "mamba2")
 
 
 def build_workflow(name: str, layers: Sequence[dict], *,

@@ -43,6 +43,12 @@ def identity(x):
     return x
 
 
+def relu2(x):
+    """Squared ReLU, ``max(x, 0)^2``."""
+    r = jnp.maximum(x, 0)
+    return r * r
+
+
 ACTIVATIONS = {
     "linear": identity,
     "relu": relu,
@@ -51,6 +57,7 @@ ACTIVATIONS = {
     "sigmoid": sigmoid,
     "sincos": sincos,
     "silu": jax.nn.silu,
+    "relu2": relu2,
 }
 
 

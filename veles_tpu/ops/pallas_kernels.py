@@ -928,6 +928,16 @@ def _gmm_dw_kernel(step_group_ref, step_tile_ref, step_flags_ref, lhs_ref,
         out_ref[0] = acc_ref[...].astype(out_ref.dtype)
 
 
+def _gmm_dw_block_cols(n: int, block_cols: int) -> int:
+    """The columns a step of ``grouped_matmul_dw`` takes: the widest
+    divisor of ``n`` that is a multiple of 128 and at most ``block_cols``,
+    so that the grid's ``n // block`` column blocks cover every column
+    (``min(block_cols, n)`` lost columns 1536..1855 of 1856); the whole
+    width where no such divisor exists."""
+    fits = [c for c in range(128, min(block_cols, n) + 1, 128) if n % c == 0]
+    return fits[-1] if fits else n
+
+
 def _gmm_dw(lhs, g, group_sizes, *, block_rows, block_cols, interpret):
     """``out[e] = lhs[rows of e].T @ g[rows of e]`` (G, K, N): the
     products' gradient to the groups' matrices.  A group's tiles are
@@ -950,7 +960,7 @@ def _gmm_dw(lhs, g, group_sizes, *, block_rows, block_cols, interpret):
     flags = ((s == step_start[step_group]) * 1
              + (s == step_end[step_group] - 1) * 2
              + (group_sizes[step_group] > 0) * 4).astype(jnp.int32)
-    block_cols = min(block_cols, N)
+    block_cols = _gmm_dw_block_cols(N, block_cols)
     return pl.pallas_call(
         _gmm_dw_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

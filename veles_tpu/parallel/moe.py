@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops.activations import ACTIVATIONS
+
 
 def init_moe_params(key, n_experts: int, d_model: int, d_hidden: int,
                     dtype=jnp.float32) -> dict:
@@ -392,16 +394,19 @@ def routed_experts_apply(params: dict, x: jnp.ndarray, *, top_k: int,
                          n_held: Optional[int] = None, offset: int = 0,
                          bias=None, route_norm: bool = True, route_scale: float = 1.0,
                          block_rows: int = 128, compute_dtype=None,
-                         use_pallas: Optional[bool] = None):
-    """Dropless top-k routed gated experts, the held experts' part.
+                         use_pallas: Optional[bool] = None,
+                         activation: str = "silu"):
+    """Dropless top-k routed experts, the held experts' part.
 
     ``x`` (T, D); ``params``: ``router`` (D, n_experts), and the held
     experts' ``wg``, ``wu`` (n_held, D, H) and ``wd`` (n_held, H, D),
     expert ``offset + i`` of the router at index i.  Every token is
     routed over all the router's experts; a route to an expert not held
     adds nothing.  Returns (y (T, D) float32, counters): ``y[t] = sum
-    over held e in top_k(t) of w[t, e] * wd[e](silu(wg[e] x[t]) * wu[e]
-    x[t])``; ``counters`` are int32 scalars: ``rows_routed`` (routes
+    over held e in top_k(t) of w[t, e] * wd[e](act(wg[e] x[t]) * wu[e]
+    x[t])``, or of ``w[t, e] * wd[e] act(wu[e] x[t])`` where ``params``
+    has no ``wg`` (experts that are not gated: two grouped products, not
+    three); ``counters`` are int32 scalars: ``rows_routed`` (routes
     that landed on held experts), ``rows_computed`` (rows the grouped
     products ran, tile padding included), ``experts_active`` (held
     experts with a row), ``expert_rows_max``.
@@ -417,7 +422,8 @@ def routed_experts_apply(params: dict, x: jnp.ndarray, *, top_k: int,
         from ..ops import use_pallas_default
         use_pallas = use_pallas_default()
     T, D = x.shape
-    n_held = params["wg"].shape[0] if n_held is None else int(n_held)
+    n_held = params["wu"].shape[0] if n_held is None else int(n_held)
+    wg = params.get("wg")
     dt = compute_dtype or x.dtype
     with jax.named_scope("moe_route"):
         w, topi = route_scores(x, params["router"], top_k=top_k, bias=bias,
@@ -429,9 +435,10 @@ def routed_experts_apply(params: dict, x: jnp.ndarray, *, top_k: int,
     small, large = buffer_rows(T, topi.shape[1], n_held,
                                params["router"].shape[1], block_rows)
     y = experts_through_buffers(
-        (small, large, block_rows, bool(use_pallas)), x.astype(dt), w,
-        params["wg"].astype(dt), params["wu"].astype(dt),
-        params["wd"].astype(dt), row_of_route, sizes, rows_needed)
+        (small, large, block_rows, bool(use_pallas), activation),
+        x.astype(dt), w, None if wg is None else wg.astype(dt),
+        params["wu"].astype(dt), params["wd"].astype(dt), row_of_route,
+        sizes, rows_needed)
     counters = {"rows_routed": jnp.sum(sizes),
                 "rows_computed": rows_needed,
                 "experts_active": jnp.sum((sizes > 0).astype(jnp.int32)),
@@ -439,10 +446,11 @@ def routed_experts_apply(params: dict, x: jnp.ndarray, *, top_k: int,
     return y, counters
 
 
-def _through_buffer(M, block_rows, use_pallas, x, w, wg, wu, wd,
+def _through_buffer(M, block_rows, use_pallas, activation, x, w, wg, wu, wd,
                     row_of_route, sizes):
     """The held experts' part of y (T, D) float32, the sorted rows going
-    through a buffer of M rows (which has to hold them)."""
+    through a buffer of M rows (which has to hold them).  ``wg`` None:
+    experts without a gate, ``wd act(wu x)``."""
     T, K = row_of_route.shape
     with jax.named_scope("moe_dispatch"):
         row = jnp.minimum(row_of_route, M)              # M: no row
@@ -454,11 +462,11 @@ def _through_buffer(M, block_rows, use_pallas, x, w, wg, wu, wd,
         product = functools.partial(grouped_products, sizes=sizes,
                                     block_rows=block_rows,
                                     use_pallas=use_pallas)
-        g = product(rows, wg)
-        u = product(rows, wu)
-        h = (jax.nn.silu(g.astype(jnp.float32))
-             * u.astype(jnp.float32)).astype(x.dtype)
-        out_rows = product(h, wd)
+        act = ACTIVATIONS[activation]
+        g = None if wg is None else product(rows, wg)
+        u = product(rows, wu).astype(jnp.float32)
+        h = act(u) if g is None else act(g.astype(jnp.float32)) * u
+        out_rows = product(h.astype(x.dtype), wd)
     with jax.named_scope("moe_combine"):
         # rows no product wrote hold anything: take_rows selects, it does
         # not multiply
@@ -481,7 +489,7 @@ def _with_the_buffer_that_fits(cfg, rows_needed, make, *operands):
 def experts_through_buffers(cfg, x, w, wg, wu, wd, row_of_route, sizes,
                             rows_needed):
     """``_through_buffer`` at the buffer that fits, ``cfg = (small, large,
-    block_rows, use_pallas)``.  Its gradient runs the forward again inside
+    block_rows, use_pallas, activation)``.  Its gradient runs the forward again inside
     the branch it takes: differentiating through a ``lax.cond`` keeps
     what both branches would save, the large buffer's with the small
     one's, for every layer until its backward, which is more than the

@@ -1,6 +1,6 @@
 from .base import (Avatar, Context, Forward, InputJoiner, LambdaUnit, Spec,
                    TrivialUnit, Unit, UnitRegistry)
-from .nn import (All2All, All2AllRELU, All2AllSincos, All2AllSoftmax,
+from .nn import (Add, All2All, All2AllRELU, All2AllSincos, All2AllSoftmax,
                  All2AllTanh, AvgPooling, Conv, ConvRELU, ConvTanh, Deconv,
                  Depool, Dropout, Evaluator, EvaluatorMSE, EvaluatorSoftmax,
                  Embedding, Flatten, LayerNorm, LRN, MaxPooling,
@@ -9,6 +9,7 @@ from .nn import (All2All, All2AllRELU, All2AllSincos, All2AllSoftmax,
                  StochasticAbsPooling)
 from .parallel_nn import (MoEFFN, MultiHeadAttention, PipelineStack,
                           expert_rules, pipeline_rules)
+from .ssm import Mamba2Mixer
 from .kohonen import KohonenForward
 from .recurrent import GRU, LSTM, RNN
 from .rbm import RBM

@@ -477,6 +477,22 @@ class RMSNorm(Unit):
         return y, state
 
 
+class Add(Unit):
+    """The sum of its inputs: how a layer list writes a block wired
+    ``h + f(N(h))``, ``{"type": "add", "inputs": [f, h]}``, whatever the
+    mixer ``f`` is.  The sum takes the last input's dtype (the residual
+    stream's)."""
+
+    def output_spec(self, in_specs):
+        return in_specs[-1]
+
+    def apply(self, params, state, xs, ctx):
+        y = xs[-1]
+        for x in xs[:-1]:
+            y = y + x.astype(y.dtype)
+        return y, state
+
+
 class GatedMLP(Unit):
     """Per-position gated MLP, ``y = Wd(act(Wg x) * (Wu x))`` (SwiGLU
     with ``activation="silu"``): three matrices, no bias, no residual."""
@@ -508,9 +524,13 @@ class GatedMLP(Unit):
 
 
 def gated_mlp(x, wg, wu, wd, activation="silu", compute_dtype=None):
-    """``(act(x wg) * (x wu)) wd`` on (rows, E), float32 accumulation."""
-    h = ACTIVATIONS[activation](ops.dense(x, wg, compute_dtype=compute_dtype)) \
-        * ops.dense(x, wu, compute_dtype=compute_dtype)
+    """``(act(x wg) * (x wu)) wd`` on (rows, E), float32 accumulation;
+    ``wg`` None: no gate, ``act(x wu) wd``."""
+    act = ACTIVATIONS[activation]
+    g = None if wg is None \
+        else ops.dense(x, wg, compute_dtype=compute_dtype)
+    u = ops.dense(x, wu, compute_dtype=compute_dtype)
+    h = act(u) if g is None else act(g) * u
     return ops.dense(h, wd, compute_dtype=compute_dtype)
 
 

@@ -344,10 +344,13 @@ class MoEFFN(Forward):
 
 
 class RoutedExpertsFFN(Forward):
-    """Dropless routed gated experts beside an optional shared expert,
-    over (B, T, E) or (N, E) activations: ``y = shared(x) + sum over the
+    """Dropless routed experts beside an optional shared expert, over
+    (B, T, E) or (N, E) activations: ``y = shared(x) + sum over the
     top_k experts e of w_e expert_e(x)``, every expert a gated MLP
-    ``Wd(silu(Wg x) * (Wu x))`` of width ``d_hidden``.
+    ``Wd(act(Wg x) * (Wu x))`` of width ``d_hidden`` or, with ``gated``
+    false, ``Wd act(Wu x)`` (no ``wg``: two products an expert);
+    ``activation`` names ``act`` (``"silu"``; ``"relu2"`` is ReLU
+    squared).  The shared expert has the same form.
 
     No capacity and no drops: an expert's rows are whatever the router
     gives it, zero included (``parallel/moe.routed_experts_apply``); the
@@ -369,10 +372,12 @@ class RoutedExpertsFFN(Forward):
                  inputs=("@input",), *, top_k: int = 2,
                  experts_held: Optional[int] = None, expert_offset: int = 0,
                  route_norm: bool = True, route_scale: float = 1.0,
-                 shared_width: int = 0,
+                 shared_width: int = 0, gated: bool = True,
+                 activation: str = "silu",
                  block_rows: int = 128, compute_dtype=None,
                  use_pallas: Optional[bool] = None):
         super().__init__(name, inputs)
+        self.gated, self.activation = bool(gated), activation
         self.n_experts = int(n_experts)
         self.d_hidden = int(d_hidden)
         self.top_k = int(top_k)
@@ -399,16 +404,18 @@ class RoutedExpertsFFN(Forward):
         kr, kg, ku, kd, ks = jax.random.split(key, 5)
         params = {
             "router": _uniform_init(kr, (E, self.n_experts), E),
-            "wg": _uniform_init(kg, (G, E, H), E),
             "wu": _uniform_init(ku, (G, E, H), E),
             "wd": _uniform_init(kd, (G, H, E), H),
         }
+        if self.gated:
+            params["wg"] = _uniform_init(kg, (G, E, H), E)
         if self.shared_width:
             S = self.shared_width
             k1, k2, k3 = jax.random.split(ks, 3)
-            params.update(shared_wg=_uniform_init(k1, (E, S), E),
-                          shared_wu=_uniform_init(k2, (E, S), E),
+            params.update(shared_wu=_uniform_init(k2, (E, S), E),
                           shared_wd=_uniform_init(k3, (S, E), S))
+            if self.gated:
+                params["shared_wg"] = _uniform_init(k1, (E, S), E)
         # one buffer each: the step donates its state
         state = {"route_bias": jnp.zeros((self.n_experts,), jnp.float32),
                  "counters": {k: jnp.zeros((), jnp.int32) for k in (
@@ -425,12 +432,13 @@ class RoutedExpertsFFN(Forward):
             params, flat, top_k=self.top_k, n_held=self.experts_held,
             offset=self.expert_offset, bias=state["route_bias"], route_norm=self.route_norm,
             route_scale=self.route_scale, block_rows=self.block_rows,
-            compute_dtype=self.compute_dtype, use_pallas=self.use_pallas)
+            compute_dtype=self.compute_dtype, use_pallas=self.use_pallas,
+            activation=self.activation)
         if self.shared_width:
             with jax.named_scope("moe_shared"):
-                y = y + gated_mlp(flat, params["shared_wg"],
+                y = y + gated_mlp(flat, params.get("shared_wg"),
                                   params["shared_wu"], params["shared_wd"],
-                                  compute_dtype=self.compute_dtype)
+                                  self.activation, self.compute_dtype)
         return (y.reshape(x.shape).astype(x.dtype),
                 {"route_bias": state["route_bias"], "counters": counters})
 
