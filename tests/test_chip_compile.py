@@ -132,6 +132,18 @@ def _grouped(rows, groups, k, n, grad=True):
     return case
 
 
+def _row_sum(rows, width, tokens=4096):
+    """The routed experts' combine and dispatch gradient: a buffer of
+    ``rows`` summed by token into (tokens, width) float32 that stays in
+    VMEM (32 MiB at 2048 columns, 42 MiB at 2688)."""
+    def case(sh):
+        return (lambda r, t, w: pk.sum_rows_by_token(r, t, tokens, w),
+                [_sds((rows, width), "bfloat16", sh),
+                 _sds((rows,), "int32", sh), _sds((rows,), "float32", sh)],
+                1)
+    return case
+
+
 def _xent(rows, classes, dtype="float32"):
     """The loss's forward sweep over (rows, classes) logits, with the
     loss, the predictions and the logits' gradient around it."""
@@ -171,6 +183,10 @@ ONE_CHIP = {
         5632, 8, 1856, 2688),
     "grouped_matmul_fwd_bwd_up_large_8x2688x1856": _grouped(
         25600, 8, 2688, 1856),
+    "sum_rows_by_token_trinity_small_14336x2048": _row_sum(14336, 2048),
+    "sum_rows_by_token_trinity_large_34816x2048": _row_sum(34816, 2048),
+    "sum_rows_by_token_hybrid_small_5632x2688": _row_sum(5632, 2688),
+    "sum_rows_by_token_hybrid_large_25600x2688": _row_sum(25600, 2688),
     "flash_fwd_bwd_t4096_h32_kv2_d128_full": _flash(1, 4096, 32, 2, 128),
     "flash_fwd_bwd_t4096_h32_kv4_d128_window2048": _flash_gqa_d128(2048),
     "flash_fwd_bwd_t4096_h32_kv4_d128_full": _flash_gqa_d128(None),
@@ -298,6 +314,59 @@ def test_kernel_call_site_compiles_under_a_mesh(topo, for_the_chip, name):
     dp = tuple(a for a in ("data", "fsdp") if mesh.shape[a] > 1)
     fn, args, kernels = site(mesh, NamedSharding(mesh, P(dp)))
     assert _custom_calls(fn, *args) == kernels
+
+
+# -- a routed layer's forward and backward -------------------------------------
+
+#: cell -> (tokens, width, expert width, top_k, held of 128, gated, act)
+ROUTED_LAYERS = {
+    "trinity": (4096, 2048, 1024, 8, 16, True, "silu"),
+    "hybrid": (4096, 2688, 1856, 6, 8, False, "relu2"),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ROUTED_LAYERS))
+def test_routed_layer_holds_no_array_of_all_routes_rows(topo, for_the_chip,
+                                                        cell):
+    """Two equal routed layers, forward and backward, at a sparse cell's
+    shapes: lowered, the row-summing kernel is there once for each of the
+    two buffers however many calls name it (layers, the recomputed
+    forward, the dispatch's gradient: PERF.md section 6, PR 33's rule);
+    compiled for one described chip, no operation's result has T * K
+    rows (or T by K) by D columns, whatever its type.  The plain path
+    gathers such arrays."""
+    import re
+    from veles_tpu.parallel import moe
+    T, D, H, K, held, gated, act = ROUTED_LAYERS[cell]
+    sh = SingleDeviceSharding(topo.devices[0])
+    params = {"router": _sds((D, 128), "float32", sh),
+              "wu": _sds((held, D, H), "float32", sh),
+              "wd": _sds((held, H, D), "float32", sh)}
+    if gated:
+        params["wg"] = params["wu"]
+
+    def layers(use_pallas):
+        def f(params, x):
+            for _ in range(2):
+                x = x + moe.routed_experts_apply(
+                    params, x, top_k=K, n_held=held, route_scale=2.5,
+                    compute_dtype=jnp.bfloat16, use_pallas=use_pallas,
+                    activation=act)[0]
+            return jnp.sum(x)
+        return jax.jit(jax.grad(f, argnums=(0, 1))).trace(
+            params, _sds((T, D), "float32", sh))
+
+    all_routes = rf"(\[|<)({T * K}[,x]{D}|{T}[,x]{K}[,x]{D})(\]|x)"
+    plain = layers(False).lower(lowering_platforms=("tpu",)).as_text()
+    assert re.search(all_routes, plain)
+    lowered = layers(True).lower()
+    text = lowered.as_text()
+    assert text.count('kernel_name = "sum_rows_by_token"') == 2
+    assert not re.search(all_routes, text)
+    compiled = lowered.compile().as_text()
+    assert "sum_rows_by_token" in compiled
+    found = re.search(all_routes, compiled)
+    assert not found, compiled[found.start() - 200:found.end() + 200]
 
 
 # -- the LM's loss in the compiled train step ---------------------------------

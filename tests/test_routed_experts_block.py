@@ -18,10 +18,11 @@ for p in (ROOT, BENCH):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from references import afmoe  # noqa: E402
+from references import afmoe, nemotron_h  # noqa: E402
 from references.train_steps import cast_float32  # noqa: E402
 
 from veles_tpu.models.standard import StandardWorkflow  # noqa: E402
+from veles_tpu.ops import pallas_kernels as pk  # noqa: E402
 from veles_tpu.parallel import moe  # noqa: E402
 from veles_tpu.units.base import Context, Spec  # noqa: E402
 from veles_tpu.units.parallel_nn import (MultiHeadAttention,  # noqa: E402
@@ -220,18 +221,89 @@ def test_no_route_is_dropped_when_every_token_picks_one_expert():
     assert int(counters["rows_computed"]) == 2 * 2 * T    # 32 = 4 tiles of 8
 
 
-@pytest.mark.parametrize("use_pallas", [True, False])
-def test_kernel_and_ragged_dot_paths_agree(use_pallas):
-    _, (params, state) = expert_params(jax.random.key(7), held=4)
-    x = jax.random.normal(jax.random.key(8), (T * 2, E))
-    y, _ = moe.routed_experts_apply(
-        params, x, top_k=2, n_held=4, offset=2, route_scale=2.826,
-        block_rows=8, use_pallas=use_pallas)
-    layer = expert_layer(experts_held=4, expert_offset=2, shared_width=0)
+UNGATED = dict(gated=False, activation="relu2")
+
+
+def reference_layer(gated, **cut):
+    """(the reference's routed layer, its layer description): gated
+    ``silu`` experts (afmoe) or ``Wd relu(Wu x)^2`` (nemotron_h)."""
+    layer = expert_layer(shared_width=0, **cut, **({} if gated else UNGATED))
+    return (afmoe if gated else nemotron_h)._routed_experts, layer
+
+
+def unit_of(layer, **kw):
+    return RoutedExpertsFFN(**{k: v for k, v in dict(layer, **kw).items()
+                               if k != "type"})
+
+
+def reference_counters(layer, params, x, block_rows=8):
+    """The unit's four counters from the reference's own routing."""
+    weights, _ = afmoe.route(layer, params, x)
+    first, held = layer["expert_offset"], layer["experts_held"]
+    sizes = np.asarray(
+        (weights[..., first:first + held] != 0).sum(axis=(0, 1)))
+    return {"rows_routed": sizes.sum(),
+            "rows_computed": (-(-sizes // block_rows) * block_rows).sum(),
+            "experts_active": (sizes > 0).sum(),
+            "expert_rows_max": sizes.max()}
+
+
+def combine_path(unit):
+    from veles_tpu.runtime.metrics import registry
+    gauge = registry().gauge("vt_moe_combine_path", "",
+                             labels=("unit", "path"))
+    return {p: gauge.labels(unit=unit, path=p).value
+            for p in ("rows", "routes")}
+
+
+def assert_the_reference_layer(unit, layer, reference, params, state, x,
+                               out=jnp.sin):
+    """``y``, the gradient to every parameter and to ``x``, and the four
+    counters of ``unit`` against the reference's; returns the counters."""
+    def program(params, x):
+        y, new = unit.apply(params, state, [x], Context(train=True))
+        return jnp.sum(out(y)), (y, new["counters"])
+
+    def plain(params, x):
+        y, _ = reference(layer, params, x, cast_float32, ())
+        return jnp.sum(out(y)), y
+
+    (_, (y, counters)), (gp, gx) = jax.jit(jax.value_and_grad(
+        program, argnums=(0, 1), has_aux=True))(params, x)
     with jax.default_matmul_precision("highest"):
-        ref, _ = afmoe._routed_experts(layer, params, x[None], cast_float32,
-                                       ())
-    np.testing.assert_allclose(y, ref[0], atol=1e-5)
+        (_, ref_y), (rp, rx) = jax.jit(jax.value_and_grad(
+            plain, argnums=(0, 1), has_aux=True))(params, x)
+    np.testing.assert_allclose(y, ref_y, atol=1e-5)
+    np.testing.assert_allclose(gx, rx, atol=1e-5, rtol=1e-4)
+    assert set(gp) == set(rp)
+    for name in gp:
+        np.testing.assert_allclose(gp[name], rp[name], atol=1e-5, rtol=1e-4,
+                                   err_msg=name)
+    want = reference_counters(layer, params, x)
+    assert {k: int(v) for k, v in counters.items()} == want
+    return counters
+
+
+@pytest.mark.parametrize("gated", [True, False])
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_kernel_and_ragged_dot_paths_agree(use_pallas, gated):
+    """The grouped kernels with the row-summing kernel around them, and
+    ``ragged_dot`` with ``take_rows`` and the ``einsum``: ``y``, every
+    gradient and the four counters are the reference's either way, gated
+    or not, and the gauge says which way the combine went."""
+    cut = dict(experts_held=4, expert_offset=2)
+    reference, layer = reference_layer(gated, **cut)
+    name = f"paths_{use_pallas}_{gated}"
+    unit = unit_of(layer, name=name, use_pallas=use_pallas)
+    params, state = unit.init(jax.random.key(7),
+                              [Spec((2, T, E), jnp.float32)])
+    assert ("wg" in params) == gated
+    x = jax.random.normal(jax.random.key(8), (2, T, E))
+    counters = assert_the_reference_layer(unit, layer, reference, params,
+                                          state, x)
+    assert 0 < int(counters["rows_routed"]) < 2 * T * 2
+    assert combine_path(name) == {"rows": float(use_pallas),
+                                  "routes": float(not use_pallas)}
 
 
 def test_counters_reach_the_registry_at_the_epoch_drain():
@@ -360,41 +432,142 @@ def test_grouped_matmul_kernels_on_ragged_groups(sizes):
             assert not np.asarray(dr)[e].any()
 
 
+@pytest.mark.parametrize("gated", [True, False])
 @pytest.mark.parametrize("crowd", [False, True])
-def test_small_and_large_buffer_give_the_reference(crowd):
+def test_small_and_large_buffer_give_the_reference(crowd, gated):
     """2 of 8 experts held: uniform routing fits the small buffer; every
-    token sent to both held experts needs the large one.  Same answer
-    and gradients as the reference either way, nothing dropped."""
-    layer = expert_layer(experts_held=2, expert_offset=4, shared_width=0)
+    token sent to both held experts needs the large one.  Same answer,
+    gradients and counters as the reference either way, nothing dropped,
+    the row-summing kernel on both sides of the grouped ones."""
+    cut = dict(experts_held=2, expert_offset=4)
+    reference, layer = reference_layer(gated, **cut)
     small, large = moe.buffer_rows(3 * T, 2, 2, 8, 8)
     assert (small, large) == (88, 112)
-    _, (params, _) = expert_params(jax.random.key(9), held=2)
-    params = {k: v for k, v in params.items() if "shared" not in k}
+    name = f"buffers_{crowd}_{gated}"
+    unit = unit_of(layer, name=name)
+    params, state = unit.init(jax.random.key(9),
+                              [Spec((1, 3 * T, E), jnp.float32)])
     if crowd:
         params["router"] = params["router"] * 0.01 \
             + jnp.zeros((E, 8)).at[:, 4:6].set(0.5)
     x = jnp.abs(jax.random.normal(jax.random.key(10), (1, 3 * T, E)))
-
-    def program(params, x):
-        y, counters = moe.routed_experts_apply(
-            params, x[0], top_k=2, n_held=2, offset=4, route_scale=2.826,
-            block_rows=8, use_pallas=True)
-        return jnp.sum(jnp.sin(y)), (y, counters)
-
-    def reference(params, x):
-        y, _ = afmoe._routed_experts(layer, params, x, cast_float32, ())
-        return jnp.sum(jnp.sin(y)), y[0]
-
-    (_, (y, counters)), (gp, gx) = jax.jit(jax.value_and_grad(
-        program, argnums=(0, 1), has_aux=True))(params, x)
-    with jax.default_matmul_precision("highest"):
-        (_, ref_y), (rp, rx) = jax.jit(jax.value_and_grad(
-            reference, argnums=(0, 1), has_aux=True))(params, x)
+    counters = assert_the_reference_layer(unit, layer, reference, params,
+                                          state, x)
     assert (int(counters["rows_computed"]) > small) == crowd
     if crowd:
         assert int(counters["rows_routed"]) == 2 * 3 * T
-    np.testing.assert_allclose(y, ref_y, atol=1e-5)
-    np.testing.assert_allclose(gx, rx, atol=1e-5, rtol=1e-4)
-    for name in gp:
-        np.testing.assert_allclose(gp[name], rp[name], atol=1e-5, rtol=1e-4,
-                                   err_msg=name)
+    assert combine_path(name) == {"rows": 1.0, "routes": 0.0}
+
+
+# -- the kernel that sums the buffer's rows by token ---------------------------
+
+def rows_case(K, D, large, seed=0, tokens=24, block_rows=8):
+    """A buffer of rows as a step's routes name them: token 0 has no row,
+    token 1 has all K, the others some; rows that no route names, the
+    whole last tile among them, hold NaN.  ``large``: every other route
+    has a row (the large buffer's case); else a sixth of them."""
+    rng = np.random.default_rng(seed)
+    routes = tokens * K
+    M = (routes + 2 * block_rows) if large else 64
+    some = rng.permutation(np.arange(2 * K, routes))
+    some = some[:len(some) if large else routes // 6]
+    named = np.concatenate([np.arange(K, 2 * K), some])
+    slots = rng.permutation(M - block_rows)[:len(named)]
+    row = np.full(routes, moe.NO_ROW, np.int32)
+    row[named] = slots
+    route_of_row = np.full(M, routes, np.int32)
+    route_of_row[slots] = named
+    rows = rng.standard_normal((M, D)).astype(np.float32)
+    rows[route_of_row == routes] = np.nan
+    w = rng.standard_normal((tokens, K)).astype(np.float32)
+    return (jnp.asarray(rows), jnp.minimum(jnp.asarray(row), M).reshape(
+        tokens, K), jnp.asarray(route_of_row), jnp.asarray(w))
+
+
+def sum_over_routes(rows, row, w=None):
+    """``sum_k w[t, k] * rows[row[t, k]]`` over the routes that have a
+    row, in numpy float32."""
+    rows, row = np.asarray(rows, np.float32), np.asarray(row)
+    picked = np.where((row < len(rows))[..., None],
+                      rows[np.minimum(row, len(rows) - 1)], 0.0)
+    return picked.sum(axis=1) if w is None \
+        else np.einsum("tk,tkd->td", np.asarray(w), picked)
+
+
+@pytest.mark.parametrize("large", [False, True])
+@pytest.mark.parametrize("D", [256, 384])
+@pytest.mark.parametrize("K", [6, 8])
+def test_combine_by_the_row_sum_is_take_rows_and_einsum(K, D, large):
+    """Values and the gradients to the rows and the weights, interpreted
+    (D = 384 is, like 2688, no multiple of 1024)."""
+    rows, row, route_of_row, w = rows_case(K, D, large, seed=K + D)
+    target = jnp.cos(jnp.arange(w.shape[0] * D, dtype=jnp.float32)
+                     ).reshape(-1, D)
+
+    def plain(rows, w):
+        picked = moe.take_rows(rows, row.reshape(-1), route_of_row[:, None])
+        y = jnp.einsum("tk,tkd->td", w, picked.reshape(*w.shape, -1))
+        return jnp.sum(y * target), y
+
+    def kernel(rows, w):
+        y = moe.combine_rows(8, rows, w, route_of_row)
+        return jnp.sum(y * target), y
+
+    (_, y), (d_rows, d_w) = jax.value_and_grad(
+        kernel, argnums=(0, 1), has_aux=True)(rows, w)
+    (_, want), (want_rows, want_w) = jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True)(rows, w)
+    assert y.dtype == jnp.float32 and bool(jnp.isfinite(y).all())
+    assert not np.asarray(y[0]).any()               # no row: exact zeros
+    assert np.asarray(y[1]).all()                   # all K of them
+    np.testing.assert_allclose(y, want, atol=1e-5)
+    np.testing.assert_allclose(d_rows, want_rows, atol=1e-6)
+    np.testing.assert_allclose(d_w, want_w, atol=1e-4)
+    assert not np.asarray(d_w)[np.asarray(row) >= rows.shape[0]].any()
+
+
+@pytest.mark.parametrize("large", [False, True])
+@pytest.mark.parametrize("D", [256, 384])
+@pytest.mark.parametrize("K", [6, 8])
+def test_dispatch_gradient_by_the_row_sum_is_take_rows_gradient(K, D, large):
+    """Without weights: ``dx[t]`` is the sum of the cotangent's rows that
+    token ``t``'s routes name, summed in float32 and cast; the garbage in
+    the rows no route names reaches nothing."""
+    g, row, route_of_row, _ = rows_case(K, D, large, seed=K * D)
+    g = g.astype(jnp.bfloat16)
+    x = jnp.zeros((row.shape[0], D), jnp.bfloat16)
+    token_of_row = route_of_row // K
+    dx, = jax.vjp(lambda x: moe.rows_by_token(8, x, token_of_row), x)[1](g)
+    want, = jax.vjp(lambda x: moe.take_rows(x, token_of_row, row), x)[1](g)
+    assert dx.dtype == jnp.bfloat16 and bool(jnp.isfinite(dx).all())
+    assert not np.asarray(dx[0]).any()
+    # both sum in float32 and round once; the orders differ (ascending
+    # row against ascending k)
+    np.testing.assert_allclose(np.asarray(dx, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2,
+                               rtol=1e-2)
+    exact = pk.sum_rows_by_token(g, token_of_row, row.shape[0],
+                                 block_rows=8)
+    np.testing.assert_allclose(exact, sum_over_routes(g, row), atol=1e-5)
+
+
+def test_row_sum_with_no_named_row_writes_zeros():
+    rows = jnp.full((32, 256), jnp.nan, jnp.bfloat16)
+    out = pk.sum_rows_by_token(rows, jnp.full((32,), 12, jnp.int32), 12,
+                               block_rows=8)
+    assert out.shape == (12, 256) and not np.asarray(out).any()
+
+
+def test_row_sum_goes_by_token_blocks_when_the_sums_outgrow_vmem(
+        monkeypatch):
+    """20 tokens x 256 float32 is 20,480 B: held to 8 KiB the sums go in
+    three blocks of 8 tokens, the last one padded, with the same
+    result."""
+    monkeypatch.setattr(pk, "_ROW_SUM_ACC_BYTES", 8192)
+    rows, row, route_of_row, w = rows_case(6, 256, True, seed=3, tokens=20)
+    rows = rows.astype(jnp.bfloat16)
+    w_of_row = moe._gather_or_zero(w.reshape(-1), route_of_row)
+    out = pk.sum_rows_by_token(rows, route_of_row // 6, 20, w_of_row,
+                               block_rows=8)
+    np.testing.assert_allclose(out, sum_over_routes(rows, row, w),
+                               atol=1e-5)

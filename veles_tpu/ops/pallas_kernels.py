@@ -1029,6 +1029,116 @@ grouped_matmul.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
 
 
 # ---------------------------------------------------------------------------
+# Rows summed by token (the routed experts' combine and dispatch gradient)
+# ---------------------------------------------------------------------------
+
+#: the float32 sums of a token block stay in VMEM while the rows walk past
+#: (42 MiB at 4096 x 2688); more tokens than this holds go block by block
+_ROW_SUM_ACC_BYTES = 48 * 1024 * 1024
+#: the sums, a tile of rows in float32 and the two tiles in flight
+_ROW_SUM_VMEM = 64 * 1024 * 1024
+
+
+def _row_sum_kernel(token_ref, weight_ref, rows_ref, out_ref, acc_ref,
+                    tile_ref, sem, *, block_rows, block_tokens):
+    b, i = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    # single rows are read at 32 bits: packed rows cannot be sliced
+    tile_ref[...] = rows_ref[...].astype(jnp.float32)
+
+    def add(r, carry):
+        t = token_ref[i * block_rows + r] - b * block_tokens
+
+        @pl.when((t >= 0) & (t < block_tokens))
+        def _():
+            acc_ref[pl.ds(t, 1), :] += weight_ref[i * block_rows + r] \
+                * tile_ref[pl.ds(r, 1), :]
+        return carry
+
+    jax.lax.fori_loop(0, block_rows, add, 0)
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _():
+        done = pltpu.make_async_copy(
+            acc_ref, out_ref.at[pl.ds(b * block_tokens, block_tokens), :],
+            sem)
+        done.start()
+        done.wait()
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_tokens", "block_rows", "interpret"))
+def _row_sum(rows, token_of_row, weight_of_row, n_tiles, *, n_tokens,
+             block_rows, interpret):
+    """``out[t] = sum of weight_of_row[r] * rows[r] over the r with
+    token_of_row[r] == t`` among the first ``n_tiles`` tiles, (n_tokens,
+    D) float32.  A ``jax.jit`` of its own: a program's equal calls are
+    one jaxpr, lowered once (``_flash_fwd``)."""
+    M, D = rows.shape
+    blocks = -(-n_tokens * D * 4 // _ROW_SUM_ACC_BYTES)
+    block_tokens = n_tokens if blocks == 1 \
+        else _round_up(-(-n_tokens // blocks), 8)
+    out = pl.pallas_call(
+        functools.partial(_row_sum_kernel, block_rows=block_rows,
+                          block_tokens=block_tokens),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(blocks, n_tiles),
+            in_specs=[pl.BlockSpec((block_rows, D),
+                                   lambda b, i, *_: (i, 0))],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((block_tokens, D), jnp.float32),
+                            pltpu.VMEM((block_rows, D), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())],
+        ),
+        out_shape=jax.ShapeDtypeStruct((blocks * block_tokens, D),
+                                       jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_ROW_SUM_VMEM),
+        interpret=interpret,
+        name="sum_rows_by_token",
+    )(token_of_row, weight_of_row, rows)
+    return out[:n_tokens]
+
+
+def sum_rows_by_token(rows, token_of_row, n_tokens, weight_of_row=None,
+                      block_rows=128, interpret=None):
+    """``out[t] = sum of weight_of_row[r] * rows[r] over the r with
+    token_of_row[r] == t`` (``weight_of_row`` None: 1), (n_tokens, D)
+    float32, added in ascending r; a zero row for a token that no row
+    names.  ``rows`` (M, D), M a multiple of ``block_rows``;
+    ``token_of_row`` (M,) int32, anything outside 0 .. n_tokens - 1 for a
+    row of no token, which reaches nothing, whatever it holds.
+
+    With ``row`` (T, K) the rows of each token's K routes (>= M: none),
+    ``token_of_row[row[t, k]] = t`` and ``weight_of_row[row[t, k]] =
+    weight[t, k]``, this is ``out[t] = sum over k with row[t, k] < M of
+    weight[t, k] * rows[row[t, k]]``, computed from the rows' side: the
+    kernel walks the rows' tiles up to the last one with a token and
+    what it fetches and adds follows the rows that have one, not T * K."""
+    M = rows.shape[0]
+    named = (token_of_row >= 0) & (token_of_row < n_tokens)
+    token_of_row = jnp.where(named, token_of_row, -1).astype(jnp.int32)
+    if weight_of_row is None:
+        weight_of_row = jnp.ones(M, jnp.float32)
+    last = jnp.max(jnp.where(named, jnp.arange(M, dtype=jnp.int32), -1))
+    # one tile at least: the sums are written, zeros if it names no token
+    n_tiles = jnp.maximum(last // block_rows + 1, 1)
+    # a backward pass traces under an empty abstract mesh and a forward
+    # pass under none: named alike, both find the one traced ``_row_sum``
+    with jax.sharding.use_abstract_mesh(jax.sharding.get_abstract_mesh()):
+        return _row_sum(rows, token_of_row,
+                        weight_of_row.astype(jnp.float32), n_tiles,
+                        n_tokens=n_tokens, block_rows=block_rows,
+                        interpret=_interpret(interpret))
+
+
+# ---------------------------------------------------------------------------
 # Paged-attention decode (fused page gather + online softmax)
 # ---------------------------------------------------------------------------
 

@@ -356,7 +356,8 @@ def take_rows(x, src, back):
 def _gather_or_zero(x, idx):
     n = x.shape[0]
     rows = jnp.take(x, jnp.minimum(idx, n - 1), axis=0)
-    return jnp.where((idx < n)[..., None], rows, jnp.zeros((), x.dtype))
+    held = (idx < n).reshape(idx.shape + (1,) * (x.ndim - 1))
+    return jnp.where(held, rows, jnp.zeros((), x.dtype))
 
 
 def _take_rows_fwd(x, src, back):
@@ -371,6 +372,76 @@ def _take_rows_bwd(res, g):
 
 
 take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+# On the kernel path the two places that would walk all T * K routes, the
+# combine and the dispatch's gradient, are sums of the buffer's rows by
+# token (``ops/pallas_kernels.sum_rows_by_token``): what they fetch follows
+# the routes that have a row here, an eighth or a sixteenth of all at
+# uniform routing, and no array of T * K rows by D columns is formed,
+# forward or backward.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def rows_by_token(block_rows, x, token_of_row):
+    """``x[token_of_row]`` (M, D), a zero row where ``token_of_row ==
+    len(x)``: the dispatch, for M a multiple of ``block_rows``.  Its
+    gradient is the kernel's sum, ``dx[t]`` = the ``g[r]`` with
+    ``token_of_row[r] == t`` added in float32."""
+    return _gather_or_zero(x, token_of_row)
+
+
+def _rows_by_token_fwd(block_rows, x, token_of_row):
+    return _gather_or_zero(x, token_of_row), (x, token_of_row)
+
+
+def _rows_by_token_bwd(block_rows, res, g):
+    from ..ops.pallas_kernels import sum_rows_by_token
+    x, token_of_row = res
+    dx = sum_rows_by_token(g, token_of_row, x.shape[0],
+                           block_rows=block_rows)
+    return dx.astype(g.dtype), None
+
+
+rows_by_token.defvjp(_rows_by_token_fwd, _rows_by_token_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def combine_rows(block_rows, out_rows, w, route_of_row):
+    """``y[t] = sum over k with row[t, k] < M of w[t, k] *
+    out_rows[row[t, k]]`` (T, D) float32, the routes' rows ``row`` given
+    by their inverse ``route_of_row`` (M,), T * K where no route has the
+    row.  The kernel sums the rows by token; the gradient works from the
+    rows' side too: one gather of ``dy`` by the rows' tokens gives both
+    ``d out_rows[r] = w_of_row[r] * dy[token_of_row[r]]`` and, as each
+    row's product with it summed over D and sent to the row's route,
+    ``dw``."""
+    from ..ops.pallas_kernels import sum_rows_by_token
+    T, K = w.shape
+    return sum_rows_by_token(
+        out_rows, route_of_row // K, T,
+        _gather_or_zero(w.reshape(-1), route_of_row), block_rows)
+
+
+def _combine_rows_fwd(block_rows, out_rows, w, route_of_row):
+    return (combine_rows(block_rows, out_rows, w, route_of_row),
+            (out_rows, w, route_of_row))
+
+
+def _combine_rows_bwd(block_rows, res, dy):
+    out_rows, w, route_of_row = res
+    dy_of_row = _gather_or_zero(dy, route_of_row // w.shape[1])
+    w_of_row = _gather_or_zero(w.reshape(-1), route_of_row)
+    # rows no product wrote hold anything, and so do their sums: they
+    # have no route, and a scatter of M sums costs a third of a gather of
+    # T * K (PERF.md section 6, PR 35)
+    dots = jnp.sum(out_rows.astype(jnp.float32) * dy_of_row, axis=-1)
+    dw = jnp.zeros(w.size, w.dtype).at[route_of_row].set(
+        dots.astype(w.dtype), mode="drop")
+    return ((w_of_row[:, None] * dy_of_row).astype(out_rows.dtype),
+            dw.reshape(w.shape), None)
+
+
+combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
 def _round_up(n: int, m: int) -> int:
@@ -457,7 +528,9 @@ def _through_buffer(M, block_rows, use_pallas, activation, x, w, wg, wu, wd,
         route_of_row = jnp.full(M, T * K, jnp.int32).at[
             row.reshape(-1)].set(jnp.arange(T * K, dtype=jnp.int32),
                                  mode="drop")
-        rows = take_rows(x, route_of_row // K, row)
+        token_of_row = route_of_row // K                # T: no route
+        rows = rows_by_token(block_rows, x, token_of_row) if use_pallas \
+            else take_rows(x, token_of_row, row)
     with jax.named_scope("moe_experts"):
         product = functools.partial(grouped_products, sizes=sizes,
                                     block_rows=block_rows,
@@ -468,8 +541,10 @@ def _through_buffer(M, block_rows, use_pallas, activation, x, w, wg, wu, wd,
         h = act(u) if g is None else act(g.astype(jnp.float32)) * u
         out_rows = product(h.astype(x.dtype), wd)
     with jax.named_scope("moe_combine"):
-        # rows no product wrote hold anything: take_rows selects, it does
+        # rows no product wrote hold anything: both paths select, they do
         # not multiply
+        if use_pallas:
+            return combine_rows(block_rows, out_rows, w, route_of_row)
         picked = take_rows(out_rows, row.reshape(-1), route_of_row[:, None])
         return jnp.einsum("tk,tkd->td", w,
                           picked.reshape(T, K, -1).astype(jnp.float32))

@@ -365,7 +365,11 @@ class RoutedExpertsFFN(Forward):
     ``route_bias`` is unit state: added to the scores for the choice of
     experts only, reached by no gradient, and left as it is by the step.
     Each step's row counts ride the state's ``counters`` to the epoch's
-    drain (``publish_counters``).
+    drain (``publish_counters``).  With the Pallas kernels (``use_pallas``;
+    by the platform when None) the combine and the dispatch's gradient
+    sum the buffer's rows by token and fetch the held routes' rows alone;
+    gauge ``vt_moe_combine_path{unit, path="rows"|"routes"}``, set when
+    traced, says which way a call went.
     """
 
     def __init__(self, n_experts: int, d_hidden: int, name=None,
@@ -424,15 +428,19 @@ class RoutedExpertsFFN(Forward):
         return params, state
 
     def apply(self, params, state, xs, ctx: Context):
+        from .. import ops
         from ..parallel.moe import routed_experts_apply
         from .nn import gated_mlp
         x = xs[0]
         flat = x.reshape(-1, x.shape[-1])
+        use_pallas = ops.use_pallas_default() if self.use_pallas is None \
+            else self.use_pallas
+        self._note_combine_path("rows" if use_pallas else "routes")
         y, counters = routed_experts_apply(
             params, flat, top_k=self.top_k, n_held=self.experts_held,
             offset=self.expert_offset, bias=state["route_bias"], route_norm=self.route_norm,
             route_scale=self.route_scale, block_rows=self.block_rows,
-            compute_dtype=self.compute_dtype, use_pallas=self.use_pallas,
+            compute_dtype=self.compute_dtype, use_pallas=use_pallas,
             activation=self.activation)
         if self.shared_width:
             with jax.named_scope("moe_shared"):
@@ -441,6 +449,17 @@ class RoutedExpertsFFN(Forward):
                                   self.activation, self.compute_dtype)
         return (y.reshape(x.shape).astype(x.dtype),
                 {"route_bias": state["route_bias"], "counters": counters})
+
+    def _note_combine_path(self, path):
+        from ..runtime.metrics import registry
+        gauge = registry().gauge(
+            "vt_moe_combine_path",
+            "1 on the way the routed experts' combine and dispatch "
+            "gradient went when last traced: rows = the kernel that sums "
+            "the buffer's rows by token; routes = a gather of every route",
+            labels=("unit", "path"))
+        for p in ("rows", "routes"):
+            gauge.labels(unit=self.name, path=p).set(float(p == path))
 
     def publish_counters(self, klass: str, sums: dict, last: dict) -> None:
         """An epoch's counters of this unit, on the host after the drain
