@@ -188,6 +188,8 @@ ONE_CHIP = {
     "sum_rows_by_token_hybrid_small_5632x2688": _row_sum(5632, 2688),
     "sum_rows_by_token_hybrid_large_25600x2688": _row_sum(25600, 2688),
     "flash_fwd_bwd_t4096_h32_kv2_d128_full": _flash(1, 4096, 32, 2, 128),
+    # the delta-rule hybrid's full layer: a share of 15 heads, no groups
+    "flash_fwd_bwd_t4096_h15_d128_full": _flash(1, 4096, 15, 15, 128),
     "flash_fwd_bwd_t4096_h32_kv4_d128_window2048": _flash_gqa_d128(2048),
     "flash_fwd_bwd_t4096_h32_kv4_d128_full": _flash_gqa_d128(None),
     "flash_fwd_bwd_trinity_window_512x512": _flash_gqa_d128(
@@ -212,6 +214,7 @@ ONE_CHIP = {
     "gather_rows_60000x784": _gather(60000, 784, 512),
     "softmax_xent_opt_8192x50272": _xent(8192, 50272),
     "softmax_xent_trinity_4096x25024": _xent(4096, 25024),
+    "softmax_xent_hybrid_share_4096x12544": _xent(4096, 12544),
     "softmax_xent_bf16_8192x50272": _xent(8192, 50272, "bfloat16"),
 }
 

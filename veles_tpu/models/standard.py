@@ -19,7 +19,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional, Sequence
 
 from ..ops.optimizers import HyperParams, OPTIMIZERS, Optimizer
-from ..units import nn, parallel_nn, recurrent, ssm
+from ..units import linear_attention, nn, parallel_nn, recurrent, ssm
 from ..units.workflow import Workflow
 
 LAYER_TYPES = {
@@ -58,6 +58,7 @@ LAYER_TYPES = {
     "gated_mlp": nn.GatedMLP,
     "add": nn.Add,
     "mamba2": ssm.Mamba2Mixer,
+    "gated_delta_net": linear_attention.GatedDeltaNet,
     "seq_last": nn.SeqLast,
 }
 
@@ -66,7 +67,7 @@ LAYER_TYPES = {
 # switch); shared with PipelineStack's stage-config builder
 COMPUTE_DTYPE_TYPES = ("all2all", "softmax", "conv", "deconv", "rnn",
                        "gru", "lstm", "attention", "ffn", "gated_mlp",
-                       "routed_experts", "mamba2")
+                       "routed_experts", "mamba2", "gated_delta_net")
 
 
 def build_workflow(name: str, layers: Sequence[dict], *,

@@ -1,0 +1,165 @@
+"""The gated delta rule of a linear-attention mixer as a chunked program
+(Yang, Kautz, Hatamizadeh 2025, "Gated Delta Networks"; the chunked form
+is that of Hugging Face ``transformers``
+``modeling_qwen3_next.torch_chunk_gated_delta_rule``).
+
+Per head, with a key ``k_t`` and a query ``q_t`` of ``dk`` channels, a
+value ``v_t`` of ``dv``, a log-decay ``g_t <= 0`` and a step ``beta_t``::
+
+    S_t = exp(g_t) S_{t-1}
+    S_t = S_t + k_t (beta_t (v_t - S_t^T k_t))^T         (dk x dv), S_0 = 0
+    o_t = S_t^T q_t
+
+The transition ``exp(g_t) (I - beta_t k_t k_t^T)`` is a full matrix a
+token, so no diagonal scan (``ops/ssd.py``) computes it.  In chunks of Q
+tokens, with ``G`` the running sum of ``g`` inside a chunk, ``K_beta =
+beta K`` and ``V_beta = beta V``::
+
+    A = -tril((K_beta K^T) o exp(G_i - G_j), -1)          strictly lower
+    T = (I - A)^-1
+    U = T V_beta,   W = T (K_beta o exp(G))
+
+and from the state ``S`` a chunk starts with::
+
+    v_new = U - W S
+    o     = (Q o exp(G)) S + tril((Q K^T) o exp(G_i - G_j)) v_new
+    S'    = exp(G_last) S + (K o exp(G_last - G))^T v_new
+
+``S'`` is linear in ``S``: ``S' = exp(G_last) S - P S + N`` with ``P =
+Kd^T W`` (dk x dk) and ``N = Kd^T U`` (dk x dv), ``Kd = K o exp(G_last -
+G)``.  ``P`` and ``N`` are products of every chunk at once, so the scan
+over the T / Q chunks carries the state through one dk x dk x dv product
+a step and nothing else; ``v_new`` and ``o`` of all the chunks follow
+from the states it leaves, again at once.
+
+``T``: ``A`` is nilpotent (``A^Q = 0``), so ``(I - A)^-1 = I + A + ... +
+A^(Q-1)``, taken row by row (forward substitution: row i from the rows
+before it).  Its gradient needs no pass through that loop: ``dA = T^T dT
+T^T``.
+
+Running sums, exponentials, ``T`` and the carried state are float32; the
+products take ``compute_dtype`` operands with float32 accumulation.
+
+``gated_delta`` is the expression under ``jax.checkpoint``: its residuals
+are its inputs, and the backward computes the masks, ``T`` and the states
+again.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _inverse_rows(a):
+    """``unit_lower_inverse`` as autodiff would take it, through the loop."""
+    q = a.shape[-1]
+
+    def row(i, t):
+        a_i = jax.lax.dynamic_index_in_dim(a, i, axis=-2, keepdims=False)
+        new = a_i + jnp.einsum("...j,...jk->...k", a_i, t,
+                               precision=_HIGHEST)
+        return jax.lax.dynamic_update_index_in_dim(t, new, i, axis=-2)
+
+    # t holds T - I: rows not reached yet are zero, and so is a[i, j >= i]
+    return jax.lax.fori_loop(1, q, row, jnp.zeros_like(a)) \
+        + jnp.eye(q, dtype=a.dtype)
+
+
+@jax.custom_vjp
+def unit_lower_inverse(a):
+    """``(I - a)^-1`` of strictly lower-triangular ``a`` (..., Q, Q),
+    float32: forward substitution, ``T[i] = e_i + a[i] T``, row i from
+    the rows above it."""
+    return _inverse_rows(a)
+
+
+def _inverse_fwd(a):
+    t = _inverse_rows(a)
+    return t, t
+
+
+def _inverse_bwd(t, dt):
+    tt = jnp.swapaxes(t, -1, -2)
+    da = jnp.matmul(jnp.matmul(tt, dt, precision=_HIGHEST), tt,
+                    precision=_HIGHEST)
+    return (jnp.tril(da, -1),)
+
+
+unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+
+
+def _product(spec, a, b, dtype):
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def gated_delta_chunked(q, k, v, g, beta, chunk=64, compute_dtype=None):
+    """The recurrence as the chunked expression in ``jax.numpy``: q and k
+    (b, t, h, dk), v (b, t, h, dv), g and beta (b, t, h) -> o (b, t, h,
+    dv) float32.  ``q`` comes scaled; nothing here normalises."""
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    if t % chunk:
+        raise ValueError(f"the chunked delta rule takes whole chunks: T = "
+                         f"{t} is no multiple of {chunk}")
+    c = t // chunk
+    dtype = compute_dtype or q.dtype
+    f32 = jnp.float32
+    by_chunk = lambda a: a.reshape((b, c, chunk) + a.shape[2:])
+    # a token's scalars beside its rows: (b, c, Q, h, 1)
+    g, beta = (by_chunk(a.astype(f32))[..., None] for a in (g, beta))
+    qc, kc, vc = (by_chunk(a) for a in (q, k, v))
+    cum = jnp.cumsum(g, axis=2)
+    last = cum[:, :, -1:]
+    with jax.named_scope("gdn_chunk"):
+        # (b, c, h, Q, Q): token i's running sum less token j's
+        rows = cum[..., 0].transpose(0, 1, 3, 2)
+        seg = rows[..., :, None] - rows[..., None, :]
+        causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+        # masked before the exponential: above the diagonal the sum is
+        # positive and may overflow, and 0 x inf is what a gradient gets
+        decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+        k_beta = kc.astype(f32) * beta
+        a = -jnp.tril(_product("bcihd,bcjhd->bchij", k_beta, kc, dtype)
+                      * decay, -1)
+        inv = unit_lower_inverse(a)
+        u = _product("bchij,bcjhe->bcihe", inv, vc.astype(f32) * beta, dtype)
+        w = _product("bchij,bcjhd->bcihd", inv, k_beta * jnp.exp(cum), dtype)
+    with jax.named_scope("gdn_carry"):
+        kd = kc.astype(f32) * jnp.exp(last - cum)
+        p = _product("bcihd,bcihf->bchdf", kd, w, dtype)       # Kd^T W
+        n = _product("bcihd,bcihe->bchde", kd, u, dtype)       # Kd^T U
+        over_chunk = jnp.exp(last[:, :, 0, :, 0])              # (b, c, h)
+
+        def step(s, chunk_):
+            p_c, n_c, d_c = chunk_
+            out = d_c[..., None, None] * s + n_c \
+                - _product("bhdf,bhfe->bhde", p_c, s, dtype)
+            return out, s
+
+        _, starts = jax.lax.scan(
+            step, jnp.zeros((b, h, dk, dv), f32),
+            tuple(jnp.moveaxis(x, 1, 0) for x in (p, n, over_chunk)))
+        starts = jnp.moveaxis(starts, 0, 1)                    # (b, c, h, dk, dv)
+    with jax.named_scope("gdn_chunk"):
+        v_new = u - _product("bcihd,bchde->bcihe", w, starts, dtype)
+        scores = jnp.where(
+            causal, _product("bcihd,bcjhd->bchij", qc, kc, dtype) * decay,
+            0.0)
+        o = _product("bcihd,bchde->bcihe", qc.astype(f32) * jnp.exp(cum),
+                     starts, dtype) \
+            + _product("bchij,bcjhe->bcihe", scores, v_new, dtype)
+    return o.reshape(b, t, h, dv)
+
+
+def gated_delta(q, k, v, g, beta, chunk=64, compute_dtype=None):
+    """``gated_delta_chunked`` keeping its inputs alone for the backward
+    (the module's docstring)."""
+    return jax.checkpoint(functools.partial(
+        gated_delta_chunked, chunk=chunk, compute_dtype=compute_dtype))(
+            q, k, v, g, beta)

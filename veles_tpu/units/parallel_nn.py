@@ -45,7 +45,7 @@ class MultiHeadAttention(Forward):
                  n_kv_heads: Optional[int] = None, rope: bool = False,
                  residual: bool = False,
                  use_flash: Optional[bool] = None,
-                 qk_norm: bool = False, gate: bool = False,
+                 qk_norm=False, gate: bool = False,
                  norm_eps: float = 1e-5):
         super().__init__(name, inputs)
         self.n_heads = int(n_heads)
@@ -60,10 +60,15 @@ class MultiHeadAttention(Forward):
         # y = x + attn(x): the transformer residual stream (stacked
         # attention layers can't compose circuits without it)
         self.residual = bool(residual)
-        # RMS normalisation of every head's q and k over the head's
-        # channels (learnable scales ``q_norm``, ``k_norm``), before the
-        # rotary embedding
-        self.qk_norm = bool(qk_norm)
+        # RMS normalisation of q and k (learnable scales ``q_norm``,
+        # ``k_norm``), before the rotary embedding: True or "head", of
+        # every head over the head's channels; "projection", of the whole
+        # of ``x Wq`` and of ``x Wk`` over all the held channels, before
+        # the heads are split
+        if qk_norm not in (False, None, True, "head", "projection"):
+            raise ValueError(f"qk_norm is True, 'head' or 'projection', "
+                             f"not {qk_norm!r}")
+        self.qk_norm = "head" if qk_norm is True else (qk_norm or None)
         self.norm_eps = float(norm_eps)
         # the heads' output times sigmoid(x Wg) before the out projection
         self.gate = bool(gate)
@@ -202,9 +207,12 @@ class MultiHeadAttention(Forward):
             "wv": _uniform_init(kv, (E, Hk * D), E),
             "wo": _uniform_init(ko, (H * D, E), H * D),
         }
-        if self.qk_norm:
+        if self.qk_norm == "head":
             params["q_norm"] = jnp.ones((D,))
             params["k_norm"] = jnp.ones((D,))
+        elif self.qk_norm:
+            params["q_norm"] = jnp.ones((H * D,))
+            params["k_norm"] = jnp.ones((Hk * D,))
         if self.gate:
             # a key of its own: the four above stay what they were
             params["wg"] = _uniform_init(jax.random.fold_in(key, 4),
@@ -222,14 +230,26 @@ class MultiHeadAttention(Forward):
         xq = x.astype(dt)
         mode = ctx.collective_mode(self.seq_axis)
 
-        def proj(w, nh):
-            return (xq @ w.astype(dt)).reshape(B, T, nh, -1)
-
-        q = proj(params["wq"], H)
-        k = proj(params["wk"], self.n_kv_heads)
-        v = proj(params["wv"], self.n_kv_heads)
         if self.qk_norm:
+            from ..runtime.metrics import registry
             from .nn import rms_normalize
+            registry().gauge(
+                "vt_attn_qk_norm",
+                "1 on the kind of QK norm the attention unit's last traced "
+                "call took", labels=("unit", "kind")).labels(
+                    unit=self.name, kind=self.qk_norm).set(1)
+        whole = self.qk_norm == "projection"
+
+        def proj(w, nh, norm=None):
+            y = xq @ w.astype(dt)
+            if norm is not None:
+                y = rms_normalize(y, params[norm], self.norm_eps)
+            return y.reshape(B, T, nh, -1)
+
+        q = proj(params["wq"], H, "q_norm" if whole else None)
+        k = proj(params["wk"], self.n_kv_heads, "k_norm" if whole else None)
+        v = proj(params["wv"], self.n_kv_heads)
+        if self.qk_norm == "head":
             q = rms_normalize(q, params["q_norm"], self.norm_eps)
             k = rms_normalize(k, params["k_norm"], self.norm_eps)
         if self.rope:
