@@ -29,6 +29,7 @@ from veles_tpu.units.parallel_nn import (MultiHeadAttention,  # noqa: E402
                                          RoutedExpertsFFN)
 
 E, T, VOCAB = 32, 16, 64
+NO_ROW = 2 ** 30           # a route without a row, in the plain references
 ROUTED = dict(type="routed_experts", n_experts=8, d_hidden=16, top_k=2,
               route_scale=2.826, shared_width=16, block_rows=8,
               use_pallas=True)
@@ -391,7 +392,7 @@ def test_grouped_matmul_kernels_on_ragged_groups(sizes):
     M = 64
     sizes_j = jnp.asarray(sizes, jnp.int32)
     _, row_start = pk.group_tiles(sizes_j, tm)
-    n_tiles, tile_group = pk._tile_groups(sizes_j, M, tm)
+    n_tiles, tile_group = pk.tile_groups(sizes_j, M, tm)
     row_start = np.asarray(row_start)
     assert int(n_tiles) == sum(-(-s // tm) for s in sizes)
     rng = np.random.default_rng(sum(sizes))
@@ -473,7 +474,7 @@ def rows_case(K, D, large, seed=0, tokens=24, block_rows=8):
     some = some[:len(some) if large else routes // 6]
     named = np.concatenate([np.arange(K, 2 * K), some])
     slots = rng.permutation(M - block_rows)[:len(named)]
-    row = np.full(routes, moe.NO_ROW, np.int32)
+    row = np.full(routes, NO_ROW, np.int32)
     row[named] = slots
     route_of_row = np.full(M, routes, np.int32)
     route_of_row[slots] = named

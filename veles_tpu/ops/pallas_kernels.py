@@ -859,14 +859,17 @@ def group_tiles(group_sizes, block_rows: int):
     return tiles.astype(jnp.int32), row_start.astype(jnp.int32)
 
 
-def _tile_groups(group_sizes, n_rows: int, block_rows: int):
+def tile_groups(group_sizes, n_rows: int, block_rows: int):
     """``(n_tiles (), tile_group (n_rows // block_rows,))``: the tiles in
     use and each tile's group (tiles past ``n_tiles`` name the last)."""
     tiles, _ = group_tiles(group_sizes, block_rows)
     tile_end = jnp.cumsum(tiles)
     t = jnp.arange(n_rows // block_rows, dtype=jnp.int32)
-    tile_group = jnp.minimum(jnp.searchsorted(tile_end, t, side="right"),
-                             group_sizes.shape[0] - 1)
+    # compared with every group's end at once: the default's binary search
+    # is a loop of dependent gathers
+    tile_group = jnp.minimum(
+        jnp.searchsorted(tile_end, t, side="right", method="compare_all"),
+        group_sizes.shape[0] - 1)
     return tile_end[-1].astype(jnp.int32), tile_group.astype(jnp.int32)
 
 
@@ -1003,7 +1006,7 @@ def grouped_matmul(lhs, rhs, group_sizes, block_rows=128, block_cols=512,
     (``grouped_matmul_t``) and to the matrices (``grouped_matmul_dw``,
     ``block_cols`` wide a step) are three Pallas kernels; the rows' cost
     follows ``group_sizes``, not M."""
-    n_tiles, tile_group = _tile_groups(group_sizes, lhs.shape[0], block_rows)
+    n_tiles, tile_group = tile_groups(group_sizes, lhs.shape[0], block_rows)
     return _gmm(lhs, rhs, n_tiles, tile_group, block_rows=block_rows,
                 transpose_rhs=False, interpret=interpret)
 
@@ -1016,7 +1019,7 @@ def _gmm_vjp_fwd(lhs, rhs, group_sizes, block_rows, block_cols, interpret):
 
 def _gmm_vjp_bwd(block_rows, block_cols, interpret, res, g):
     lhs, rhs, group_sizes = res
-    n_tiles, tile_group = _tile_groups(group_sizes, lhs.shape[0], block_rows)
+    n_tiles, tile_group = tile_groups(group_sizes, lhs.shape[0], block_rows)
     g = g.astype(lhs.dtype)
     dlhs = _gmm(g, rhs, n_tiles, tile_group, block_rows=block_rows,
                 transpose_rhs=True, interpret=interpret)

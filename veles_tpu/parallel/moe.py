@@ -23,7 +23,15 @@ no capacity and no drops, an expert's rows are whatever the router gives
 it (zero included), the layer is told which experts of the router's
 width it holds and computes their part of the result, and the experts are
 gated (three matrices).  Its products are ragged grouped products sized
-by the routes (``ops/pallas_kernels.grouped_matmul``).
+by the routes (``ops/pallas_kernels.grouped_matmul``).  What it knows
+about where routes go is read off one stable sort of the routes by expert
+(``dispatch_plan``: the sort's order and the experts' sizes;
+``rows_routes``: the route in each row of a buffer, a gather of the
+buffer's M rows from that order), and a route's score is selected, not
+gathered (``chosen_scores``): on the kernel path no scatter or gather of
+T * K single elements exists, forward or backward, because on the TPU
+each costs 3 to 9 ns an element where a vector pass over all of them is
+microseconds.
 """
 
 from __future__ import annotations
@@ -286,43 +294,74 @@ def route_scores(x, router, *, top_k: int, bias=None,
     choose = scores if bias is None \
         else scores + jax.lax.stop_gradient(bias)
     _, topi = jax.lax.top_k(jax.lax.stop_gradient(choose), top_k)
-    w = jnp.take_along_axis(scores, topi, axis=-1)
+    w = chosen_scores(scores, topi)
     if route_norm:
         w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
     return w * route_scale, topi
 
 
-#: ``dispatch_plan``'s row of a route to an expert that is not held
-NO_ROW = 2 ** 30
+def chosen_scores(scores, topi):
+    """``scores[t, topi[t, k]]`` (T, K) by selection, not by a gather: the
+    sum over the router's width of the scores where the expert is the
+    chosen one has one term that is not zero, so it is ``take_along_axis``
+    bit for bit, and its gradient is the same selection summed over a
+    token's routes (whose experts are distinct), where a gather's would
+    scatter T * K single elements into (T, n_experts).  Written experts
+    by tokens: with the tokens in the lanes a route's expert is spread
+    over sublanes and the sum over experts adds sublanes (PERF.md section
+    6, PR 37: 0.008 ms a layer where routes by experts took 0.133)."""
+    experts = jnp.arange(scores.shape[-1], dtype=topi.dtype)[None, :, None]
+    picked = jnp.where(topi.T[:, None, :] == experts, scores.T[None], 0.0)
+    return jnp.sum(picked, axis=1).T                    # (K, E, T) summed
 
 
 def dispatch_plan(topi, n_held: int, offset: int, block_rows: int):
-    """Where each route goes among the rows sorted by expert, each
-    expert's rows starting at a multiple of ``block_rows``.
+    """The one stable sort of the routes by expert, and what is read off
+    it: ``(sizes (n_held,), order (T * K,), rows_needed ())``.
 
-    Routes to experts ``offset .. offset + n_held`` are sorted by expert
-    (stable: by token within an expert).  Returns ``(sizes (n_held,),
-    row_of_route (T, K), rows_needed ())``: the routes each held expert
-    got, each route's row (``NO_ROW`` for an expert not held), and the
-    rows up to the last expert's last tile."""
-    T, K = topi.shape
-    R = T * K
+    A route's key is its expert's index among the held ones, ``offset ..
+    offset + n_held``, or ``n_held`` for an expert held elsewhere, so the
+    held experts' routes come first in ``order``, expert by expert and by
+    token within an expert, each route ``t * K + k`` once.  ``sizes`` are
+    the routes each held expert got, counted by comparing the keys with
+    the experts' indices (a vector pass, not a scatter-add of ones);
+    ``rows_needed`` the rows up to the last expert's last tile when each
+    expert's rows start at a multiple of ``block_rows``.  Which route
+    sits in which row of a buffer is ``rows_routes``'s to say, from
+    these alone: no array here is indexed by T * K single elements."""
+    from ..ops.pallas_kernels import group_tiles
     local = topi.reshape(-1) - offset
     key = jnp.where((local >= 0) & (local < n_held), local, n_held)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sorted_key = key[order]
-    sizes_all = jnp.zeros(n_held + 1, jnp.int32).at[key].add(1)
-    sizes = sizes_all[:n_held]
-    from ..ops.pallas_kernels import group_tiles
-    tiles, row_start = group_tiles(sizes, block_rows)
-    first = jnp.cumsum(sizes_all) - sizes_all
-    rank = jnp.arange(R, dtype=jnp.int32) - first[sorted_key]
-    row_sorted = jnp.where(
-        sorted_key < n_held,
-        jnp.concatenate([row_start, jnp.zeros(1, jnp.int32)])[sorted_key]
-        + rank, NO_ROW)
-    row_of_route = jnp.zeros(R, jnp.int32).at[order].set(row_sorted)
-    return sizes, row_of_route.reshape(T, K), jnp.sum(tiles) * block_rows
+    held = jnp.arange(n_held, dtype=key.dtype)[:, None] == key[None, :]
+    sizes = jnp.sum(held, axis=1, dtype=jnp.int32)
+    tiles, _ = group_tiles(sizes, block_rows)
+    return sizes, order, jnp.sum(tiles) * block_rows
+
+
+def rows_routes(M: int, sizes, order, block_rows: int):
+    """``route_of_row`` (M,): the route in each row of a buffer of M rows
+    (a multiple of ``block_rows``), T * K where the row has none, for
+    ``dispatch_plan``'s ``sizes`` and ``order``.
+
+    Held expert e's routes are ``order[first[e] : first[e] + sizes[e]]``
+    and its rows start at ``row_start[e]``, so row r of expert ``e_r``
+    holds ``order[first[e_r] + r - row_start[e_r]]`` while ``r -
+    row_start[e_r] < sizes[e_r]``.  A tile of rows has one expert
+    (``tile_groups``), so what depends on the expert is looked up once a
+    tile and ``order`` is gathered once a row: M elements, where the
+    inverse of a route-to-row map is a scatter of T * K."""
+    from ..ops.pallas_kernels import group_tiles, tile_groups
+    R = order.shape[0]
+    _, row_start = group_tiles(sizes, block_rows)
+    first = jnp.cumsum(sizes) - sizes
+    _, tile_group = tile_groups(sizes, M, block_rows)
+    tile_row = jnp.arange(M // block_rows, dtype=jnp.int32) * block_rows
+    nth = (tile_row - row_start[tile_group])[:, None] \
+        + jnp.arange(block_rows, dtype=jnp.int32)
+    at = jnp.minimum(first[tile_group][:, None] + nth, R - 1).reshape(M)
+    has_route = (nth < sizes[tile_group][:, None]).reshape(M)
+    return jnp.where(has_route, order[at], R)
 
 
 def buffer_rows(n_tokens: int, top_k: int, n_held: int, n_experts: int,
@@ -501,15 +540,15 @@ def routed_experts_apply(params: dict, x: jnp.ndarray, *, top_k: int,
                                route_norm=route_norm,
                                route_scale=route_scale)
     with jax.named_scope("moe_dispatch"):
-        sizes, row_of_route, rows_needed = dispatch_plan(
+        sizes, order, rows_needed = dispatch_plan(
             topi, n_held, offset, block_rows)
     small, large = buffer_rows(T, topi.shape[1], n_held,
                                params["router"].shape[1], block_rows)
     y = experts_through_buffers(
         (small, large, block_rows, bool(use_pallas), activation),
         x.astype(dt), w, None if wg is None else wg.astype(dt),
-        params["wu"].astype(dt), params["wd"].astype(dt), row_of_route,
-        sizes, rows_needed)
+        params["wu"].astype(dt), params["wd"].astype(dt), order, sizes,
+        rows_needed)
     counters = {"rows_routed": jnp.sum(sizes),
                 "rows_computed": rows_needed,
                 "experts_active": jnp.sum((sizes > 0).astype(jnp.int32)),
@@ -518,19 +557,25 @@ def routed_experts_apply(params: dict, x: jnp.ndarray, *, top_k: int,
 
 
 def _through_buffer(M, block_rows, use_pallas, activation, x, w, wg, wu, wd,
-                    row_of_route, sizes):
+                    order, sizes):
     """The held experts' part of y (T, D) float32, the sorted rows going
     through a buffer of M rows (which has to hold them).  ``wg`` None:
-    experts without a gate, ``wd act(wu x)``."""
-    T, K = row_of_route.shape
+    experts without a gate, ``wd act(wu x)``.
+
+    The buffer is described from its rows' side, ``rows_routes``: with
+    the kernels nothing asks for a route's row, so no map of T * K
+    entries is built.  ``take_rows`` wants that back-index, and gets it
+    as the scatter of the M row numbers to their routes."""
+    T, K = w.shape
     with jax.named_scope("moe_dispatch"):
-        row = jnp.minimum(row_of_route, M)              # M: no row
-        route_of_row = jnp.full(M, T * K, jnp.int32).at[
-            row.reshape(-1)].set(jnp.arange(T * K, dtype=jnp.int32),
-                                 mode="drop")
+        route_of_row = rows_routes(M, sizes, order, block_rows)
         token_of_row = route_of_row // K                # T: no route
-        rows = rows_by_token(block_rows, x, token_of_row) if use_pallas \
-            else take_rows(x, token_of_row, row)
+        if use_pallas:
+            rows = rows_by_token(block_rows, x, token_of_row)
+        else:
+            row = jnp.full(T * K, M, jnp.int32).at[route_of_row].set(
+                jnp.arange(M, dtype=jnp.int32), mode="drop").reshape(T, K)
+            rows = take_rows(x, token_of_row, row)      # M: no row
     with jax.named_scope("moe_experts"):
         product = functools.partial(grouped_products, sizes=sizes,
                                     block_rows=block_rows,
@@ -561,7 +606,7 @@ def _with_the_buffer_that_fits(cfg, rows_needed, make, *operands):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def experts_through_buffers(cfg, x, w, wg, wu, wd, row_of_route, sizes,
+def experts_through_buffers(cfg, x, w, wg, wu, wd, order, sizes,
                             rows_needed):
     """``_through_buffer`` at the buffer that fits, ``cfg = (small, large,
     block_rows, use_pallas, activation)``.  Its gradient runs the forward again inside
@@ -573,25 +618,24 @@ def experts_through_buffers(cfg, x, w, wg, wu, wd, row_of_route, sizes,
     again."""
     return _with_the_buffer_that_fits(
         cfg, rows_needed,
-        lambda M: lambda *a: _through_buffer(M, *cfg[2:], *a, row_of_route,
-                                             sizes),
+        lambda M: lambda *a: _through_buffer(M, *cfg[2:], *a, order, sizes),
         x, w, wg, wu, wd)
 
 
-def _experts_fwd(cfg, x, w, wg, wu, wd, row_of_route, sizes, rows_needed):
-    y = experts_through_buffers(cfg, x, w, wg, wu, wd, row_of_route, sizes,
+def _experts_fwd(cfg, x, w, wg, wu, wd, order, sizes, rows_needed):
+    y = experts_through_buffers(cfg, x, w, wg, wu, wd, order, sizes,
                                 rows_needed)
-    return y, (x, w, wg, wu, wd, row_of_route, sizes, rows_needed)
+    return y, (x, w, wg, wu, wd, order, sizes, rows_needed)
 
 
 def _experts_bwd(cfg, res, dy):
-    *operands, row_of_route, sizes, rows_needed = res
+    *operands, order, sizes, rows_needed = res
 
     def back(M):
         def run(dy, *operands):
             _, vjp = jax.vjp(
-                lambda *a: _through_buffer(M, *cfg[2:], *a, row_of_route,
-                                           sizes), *operands)
+                lambda *a: _through_buffer(M, *cfg[2:], *a, order, sizes),
+                *operands)
             return vjp(dy)
         return run
 

@@ -389,7 +389,9 @@ class RoutedExpertsFFN(Forward):
     by the platform when None) the combine and the dispatch's gradient
     sum the buffer's rows by token and fetch the held routes' rows alone;
     gauge ``vt_moe_combine_path{unit, path="rows"|"routes"}``, set when
-    traced, says which way a call went.
+    traced, says which way a call went, and ``vt_moe_plan_path{unit,
+    path="sorted"|"scattered"}`` whether the rows' routes came from the
+    sort's order alone or the back-index was scattered as well.
     """
 
     def __init__(self, n_experts: int, d_hidden: int, name=None,
@@ -450,12 +452,27 @@ class RoutedExpertsFFN(Forward):
     def apply(self, params, state, xs, ctx: Context):
         from .. import ops
         from ..parallel.moe import routed_experts_apply
+        from ..runtime.metrics import registry
         from .nn import gated_mlp
         x = xs[0]
         flat = x.reshape(-1, x.shape[-1])
         use_pallas = ops.use_pallas_default() if self.use_pallas is None \
             else self.use_pallas
-        self._note_combine_path("rows" if use_pallas else "routes")
+        self._note_path(registry().gauge(
+            "vt_moe_combine_path",
+            "1 on the way the routed experts' combine and dispatch "
+            "gradient went when last traced: rows = the kernel that sums "
+            "the buffer's rows by token; routes = a gather of every route",
+            labels=("unit", "path")),
+            ("rows", "routes"), "rows" if use_pallas else "routes")
+        self._note_path(registry().gauge(
+            "vt_moe_plan_path",
+            "1 on the way the routed experts' rows were planned when last "
+            "traced: sorted = each row's route gathered from the sort's "
+            "order, nothing indexed by every route; scattered = the rows "
+            "scattered to their routes besides, the back-index that "
+            "take_rows needs", labels=("unit", "path")),
+            ("sorted", "scattered"), "sorted" if use_pallas else "scattered")
         y, counters = routed_experts_apply(
             params, flat, top_k=self.top_k, n_held=self.experts_held,
             offset=self.expert_offset, bias=state["route_bias"], route_norm=self.route_norm,
@@ -470,15 +487,8 @@ class RoutedExpertsFFN(Forward):
         return (y.reshape(x.shape).astype(x.dtype),
                 {"route_bias": state["route_bias"], "counters": counters})
 
-    def _note_combine_path(self, path):
-        from ..runtime.metrics import registry
-        gauge = registry().gauge(
-            "vt_moe_combine_path",
-            "1 on the way the routed experts' combine and dispatch "
-            "gradient went when last traced: rows = the kernel that sums "
-            "the buffer's rows by token; routes = a gather of every route",
-            labels=("unit", "path"))
-        for p in ("rows", "routes"):
+    def _note_path(self, gauge, paths, path):
+        for p in paths:
             gauge.labels(unit=self.name, path=p).set(float(p == path))
 
     def publish_counters(self, klass: str, sums: dict, last: dict) -> None:
