@@ -1,0 +1,12 @@
+"""attn_device_ms.<items>: the attention units (class
+``MultiHeadAttention``: projections, norms, layout copies and the flash
+kernels), forward and backward, in ms of device self time a traced train
+step.  Source: the
+profiler's trace joined to the program's scope tables
+(unit_device_ms.py)."""
+
+from metrics import unit_device_ms
+
+
+def read(run):
+    return unit_device_ms.of_classes(run, "MultiHeadAttention")

@@ -25,7 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops import use_pallas_default
-from .base import ArrayLoader, TEST, TRAIN, VALID
+from ..runtime import program_scopes
+from .base import ArrayLoader, CLASS_NAMES, TEST, TRAIN, VALID
 
 # Packed-DMA-gather eligibility, calibrated to an on-chip measurement of
 # the loader's pack→gather→unpack path (3,136-byte rows at 30% pad
@@ -46,6 +47,7 @@ class FullBatchLoader(ArrayLoader):
         self._use_pallas_gather = use_pallas_gather
         self._dev_data: Dict[int, dict] = {}
         self._gather = None
+        self._compiled: Dict[tuple, object] = {}
         self.on_device = False
 
     def initialize(self):
@@ -89,6 +91,7 @@ class FullBatchLoader(ArrayLoader):
         return use_pallas_default(platform)
 
     def _upload(self, allow_pallas: bool = True):
+        self._compiled = {}
         put = (lambda x: jax.device_put(x, self._device)) \
             if self._device is not None else jax.device_put
         for klass in (TEST, VALID, TRAIN):
@@ -167,6 +170,22 @@ class FullBatchLoader(ArrayLoader):
             self._gather = {klass: take_gather
                             for klass in self._dev_data}
 
+    def _program(self, scope: str, klass: int, fn, *args):
+        """``fn``, one of the loader's jitted programs, compiled for one
+        class's arrays: once, ahead of its first call, so that what was
+        compiled can be noted like a step program (the scope table of
+        ``runtime/program_scopes.py``; a trace's events of the loader's
+        own programs then go to ``loader_gather`` / ``loader_aug``).  The
+        same program the jitted function would have compiled at that
+        call, and the only compile of it."""
+        compiled = self._compiled.get((scope, klass))
+        if compiled is None:
+            compiled = fn.lower(*args).compile()
+            program_scopes.note_compiled(
+                f"{scope}.{CLASS_NAMES[klass]}", compiled)
+            self._compiled[(scope, klass)] = compiled
+        return compiled
+
     def make_batch(self, chunk: np.ndarray, klass: int):
         if not self.on_device:
             return super().make_batch(chunk, klass)
@@ -175,8 +194,9 @@ class FullBatchLoader(ArrayLoader):
         if valid_n < bs:
             chunk = np.concatenate(
                 [chunk, np.zeros(bs - valid_n, chunk.dtype)])
-        idx = jnp.asarray(chunk, jnp.int32)
-        batch = dict(self._gather[klass](self._dev_data[klass], idx))
+        args = (self._dev_data[klass], jnp.asarray(chunk, jnp.int32))
+        batch = dict(self._program("loader_gather", klass,
+                                   self._gather[klass], *args)(*args))
         mask = np.zeros(bs, np.float32)
         mask[:valid_n] = 1.0
         batch["@mask"] = jnp.asarray(mask)
@@ -313,9 +333,10 @@ class FullBatchAugmentedLoader(FullBatchLoader):
                 [chunk, np.zeros(bs - valid_n, chunk.dtype)])
         anchor = int(chunk[0]) if valid_n else 0
         offs, flips = self._draw_aug(bs, klass, anchor)
-        batch = dict(self._aug(self._dev_data[klass],
-                               jnp.asarray(chunk, jnp.int32),
-                               jnp.asarray(offs), jnp.asarray(flips)))
+        args = (self._dev_data[klass], jnp.asarray(chunk, jnp.int32),
+                jnp.asarray(offs), jnp.asarray(flips))
+        batch = dict(self._program("loader_aug", klass, self._aug,
+                                   *args)(*args))
         mask = np.zeros(bs, np.float32)
         mask[:valid_n] = 1.0
         batch["@mask"] = jnp.asarray(mask)

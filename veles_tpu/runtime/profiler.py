@@ -21,7 +21,11 @@ Contract (docs/observability.md "On-demand profiler capture"):
 * captures land under ``root.common.observe.profile_dir`` (default
   ``<cache_dir>/profiles``) in a per-capture timestamped directory,
   returned in the response and linked from the status page —
-  TensorBoard/xprof-loadable.
+  TensorBoard/xprof-loadable.  Beside the trace files lies
+  ``program_scopes.json``: the scope tables of the programs this
+  process compiled (``runtime/program_scopes.py``), so the capture's
+  device events can be summed by unit (docs/observability.md "Device
+  time by unit").
 
 Host-side only: the capture thread blocks in ``time.sleep``, never in
 traced scope (VT103).
@@ -38,6 +42,7 @@ from typing import Optional, Tuple
 
 from ..config import root
 from ..logger import Logger
+from . import program_scopes
 from .metrics import ScopedCounter, registry
 
 _CAPTURE_IDS = itertools.count(1)
@@ -110,6 +115,12 @@ class ProfilerCapture(Logger):
                 time.sleep(dur)
             finally:
                 jax.profiler.stop_trace()
+            # the capture describes itself: which unit each instruction
+            # of the noted programs belongs to, for the same join a
+            # training run's trace gets (program_scopes.seconds_by_scope
+            # over ScopeTable.from_json of each entry)
+            with open(os.path.join(path, "program_scopes.json"), "w") as f:
+                json.dump([t.to_json() for t in program_scopes.noted()], f)
             n_files = sum(len(fs) for _b, _d, fs in os.walk(path))
             with self._lock:
                 self._last_path = path

@@ -40,6 +40,7 @@ from jax.experimental.compilation_cache import compilation_cache
 
 from ..config import root
 from ..logger import Logger
+from . import program_scopes
 from .metrics import registry, span
 
 
@@ -198,8 +199,16 @@ class StepCache(Logger):
     # -- the cache ----------------------------------------------------------
     def get_step(self, kind: str, key: Tuple,
                  builder: Callable[[], Tuple], args: Tuple, *,
-                 pin: Tuple = ()) -> Tuple:
-        """Fetch or build+AOT-compile the ``kind`` ('train'/'eval') step."""
+                 pin: Tuple = (), units: Optional[Dict] = None) -> Tuple:
+        """Fetch or build+AOT-compile the ``kind`` ('train'/'eval') step.
+
+        Every program compiled here is noted in
+        :mod:`~veles_tpu.runtime.program_scopes`: which unit, sub-scope
+        and direction each of its instructions belongs to, read from the
+        compiled module's text, so a device trace's events can be summed
+        by unit.  ``units`` (``program_scopes.workflow_units``) tells the
+        table the units' names and classes; the table holds text alone,
+        never the executable."""
         full_key = (kind,) + tuple(key)
         ent = self._entries.get(full_key)
         if ent is not None:
@@ -207,13 +216,15 @@ class StepCache(Logger):
             self._m_hits.labels(program=kind).inc()
             return ent["fn"], ent["state_sh"], ent["batch_sh"]
 
-        with span("step_compile", cat="compile", program=kind):
+        with span("step_compile", cat="compile", program=kind) as sp:
             t0 = time.perf_counter()
             fn, state_sh, batch_sh = builder()
             # a program the compiler refuses fails HERE, by name, not
             # later inside whichever call first runs a lazy jit
             compiled = fn.lower(*args).compile()
             wall = time.perf_counter() - t0
+            program_scopes.note_compiled(kind, compiled, units,
+                                         into=sp.args)
         self.compiles += 1
         self.compile_wall_s += wall
         self._m_compiles.labels(program=kind).inc()

@@ -32,6 +32,7 @@ from .decision import Decision
 from .memory import memory_monitor, tree_bytes
 from .metrics import registry, span
 from .snapshotter import (Snapshotter, _to_numpy, restore_with_walkback)
+from . import program_scopes
 from .step_cache import StepCache, enable_persistent_cache
 
 
@@ -222,6 +223,9 @@ class Trainer(Logger):
                       self.pipeline_interleave))
         pin = (self.workflow, self.rule, self.optimizer)
         args = (state_struct, dict(self._batch_spec))
+        # the units' names and classes go with the programs, so the
+        # tables noted at compile time can tell a unit's instructions
+        units = program_scopes.workflow_units(self.workflow)
         if self.mesh is not None:
             fused_pp = (self.pipeline_microbatches is not None
                         and self.mesh.shape.get("pipe", 1) > 1)
@@ -263,7 +267,7 @@ class Trainer(Logger):
 
         self._train_step, self._state_sh, self._batch_sh = \
             self.step_cache.get_step("train", key, build_train, args,
-                                     pin=pin)
+                                     pin=pin, units=units)
         # the cost of THIS trainer's live train program — never the
         # kind-sum, which double-counts superseded entries after an
         # optimizer rebuild (the cache keeps them by design)
@@ -272,14 +276,14 @@ class Trainer(Logger):
         # train-only run (no VALID/TEST data, bench loops) never pays
         # for a program it does not execute.
         self._eval_step = None
-        self._eval_entry = (key, build_eval, args, pin)
+        self._eval_entry = (key, build_eval, args, pin, units)
 
     def _ensure_eval_step(self):
         if self._eval_step is None:
-            key, build_eval, args, pin = self._eval_entry
+            key, build_eval, args, pin, units = self._eval_entry
             self._eval_step, _, _ = \
                 self.step_cache.get_step("eval", key, build_eval, args,
-                                         pin=pin)
+                                         pin=pin, units=units)
         return self._eval_step
 
     # -- epoch passes -------------------------------------------------------
@@ -520,8 +524,10 @@ class Trainer(Logger):
             valid_mets = self._run_epoch_eval(VALID, epoch)
             if root.common.timings:
                 # reference: per-unit/root.common.timings wall prints
-                # (veles/units.py:144-149); per-unit attribution needs the
-                # instrumented Workflow.profile_units mode.
+                # (veles/units.py:144-149).  These are the epoch's wall
+                # seconds; the step's device time by unit is read from a
+                # trace joined to the scope tables noted at compile time
+                # (runtime/program_scopes.py), not from per-unit jits.
                 self.info(
                     "epoch %d timings: train %.3fs (%.0f samples/s), "
                     "eval %.3fs", epoch, t_train - t_ep,

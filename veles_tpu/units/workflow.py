@@ -557,9 +557,21 @@ class Workflow(Logger):
         """Per-unit wall timing: run each unit's apply as its own jitted
         call with a forced device sync — the analog of the reference's
         ``--sync-run`` honest per-unit timers (veles/accelerated_units.py
-        :186-193, per-unit timers veles/units.py:805-817). In the fused
-        production step XLA erases unit boundaries, so this instrumented
-        mode is how per-unit cost is attributed."""
+        :186-193, per-unit timers veles/units.py:805-817).
+
+        This is NOT how the production step's cost is attributed: a unit
+        jitted alone and synced is another program than the unit inside
+        the fused step (forward only, its operands' layouts and what XLA
+        fuses across units both lost; a kernel timed alone has read three
+        times off, PERF.md section 6, PR 30).  The production step's
+        device time by unit, forward and backward, comes from its own
+        trace: every compiled step program is noted in
+        ``runtime/program_scopes.py`` (which unit each instruction
+        belongs to), and ``program_scopes.seconds_by_scope`` joins a
+        device trace's events to that (docs/observability.md "Device
+        time by unit").  What this mode is still good for: a machine
+        with no device trace (a CPU run, a backend whose profiler names
+        no operations), as a rough forward-only ranking of the units."""
         import time as _time
         ctx = Context(train=train, key=wstate.get("key"))
         outputs = dict(batch)
