@@ -209,6 +209,46 @@ def test_parse_numbers_no_operand_and_names_an_unnamed_caller():
     assert mixed.by_name["%conditional.1"].unit is None
 
 
+def test_an_unnamed_fusion_takes_what_its_instructions_agree_on():
+    """XLA rewrites a ``concatenate`` to updates in place and gives all
+    but the last no metadata: such a fusion is its instructions'."""
+    bare = MODULE.replace(
+        ', metadata={op_name="jit(step)/jvp(head)/mul" '
+        'source_file="a {b}.py"}', "")
+    assert table(text=bare).by_name["%fusion.1"][5:] == (
+        "head", "", "forward", False)
+    root = ('ROOT %mul.1 = f32[8]{0} multiply(%p, %p), metadata='
+            '{op_name="jit(step)/jvp(head)/mul"}')
+    mixer = 'metadata={op_name="jit(step)/transpose(jvp(b1_mix))/'
+
+    def fused(*lines):
+        return table(text=bare.replace(root, "\n  ".join(lines))
+                     ).by_name["%fusion.1"]
+
+    last = (f'ROOT %mul.1 = f32[8]{{0}} multiply(%mul.0, %p), {mixer}'
+            'ssm_conv/ssm_conv_bwd/mul"}')
+    # one unit, one of its instructions under a path: the path's
+    assert fused(f'%mul.0 = f32[8]{{0}} multiply(%p, %p), {mixer}'
+                 'concatenate"}', last)[5:] == (
+        "b1_mix", "ssm_conv/ssm_conv_bwd", "backward", False)
+    # working instructions of two units, however many of each: nobody's
+    other = ('%mul.0 = f32[8]{0} multiply(%p, %p), metadata={op_name='
+             '"jit(step)/jvp(attn)/mul"}')
+    assert fused(other, last).unit is None
+    assert fused(other, last.replace("ROOT %mul.1", "%mul.2"),
+                 last).unit is None
+    # XLA shares a constant between units' fusions under the first
+    # unit's name: it and what spreads it have no vote
+    assert fused(
+        '%c.0 = f32[] constant(1), metadata={op_name="jit(step)/jvp(attn)/'
+        'mul"}', '%mul.0 = f32[8]{0} broadcast(%c.0), dimensions={}, '
+        'metadata={op_name="jit(step)/jvp(attn)/mul"}', last)[5:] == (
+        "b1_mix", "ssm_conv/ssm_conv_bwd", "backward", False)
+    # a fusion XLA did name keeps its name, whatever is fused into it
+    assert table(text=MODULE.replace(root, root.replace("head", "attn"))
+                 ).by_name["%fusion.7"].unit == "b1_mix"
+
+
 def eval_module():
     """The validation step: the same instruction name as the train
     step's kernel, under another operand and another unit."""

@@ -30,7 +30,7 @@ from veles_tpu.ops import ssd as ssd_ops  # noqa: E402
 from veles_tpu.units.base import Context, Spec  # noqa: E402
 from veles_tpu.units.parallel_nn import RoutedExpertsFFN  # noqa: E402
 from veles_tpu.units.ssm import (Mamba2Mixer,  # noqa: E402
-                                 causal_depthwise_conv)
+                                 causal_conv_silu, gated_group_rms_norm)
 
 E, T, VOCAB = 32, 16, 64
 
@@ -98,19 +98,121 @@ def test_convolution_is_causal_and_depthwise():
     x = jax.random.normal(jax.random.key(0), (2, 10, 6))
     w = jax.random.normal(jax.random.key(1), (4, 6))
     b = jax.random.normal(jax.random.key(2), (6,))
-    y = causal_depthwise_conv(x, w, b)
+    y = causal_conv_silu(x, w, b)
     want = np.zeros((2, 10, 6), np.float32)
     for t in range(10):
         for k in range(4):
             if t - 3 + k >= 0:
                 want[:, t] += np.asarray(w[k]) * np.asarray(x[:, t - 3 + k])
-    np.testing.assert_allclose(y, want + np.asarray(b), atol=1e-5)
+    np.testing.assert_allclose(y, jax.nn.silu(want + np.asarray(b)),
+                               atol=1e-5)
     # a later token and another channel change nothing before or beside
-    moved = causal_depthwise_conv(x.at[:, 5, 2].add(1.0), w, b) - y
+    moved = causal_conv_silu(x.at[:, 5, 2].add(1.0), w, b) - y
     assert not np.asarray(moved[:, :5]).any()
     assert not np.asarray(moved[..., [0, 1, 3, 4, 5]]).any()
     assert np.asarray(moved[:, 5:9, 2]).all() and not \
         np.asarray(moved[:, 9:, 2]).any()
+
+
+def plain_conv(x, w, b=None):
+    """The convolution as it was written before its backward was: the
+    reference that autodiff differentiates."""
+    k, t = w.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
+    y = sum(padded[:, i:i + t] * w[i] for i in range(k))
+    return y if b is None else y + b
+
+
+def plain_gated_norm(y, z, scale, n_groups, eps):
+    h = y * jax.nn.silu(z)
+    grouped = h.reshape(h.shape[:-1] + (n_groups, -1))
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + eps)
+    return grouped.reshape(h.shape) * scale
+
+
+def residual_shapes(f, *args):
+    _, vjp = jax.vjp(f, *args)
+    return sorted(tuple(x.shape) for x in jax.tree_util.tree_leaves(vjp)
+                  if hasattr(x, "shape"))
+
+
+@pytest.mark.parametrize("tokens", [10, 2])
+@pytest.mark.parametrize("bias", [True, False])
+@pytest.mark.parametrize("taps", [4, 2])
+def test_convolutions_written_backward_is_autodiff_of_the_plain_one(
+        taps, bias, tokens):
+    """``dx``, ``dw``, ``db``, also where the sequence is shorter than
+    the taps reach."""
+    k = jax.random.split(jax.random.key(taps), 4)
+    x = jax.random.normal(k[0], (2, tokens, 6))
+    w = jax.random.normal(k[1], (taps, 6))
+    b = jax.random.normal(k[2], (6,)) if bias else None
+    weight = jax.random.normal(k[3], x.shape)
+
+    def written(x, w, b):
+        return jnp.sum(weight * causal_conv_silu(x, w, b))
+
+    def plain(x, w, b):
+        return jnp.sum(weight * jax.nn.silu(plain_conv(x, w, b)))
+
+    argnums = (0, 1, 2) if bias else (0, 1)
+    np.testing.assert_allclose(written(x, w, b), plain(x, w, b), rtol=1e-6)
+    for name, got, want in zip(("dx", "dw", "db"),
+                               jax.grad(written, argnums)(x, w, b),
+                               jax.grad(plain, argnums)(x, w, b)):
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("n_groups", [1, 2, 8])
+def test_gated_norms_written_backward_is_autodiff_of_the_plain_one(
+        n_groups):
+    k = jax.random.split(jax.random.key(n_groups), 4)
+    y, z, weight = (jax.random.normal(k[i], (2, 10, 16)) for i in range(3))
+    scale = jax.random.normal(k[3], (16,))
+
+    def loss(norm):
+        return lambda *a: jnp.sum(weight * norm(*a, n_groups, 1e-5))
+
+    np.testing.assert_allclose(
+        gated_group_rms_norm(y, z, scale, n_groups, 1e-5),
+        plain_gated_norm(y, z, scale, n_groups, 1e-5), atol=1e-6)
+    for name, got, want in zip(
+            ("dy", "dz", "dscale"),
+            jax.grad(loss(gated_group_rms_norm), (0, 1, 2))(y, z, scale),
+            jax.grad(loss(plain_gated_norm), (0, 1, 2))(y, z, scale)):
+        np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5,
+                                   err_msg=name)
+
+
+def test_the_two_stages_keep_their_inputs_alone_for_the_backward():
+    """Differentiated as written the convolution keeps its pre-activation
+    for ``silu`` beside ``x``, and the norm its grouped product and the
+    statistic; the written ones keep what they were given."""
+    x = jax.random.normal(jax.random.key(0), (1, 32, 8))
+    w, b = jnp.ones((4, 8)), jnp.zeros((8,))
+    assert len(residual_shapes(
+        lambda *a: jax.nn.silu(plain_conv(*a)), x, w, b)) > 3
+    assert residual_shapes(causal_conv_silu, x, w, b) \
+        == sorted([x.shape, w.shape, b.shape])
+    assert residual_shapes(causal_conv_silu, x, w) \
+        == sorted([x.shape, w.shape])
+    y, z, scale = x, x + 1.0, jnp.ones((8,))
+    assert (1, 32, 2, 1) in residual_shapes(
+        lambda *a: plain_gated_norm(*a, 2, 1e-5), y, z, scale)
+    assert residual_shapes(
+        lambda *a: gated_group_rms_norm(*a, 2, 1e-5), y, z, scale) \
+        == sorted([y.shape, z.shape, scale.shape])
+
+
+def test_gated_norm_takes_whole_groups_only():
+    y = jnp.ones((1, 4, 10))
+    with pytest.raises(ValueError, match="do not divide into 4 groups"):
+        gated_group_rms_norm(y, y, jnp.ones((10,)), 4, 1e-5)
+    with pytest.raises(ValueError, match="do not divide into 4 groups"):
+        jax.grad(lambda y: jnp.sum(gated_group_rms_norm(
+            y, y, jnp.ones((10,)), 4, 1e-5)))(y)
 
 
 MIXER = dict(type="mamba2", n_heads=4, head_dim=8, n_groups=2, state_size=8,
@@ -214,20 +316,33 @@ def test_mixer_is_the_reference_layer_and_counts_its_chunks():
             if "mix" in key] == [T // MIXER["chunk"]]
 
 
+def test_the_written_backwards_scopes_are_in_the_compiled_program():
+    from veles_tpu.runtime.metrics import registry
+    unit = Mamba2Mixer(name="mix9", **{k: v for k, v in MIXER.items()
+                                       if k != "type"})
+    params, state = unit.init(jax.random.key(1),
+                              [Spec((2, T, E), jnp.float32)])
+    text = jax.jit(jax.grad(lambda p, x: jnp.sum(unit.apply(
+        p, state, [x], Context(train=True))[0]))).lower(
+            params, jnp.ones((2, T, E))).as_text(debug_info=True)
+    # inside the stage's own scope, on the backward side
+    for scope in ("transpose(jvp(ssm_conv))/ssm_conv_bwd/",
+                  "transpose(jvp(ssm_gate_norm))/ssm_gate_norm_bwd/"):
+        assert scope in text, scope
+    gauge = registry().get("vt_ssm_backward_path")
+    assert {key: child.value for key, child in gauge._snapshot()
+            if "mix9" in key} == {("mix9", "conv", "written"): 1.0,
+                                  ("mix9", "gate_norm", "written"): 1.0}
+
+
 def test_scan_keeps_its_inputs_alone_for_the_backward():
     """Differentiated as written the expression keeps the (chunks, heads,
     Q, Q) decay mask and the masked scores; ``ssd`` keeps neither."""
     args = scan_inputs(b=1, t=32, h=4, p=8, g=2, n=8)
-
-    def residual_shapes(f):
-        _, vjp = jax.vjp(f, *args)
-        return {tuple(x.shape) for x in jax.tree_util.tree_leaves(vjp)
-                if hasattr(x, "shape")}
-
     square = (1, 4, 2, 2, 8, 8)               # (b, chunks, g, r, Q, Q)
     assert square in residual_shapes(
-        lambda *a: ssd_ops.ssd_chunked(*a, 8))
-    kept = residual_shapes(lambda *a: ssd_ops.ssd(*a, 8))
+        lambda *a: ssd_ops.ssd_chunked(*a, 8), *args)
+    kept = set(residual_shapes(lambda *a: ssd_ops.ssd(*a, 8), *args))
     assert square not in kept
     assert kept <= {tuple(a.shape) for a in args}
 

@@ -22,6 +22,9 @@ it.  Three rules:
   gradient and a bfloat16 cast, and all of it is charged to the one
   unit in the fusion's ``op_name``.  A unit's seconds here are the
   seconds of the instructions named after it, not of its arithmetic.
+  A fusion XLA gave no ``op_name`` (a piece of a ``concatenate`` it
+  rewrote to updates in place, a root it made itself) takes the scope
+  that the instructions fused into it agree on (:func:`_agreed`).
 * **Self time.**  A ``conditional``, ``while`` or ``call`` event lasts
   as long as the instructions of the computations it calls, which are
   events of their own inside it.  It is charged its seconds less
@@ -83,6 +86,7 @@ _OPERAND = re.compile(r"%[\w.\-]+")
 _INDEX = re.compile(r"/\*index=\d+\*/")
 _COMPUTATION = re.compile(r"^(ENTRY )?(%?[\w.\-]+) .*\{$")
 _MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_NO_VOTE = ("constant", "broadcast")    # see _agreed
 
 
 class Instruction(NamedTuple):
@@ -246,15 +250,32 @@ def _called(attributes: str, opcode: str) -> Tuple[str, ...]:
     return tuple(c.lstrip("%") for c in out if c)
 
 
-def _agreed(instructions: Iterable[Instruction]
+def _agreed(votes: Iterable[Tuple[str, Tuple]]
             ) -> Tuple[Optional[str], str, str, bool]:
-    """The scope of a caller XLA left without an ``op_name``: the unit its
-    callees' scoped instructions agree on, if they do."""
-    scoped = [i for i in instructions if i.unit is not None]
-    if not scoped or len({i.unit for i in scoped}) > 1:
+    """The scope of an instruction XLA left without an ``op_name``, from
+    the ``(opcode, (unit, path, direction, recomputed))`` of what runs
+    inside it: the unit that all of them name that do work, if they name
+    one, and the path that those of them with a path agree on.  A
+    ``constant`` and a ``broadcast`` have no vote: XLA shares a constant
+    between the fusions of several units and leaves the first unit's
+    name on it and on what spreads it."""
+    scoped = [scope for opcode, scope in votes
+              if scope[0] is not None and opcode not in _NO_VOTE]
+    if not scoped or len({s[0] for s in scoped}) > 1:
         return None, "", "", False
-    backward = any(i.direction == BACKWARD for i in scoped)
-    return scoped[0].unit, "", BACKWARD if backward else FORWARD, False
+    paths = {s[1] for s in scoped if s[1]}
+    backward = any(s[2] == BACKWARD for s in scoped)
+    return (scoped[0][0], paths.pop() if len(paths) == 1 else "",
+            BACKWARD if backward else FORWARD, False)
+
+
+def _fused(lines: Iterable[str]) -> Iterable[Tuple[str, str]]:
+    """``(opcode, op_name)`` of the named instructions of a fusion's
+    computation."""
+    for line in lines:
+        m, named = _INSTRUCTION.match(line), _OP_NAME.search(line)
+        if m is not None and named is not None:
+            yield _split(line[m.end():])[1], named.group(1)
 
 
 def parse(program: str, text: str, units: Optional[Mapping[str, str]] = None,
@@ -272,9 +293,10 @@ def parse(program: str, text: str, units: Optional[Mapping[str, str]] = None,
     the operands' own result types, in the same computation, so the
     table's text puts them in.  A ``conditional`` or ``while`` that XLA
     left without an ``op_name`` takes the unit that the instructions it
-    calls agree on, and an instruction without one inside a called
-    computation takes its caller's scope: it runs inside the caller's
-    event."""
+    calls agree on, a fusion without one the unit that the instructions
+    fused into it agree on (:func:`_agreed`), and an instruction without
+    one inside a called computation takes its caller's scope: it runs
+    inside the caller's event."""
     module, entry, computation = "", None, None
     lines: Dict[str, List[str]] = {}
     body: List[str] = []
@@ -293,6 +315,13 @@ def parse(program: str, text: str, units: Optional[Mapping[str, str]] = None,
             module = m.group(1) if m else ""
     names = tuple(units or ())
     scopes: Dict[str, Tuple] = {}       # many instructions share a name
+
+    def scoped(op_name):
+        scope = scopes.get(op_name)
+        if scope is None:
+            scope = scopes[op_name] = scope_of(op_name, names)
+        return scope
+
     kept: Dict[str, List[Instruction]] = {}
     queue = [entry] if entry is not None else []
     while queue:
@@ -324,10 +353,16 @@ def parse(program: str, text: str, units: Optional[Mapping[str, str]] = None,
             calls = _called(attributes, opcode)
             queue.extend(calls)
             m = _OP_NAME.search(line)
-            op_name = m.group(1) if m else ""
-            scope = scopes.get(op_name)
-            if scope is None:
-                scope = scopes[op_name] = scope_of(op_name, names)
+            scope = scoped(m.group(1) if m else "")
+            if scope[0] is None and opcode == "fusion":
+                # the pieces of a concatenate that XLA rewrites to updates
+                # in place carry no metadata but the last: such a fusion
+                # takes the scope the instructions fused into it agree on
+                fused = _CALLERS.search(attributes)     # its one calls=
+                if fused is not None and fused.group(2):
+                    scope = _agreed(
+                        (opcode, scoped(n)) for opcode, n in _fused(
+                            lines.get(fused.group(2).lstrip("%"), ())))
             kept[computation].append(Instruction(
                 name, f"{name} = {rest}", computation, opcode, calls,
                 *scope))
@@ -335,8 +370,8 @@ def parse(program: str, text: str, units: Optional[Mapping[str, str]] = None,
     # (callees were discovered after their callers: deepest first) ...
     for computation in reversed(list(kept)):
         kept[computation] = [
-            _rescoped(ins, _agreed(
-                i for c in ins.calls for i in kept.get(c, ())))
+            _rescoped(ins, _agreed((i.opcode, i[5:]) for c in ins.calls
+                                   for i in kept.get(c, ())))
             if ins.unit is None and ins.calls else ins
             for ins in kept[computation]]
     # ... and what XLA left unnamed inside a called computation (the

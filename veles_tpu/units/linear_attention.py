@@ -19,7 +19,7 @@ from .. import ops
 from ..ops import gated_delta as gated_delta_ops
 from .base import Context, Forward
 from .nn import _cast_policy, rms_normalize
-from .ssm import causal_depthwise_conv
+from .ssm import causal_conv_silu
 
 
 def l2_normalize(x, eps: float = 1e-6):
@@ -112,7 +112,7 @@ class GatedDeltaNet(Forward):
                 ops.dense(x, params[w], compute_dtype=dtype)
                 for w in ("wq", "wk", "wv", "wz", "wb", "wa"))
         with jax.named_scope("gdn_conv"):
-            q, k, v = (jax.nn.silu(causal_depthwise_conv(a, params[w]))
+            q, k, v = (causal_conv_silu(a, params[w])
                        for a, w in ((q, "conv_q"), (k, "conv_k"),
                                     (v, "conv_v")))
         beta = jax.nn.sigmoid(wb.astype(jnp.float32))

@@ -8,10 +8,23 @@ and the steps; a depthwise causal convolution; the recurrence as a
 chunked scan (``ops/ssd.py``); a gated RMS norm by groups; the output
 projection.  No reference counterpart (SURVEY.md section 5.7: the
 reference has no sequence models in core).
+
+The two elementwise stages, ``causal_conv_silu`` and
+``gated_group_rms_norm``, are differentiated by hand
+(``jax.custom_vjp``, in ``jax.numpy``; PERF.md section 6, PR 39).
+Autodiff's transpose of the convolution's tap sum writes K padded
+arrays of the activation's size and reads them back to add them; the
+written one is the same tap sum mirrored in time.  The norm works a
+group at a time on slices of the channel axis, forward and backward:
+XLA lays a B = 1 stream out with T in the lanes, where a (T, G)
+statistic broadcast to (T, G, C / G) is an array it writes out, and a
+slice's (T, 1) statistic is not.  Both keep their inputs alone for the
+backward.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -23,24 +36,133 @@ from .base import Context, Forward
 from .nn import _cast_policy
 
 
-def causal_depthwise_conv(x, w, b=None):
-    """``y[t] = sum_k w[k] * x[t - (K - 1) + k] + b`` a channel, over (B,
-    T, C) with ``w`` (K, C): token t sees itself and the K - 1 before it,
-    zeros before the first."""
-    k, t = w.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    y = sum(padded[:, i:i + t] * w[i] for i in range(k))
-    return y if b is None else y + b
+def _taps(x, k, ahead=False):
+    """The K shifted reads of (B, T, C) that tap ``i`` of a causal
+    convolution multiplies: ``x[t - (K - 1) + i]``, zeros before the first
+    token; ``ahead`` mirrors them in time, ``x[t + (K - 1) - i]`` with
+    zeros past the last.  Slices of one padded array, which XLA fuses
+    into the pass that reads them."""
+    t = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (0, k - 1) if ahead else (k - 1, 0), (0, 0)))
+    return [padded[:, j:j + t]
+            for j in (range(k - 1, -1, -1) if ahead else range(k))]
 
 
+def _tap_sum(x, w, ahead=False):
+    """``sum_k w[k] * x[t - (K - 1) + k]`` over (B, T, C) with ``w`` (K,
+    C); ``ahead``: ``sum_k w[k] * x[t + (K - 1) - k]``."""
+    return sum(a * w[i] for i, a in enumerate(_taps(x, w.shape[0], ahead)))
+
+
+def _silu_slope(x):
+    """``d silu(x) / dx``."""
+    s = jax.nn.sigmoid(x)
+    return s * (1.0 + x * (1.0 - s))
+
+
+@jax.custom_vjp
+def causal_conv_silu(x, w, b=None):
+    """``silu(sum_k w[k] * x[t - (K - 1) + k] + b)`` a channel, over (B,
+    T, C) with ``w`` (K, C): a depthwise convolution in which token t sees
+    itself and the K - 1 before it, zeros before the first, and the
+    ``silu`` both mixers put behind it.
+
+    The backward is written, not derived: autodiff transposes each tap's
+    slice to a pad and writes K arrays of the activation's size before it
+    adds them.  Kept are ``x``, ``w`` and ``b``; with ``d = dy *
+    silu'(pre)`` (``pre``, the sum, computed again), ``dx[t] = sum_k w[k]
+    * d[t + (K - 1) - k]`` is the forward's own sum mirrored in time, one
+    pass over ``d``, and ``dw``, ``db`` are reductions in the pass that
+    makes ``d``."""
+    pre = _tap_sum(x, w)
+    return jax.nn.silu(pre if b is None else pre + b)
+
+
+def _causal_conv_silu_fwd(x, w, b):
+    return causal_conv_silu(x, w, b), (x, w, b)
+
+
+def _causal_conv_silu_bwd(kept, dy):
+    x, w, b = kept
+    with jax.named_scope("ssm_conv_bwd"):
+        pre = _tap_sum(x, w)
+        d = dy.astype(jnp.float32) * _silu_slope(
+            pre if b is None else pre + b)
+        dw = jnp.stack([jnp.sum(d * a, axis=(0, 1))
+                        for a in _taps(x, w.shape[0])])
+        db = None if b is None else jnp.sum(d, axis=(0, 1)).astype(b.dtype)
+        dx = _tap_sum(d, w, ahead=True)
+    return dx.astype(x.dtype), dw.astype(w.dtype), db
+
+
+causal_conv_silu.defvjp(_causal_conv_silu_fwd, _causal_conv_silu_bwd)
+
+
+def _gated_groups(y, z, n_groups):
+    """``y`` and ``z`` in float32, a group of the trailing axis at a time,
+    behind the group's slice.  By slices and not by a reshape to (..., G,
+    C / G): with T in the lanes (XLA's layout where B = 1) a (T, G)
+    statistic broadcast to (T, G, C / G) and reshaped back is an array
+    XLA writes out; a slice's (T, 1) statistic stays inside the fusion
+    that reads it."""
+    size, rest = divmod(y.shape[-1], n_groups)
+    if rest:
+        raise ValueError(f"{y.shape[-1]} channels do not divide into "
+                         f"{n_groups} groups")
+    for i in range(n_groups):
+        at = slice(i * size, (i + 1) * size)
+        yield at, y[..., at].astype(jnp.float32), \
+            z[..., at].astype(jnp.float32)
+
+
+def _rms_scale(h, eps):
+    return jax.lax.rsqrt(jnp.mean(h * h, axis=-1, keepdims=True) + eps)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def gated_group_rms_norm(y, z, scale, n_groups: int, eps: float):
     """``RMS(y * silu(z))`` over each of ``n_groups`` groups of the
-    trailing axis, times ``scale``; float32."""
-    h = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    grouped = h.reshape(h.shape[:-1] + (n_groups, -1))
-    grouped = grouped * jax.lax.rsqrt(
-        jnp.mean(grouped * grouped, axis=-1, keepdims=True) + eps)
-    return grouped.reshape(h.shape) * scale
+    trailing axis, times ``scale``; float32.
+
+    The backward is written, not derived.  Kept are ``y``, ``z`` and
+    ``scale``; with ``h = y silu(z)`` and ``r = rsqrt(mean(h^2) + eps)``
+    computed again and ``g = dout * scale``: ``dh = r (g - h r^2 mean(g
+    h))`` a group, ``dy = dh silu(z)``, ``dz = dh y silu'(z)``,
+    ``dscale = sum_t dout h r``: a pass for the group's two means and
+    one for the three gradients."""
+    out = []
+    for at, yg, zg in _gated_groups(y, z, n_groups):
+        h = yg * jax.nn.silu(zg)
+        out.append(h * _rms_scale(h, eps) * scale[at])
+    return jnp.concatenate(out, axis=-1)
+
+
+def _gated_group_rms_norm_fwd(y, z, scale, n_groups, eps):
+    return gated_group_rms_norm(y, z, scale, n_groups, eps), (y, z, scale)
+
+
+def _gated_group_rms_norm_bwd(n_groups, eps, kept, dout):
+    y, z, scale = kept
+    dy, dz, dscale = [], [], []
+    with jax.named_scope("ssm_gate_norm_bwd"):
+        for at, yg, zg in _gated_groups(y, z, n_groups):
+            gate = jax.nn.silu(zg)
+            h = yg * gate
+            r = _rms_scale(h, eps)
+            do = dout[..., at].astype(jnp.float32)
+            g = do * scale[at]
+            dh = r * (g - h * (r * r * jnp.mean(g * h, axis=-1,
+                                                keepdims=True)))
+            dy.append(dh * gate)
+            dz.append(dh * yg * _silu_slope(zg))
+            dscale.append(jnp.sum(do * h * r, axis=tuple(range(h.ndim - 1))))
+        dy, dz, dscale = (jnp.concatenate(a, axis=-1)
+                          for a in (dy, dz, dscale))
+    return dy.astype(y.dtype), dz.astype(z.dtype), dscale.astype(scale.dtype)
+
+
+gated_group_rms_norm.defvjp(_gated_group_rms_norm_fwd,
+                            _gated_group_rms_norm_bwd)
 
 
 class Mamba2Mixer(Forward):
@@ -124,14 +246,20 @@ class Mamba2Mixer(Forward):
             "vt_ssd_chunks",
             "chunks a sequence of the scan's last traced call",
             labels=("unit",)).labels(unit=self.name).set(t // self.chunk)
+        written = registry().gauge(
+            "vt_ssm_backward_path",
+            "1 on the way a stage of the mixer's last traced call is "
+            "differentiated: written = its backward pass is written by hand",
+            labels=("unit", "stage", "path"))
+        for stage in ("conv", "gate_norm"):
+            written.labels(unit=self.name, stage=stage, path="written").set(1)
         with jax.named_scope("ssm_in_proj"):
             zxbcdt = ops.dense(x, params["w_in"],
                                compute_dtype=self.compute_dtype)
         z, xbc, dt = jnp.split(
             zxbcdt, [self.inner, self.inner + self.conv_dim], axis=-1)
         with jax.named_scope("ssm_conv"):
-            xbc = jax.nn.silu(causal_depthwise_conv(
-                xbc, params["conv_w"], params["conv_b"]))
+            xbc = causal_conv_silu(xbc, params["conv_w"], params["conv_b"])
         # in the products' dtype already: the scan keeps its inputs for
         # its backward, and in float32 they are twice the bytes
         xs_, bs, cs = jnp.split(
