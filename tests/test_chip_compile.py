@@ -190,6 +190,11 @@ ONE_CHIP = {
     "flash_fwd_bwd_t4096_h32_kv2_d128_full": _flash(1, 4096, 32, 2, 128),
     # the delta-rule hybrid's full layer: a share of 15 heads, no groups
     "flash_fwd_bwd_t4096_h15_d128_full": _flash(1, 4096, 15, 15, 128),
+    # the latent attention of the Kimi share: keys 192 wide, values padded
+    # to them
+    "flash_fwd_bwd_t4096_h32_d192_full": _flash(1, 4096, 32, 32, 192),
+    "sum_rows_by_token_kimi_small_4096x2304": _row_sum(4096, 2304),
+    "sum_rows_by_token_kimi_large_33792x2304": _row_sum(33792, 2304),
     "flash_fwd_bwd_t4096_h32_kv4_d128_window2048": _flash_gqa_d128(2048),
     "flash_fwd_bwd_t4096_h32_kv4_d128_full": _flash_gqa_d128(None),
     "flash_fwd_bwd_trinity_window_512x512": _flash_gqa_d128(

@@ -59,6 +59,7 @@ LAYER_TYPES = {
     "add": nn.Add,
     "mamba2": ssm.Mamba2Mixer,
     "gated_delta_net": linear_attention.GatedDeltaNet,
+    "kimi_delta_attention": linear_attention.KimiDeltaAttention,
     "seq_last": nn.SeqLast,
 }
 
@@ -67,7 +68,8 @@ LAYER_TYPES = {
 # switch); shared with PipelineStack's stage-config builder
 COMPUTE_DTYPE_TYPES = ("all2all", "softmax", "conv", "deconv", "rnn",
                        "gru", "lstm", "attention", "ffn", "gated_mlp",
-                       "routed_experts", "mamba2", "gated_delta_net")
+                       "routed_experts", "mamba2", "gated_delta_net",
+                       "kimi_delta_attention")
 
 
 def build_workflow(name: str, layers: Sequence[dict], *,

@@ -10,6 +10,11 @@ value ``v_t`` of ``dv``, a log-decay ``g_t <= 0`` and a step ``beta_t``::
     S_t = S_t + k_t (beta_t (v_t - S_t^T k_t))^T         (dk x dv), S_0 = 0
     o_t = S_t^T q_t
 
+The decay is one number a head and token (Gated DeltaNet) or a vector of
+``dk`` a head and token, ``exp(g_t) S`` then ``Diag(exp(g_t)) S``: the
+delta attention of Kimi Linear (Moonshot AI 2025, arXiv 2510.26692,
+"KDA").
+
 The transition ``exp(g_t) (I - beta_t k_t k_t^T)`` is a full matrix a
 token, so no diagonal scan (``ops/ssd.py``) computes it.  In chunks of Q
 tokens, with ``G`` the running sum of ``g`` inside a chunk, ``K_beta =
@@ -24,6 +29,16 @@ and from the state ``S`` a chunk starts with::
     v_new = U - W S
     o     = (Q o exp(G)) S + tril((Q K^T) o exp(G_i - G_j)) v_new
     S'    = exp(G_last) S + (K o exp(G_last - G))^T v_new
+
+With a decay by channel every ``exp(G_i - G_j)`` above is a vector over
+the ``dk`` channels that the two (Q, Q) products sum over, so it goes
+inside them, and ``exp(G_last) S`` is ``Diag(exp(G_last)) S``.  Written
+``(q_i exp(G_i)) . (k_j exp(-G_j))`` the second factor overflows where
+the gates are steep, so the chunk's rows go in blocks of ``SUB``: block
+p's rows against the columns before it are one product,
+``(q_i exp(G_i - G_r)) . (k_j exp(G_r - G_j))`` with ``r`` the block's
+first row, both factors at most one; the ``SUB`` x ``SUB`` blocks on the
+diagonal sum ``q_i k_j exp(G_i - G_j)`` over the channels as they stand.
 
 ``S'`` is linear in ``S``: ``S' = exp(G_last) S - P S + N`` with ``P =
 Kd^T W`` (dk x dk) and ``N = Kd^T U`` (dk x dv), ``Kd = K o exp(G_last -
@@ -53,6 +68,8 @@ import jax
 import jax.numpy as jnp
 
 _HIGHEST = jax.lax.Precision.HIGHEST
+#: rows of a block of a chunk in the products of a decay by channel
+SUB = 16
 
 
 def _inverse_rows(a):
@@ -98,35 +115,77 @@ def _product(spec, a, b, dtype):
                       preferred_element_type=jnp.float32)
 
 
+def _decayed_products(a, b, cum, dtype):
+    """``sum_d a_i[d] b_j[d] exp(G_i[d] - G_j[d])`` for ``j <= i``, zero
+    above the diagonal: a and b (b, c, Q, h, dk), ``cum`` their running
+    sums of the decay by channel, float32 -> (b, c, h, Q, Q) float32.  No
+    factor above one is formed (the module's docstring)."""
+    nb, c, q, h, dk = a.shape
+    sub = min(SUB, q)
+    n = q // sub
+    blocks = lambda x: x.reshape(nb, c, n, sub, h, dk)
+    a_blk, b_blk, cum_blk = blocks(a), blocks(b), blocks(cum)
+    # the rows of block p against the columns before it, through row r
+    ref = cum_blk[:, :, :, :1]                           # (b, c, n, 1, h, dk)
+    a_ref = a_blk.astype(jnp.float32) * jnp.exp(cum_blk - ref)
+    before = (jnp.arange(q)[None, :] < sub * jnp.arange(n)[:, None])
+    b_ref = b.astype(jnp.float32)[:, :, None] * jnp.exp(jnp.where(
+        before[None, None, :, :, None, None],
+        ref - cum[:, :, None], -jnp.inf))               # (b, c, n, Q, h, dk)
+    off = _product("bcpihd,bcpjhd->bchpij", a_ref, b_ref, dtype)
+    # the blocks on the diagonal, channel by channel
+    causal = jnp.tril(jnp.ones((sub, sub), bool))[:, :, None, None]
+    diag = jnp.sum(
+        a_blk.astype(jnp.float32)[:, :, :, :, None]
+        * b_blk.astype(jnp.float32)[:, :, :, None]
+        * jnp.exp(jnp.where(causal, cum_blk[:, :, :, :, None]
+                            - cum_blk[:, :, :, None], -jnp.inf)),
+        axis=-1)                                         # (b, c, n, i, j, h)
+    diag = diag.transpose(0, 1, 5, 2, 3, 4)              # (b, c, h, n, i, j)
+    eye = jnp.eye(n, dtype=jnp.float32)[:, None, :, None]
+    out = off.reshape(nb, c, h, n, sub, n, sub) \
+        + diag[:, :, :, :, :, None, :] * eye
+    return out.reshape(nb, c, h, q, q)
+
+
 def gated_delta_chunked(q, k, v, g, beta, chunk=64, compute_dtype=None):
     """The recurrence as the chunked expression in ``jax.numpy``: q and k
-    (b, t, h, dk), v (b, t, h, dv), g and beta (b, t, h) -> o (b, t, h,
-    dv) float32.  ``q`` comes scaled; nothing here normalises."""
+    (b, t, h, dk), v (b, t, h, dv), beta (b, t, h), g (b, t, h) or by
+    channel (b, t, h, dk) -> o (b, t, h, dv) float32.  ``q`` comes
+    scaled; nothing here normalises."""
     b, t, h, dk = q.shape
     dv = v.shape[-1]
     if t % chunk:
         raise ValueError(f"the chunked delta rule takes whole chunks: T = "
                          f"{t} is no multiple of {chunk}")
+    by_channel = g.ndim == 4
     c = t // chunk
     dtype = compute_dtype or q.dtype
     f32 = jnp.float32
     by_chunk = lambda a: a.reshape((b, c, chunk) + a.shape[2:])
-    # a token's scalars beside its rows: (b, c, Q, h, 1)
-    g, beta = (by_chunk(a.astype(f32))[..., None] for a in (g, beta))
+    # a token's scalars beside its rows: (b, c, Q, h, 1); a decay by
+    # channel is (b, c, Q, h, dk) as it stands
+    beta = by_chunk(beta.astype(f32))[..., None]
+    g = by_chunk(g.astype(f32))
+    g = g if by_channel else g[..., None]
     qc, kc, vc = (by_chunk(a) for a in (q, k, v))
     cum = jnp.cumsum(g, axis=2)
     last = cum[:, :, -1:]
     with jax.named_scope("gdn_chunk"):
-        # (b, c, h, Q, Q): token i's running sum less token j's
-        rows = cum[..., 0].transpose(0, 1, 3, 2)
-        seg = rows[..., :, None] - rows[..., None, :]
         causal = jnp.tril(jnp.ones((chunk, chunk), bool))
-        # masked before the exponential: above the diagonal the sum is
-        # positive and may overflow, and 0 x inf is what a gradient gets
-        decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
         k_beta = kc.astype(f32) * beta
-        a = -jnp.tril(_product("bcihd,bcjhd->bchij", k_beta, kc, dtype)
-                      * decay, -1)
+        if by_channel:
+            a = -jnp.tril(_decayed_products(k_beta, kc, cum, dtype), -1)
+        else:
+            # (b, c, h, Q, Q): token i's running sum less token j's
+            rows = cum[..., 0].transpose(0, 1, 3, 2)
+            seg = rows[..., :, None] - rows[..., None, :]
+            # masked before the exponential: above the diagonal the sum
+            # is positive and may overflow, and 0 x inf is what a
+            # gradient gets
+            decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))
+            a = -jnp.tril(_product("bcihd,bcjhd->bchij", k_beta, kc, dtype)
+                          * decay, -1)
         inv = unit_lower_inverse(a)
         u = _product("bchij,bcjhe->bcihe", inv, vc.astype(f32) * beta, dtype)
         w = _product("bchij,bcjhd->bcihd", inv, k_beta * jnp.exp(cum), dtype)
@@ -134,11 +193,13 @@ def gated_delta_chunked(q, k, v, g, beta, chunk=64, compute_dtype=None):
         kd = kc.astype(f32) * jnp.exp(last - cum)
         p = _product("bcihd,bcihf->bchdf", kd, w, dtype)       # Kd^T W
         n = _product("bcihd,bcihe->bchde", kd, u, dtype)       # Kd^T U
-        over_chunk = jnp.exp(last[:, :, 0, :, 0])              # (b, c, h)
+        # (b, c, h, 1): one number a head, or (b, c, h, dk): a row of
+        # the state each
+        over_chunk = jnp.exp(last[:, :, 0])
 
         def step(s, chunk_):
             p_c, n_c, d_c = chunk_
-            out = d_c[..., None, None] * s + n_c \
+            out = d_c[..., None] * s + n_c \
                 - _product("bhdf,bhfe->bhde", p_c, s, dtype)
             return out, s
 
@@ -148,9 +209,12 @@ def gated_delta_chunked(q, k, v, g, beta, chunk=64, compute_dtype=None):
         starts = jnp.moveaxis(starts, 0, 1)                    # (b, c, h, dk, dv)
     with jax.named_scope("gdn_chunk"):
         v_new = u - _product("bcihd,bchde->bcihe", w, starts, dtype)
-        scores = jnp.where(
-            causal, _product("bcihd,bcjhd->bchij", qc, kc, dtype) * decay,
-            0.0)
+        if by_channel:
+            scores = _decayed_products(qc, kc, cum, dtype)
+        else:
+            scores = jnp.where(
+                causal, _product("bcihd,bcjhd->bchij", qc, kc, dtype)
+                * decay, 0.0)
         o = _product("bcihd,bchde->bcihe", qc.astype(f32) * jnp.exp(cum),
                      starts, dtype) \
             + _product("bchij,bcjhe->bcihe", scores, v_new, dtype)

@@ -10,7 +10,7 @@ from .nn import (Add, All2All, All2AllRELU, All2AllSincos, All2AllSoftmax,
 from .parallel_nn import (MoEFFN, MultiHeadAttention, PipelineStack,
                           expert_rules, pipeline_rules)
 from .ssm import Mamba2Mixer
-from .linear_attention import GatedDeltaNet
+from .linear_attention import GatedDeltaNet, KimiDeltaAttention
 from .kohonen import KohonenForward
 from .recurrent import GRU, LSTM, RNN
 from .rbm import RBM

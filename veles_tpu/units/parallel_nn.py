@@ -34,6 +34,21 @@ class MultiHeadAttention(Forward):
     K/V blocks rotate over ICI while each device holds one sequence shard.
     Otherwise the blockwise/flash local kernel handles arbitrary T on one
     device.  Projections are plain gemms GSPMD shards by rule.
+
+    Latent K and V (``kv_latent``; DeepSeek-V2's multi-head latent
+    attention in the form of Hugging Face ``transformers``
+    ``models/deepseek_v3/modeling_deepseek_v3.py``, without the rotary
+    embedding, as Kimi Linear's ``mla_use_nope`` has it)::
+
+        [c, k_shared] = x W_kv_down          kv_latent | k_shared a token
+        k_h = [RMS(c; kv_norm) Wk_up | k_shared],  v_h = RMS(c; kv_norm) Wv_up
+        q_h = (x Wq)_h                       head_dim = its k_h's width
+
+    ``k_shared`` channels of every head's key are one row a token for
+    all heads; values are ``v_head_dim`` wide.  The core is the one of
+    every call, values padded with zeros to ``head_dim`` and the output
+    cut back to ``v_head_dim`` (exact: a zero column of V gives a zero
+    column of O).  No GQA, QK norm, gate or rotary embedding with it.
     """
 
     stochastic = False
@@ -46,7 +61,8 @@ class MultiHeadAttention(Forward):
                  residual: bool = False,
                  use_flash: Optional[bool] = None,
                  qk_norm=False, gate: bool = False,
-                 norm_eps: float = 1e-5):
+                 norm_eps: float = 1e-5, kv_latent: Optional[int] = None,
+                 k_shared: int = 0, v_head_dim: Optional[int] = None):
         super().__init__(name, inputs)
         self.n_heads = int(n_heads)
         self.head_dim = head_dim
@@ -77,6 +93,23 @@ class MultiHeadAttention(Forward):
         self.n_kv_heads = (self.n_heads if n_kv_heads is None
                            else int(n_kv_heads))
         check_gqa_heads(self.n_heads, self.n_kv_heads)
+        self.kv_latent = None if kv_latent is None else int(kv_latent)
+        self.k_shared = int(k_shared)
+        self.v_head_dim = None if v_head_dim is None else int(v_head_dim)
+        if self.kv_latent is not None:
+            if head_dim is None or self.n_kv_heads != self.n_heads \
+                    or self.qk_norm or self.gate or self.rope:
+                raise ValueError(
+                    "latent K/V take a head_dim and no kv heads, QK norm, "
+                    "gate or rotary embedding")
+            if not 0 <= self.k_shared < int(head_dim) \
+                    or not 0 < (self.v_head_dim or int(head_dim)) \
+                    <= int(head_dim):
+                raise ValueError(
+                    f"k_shared {k_shared} and v_head_dim {v_head_dim} do "
+                    f"not fit head_dim {head_dim}")
+        elif self.k_shared or self.v_head_dim is not None:
+            raise ValueError("k_shared and v_head_dim are latent K/V's")
         # None = measured Pallas-vs-XLA pick at build shape (prepare);
         # True/False forces; falls back to the platform default
         self.use_flash = use_flash
@@ -200,6 +233,8 @@ class MultiHeadAttention(Forward):
         D = self.head_dim or E // H
         if self.head_dim is None and E % H:
             raise ValueError(f"model dim {E} not divisible by {H} heads")
+        if self.kv_latent is not None:
+            return self._latent_params(key, E, H, D), {}
         kq, kk, kv, ko = jax.random.split(key, 4)
         params = {
             "wq": _uniform_init(kq, (E, H * D), E),
@@ -218,6 +253,43 @@ class MultiHeadAttention(Forward):
             params["wg"] = _uniform_init(jax.random.fold_in(key, 4),
                                          (E, H * D), E)
         return params, {}
+
+    def _latent_params(self, key, E, H, D):
+        c, dv = self.kv_latent, self.v_head_dim or D
+        kq, kd, kk, kv, ko = jax.random.split(key, 5)
+        return {
+            "wq": _uniform_init(kq, (E, H * D), E),
+            "w_kv_down": _uniform_init(kd, (E, c + self.k_shared), E),
+            "kv_norm": jnp.ones((c,)),
+            "wk_up": _uniform_init(kk, (c, H * (D - self.k_shared)), c),
+            "wv_up": _uniform_init(kv, (c, H * dv), c),
+            "wo": _uniform_init(ko, (H * dv, E), H * dv),
+        }
+
+    def _latent_kv(self, params, xq, dt):
+        """(k, v) (B, T, H, head_dim) from the latent row, v padded with
+        zeros to the key's width."""
+        from ..runtime.metrics import registry
+        from .nn import rms_normalize
+        registry().gauge(
+            "vt_attn_latent", "width of the latent row K and V come from, "
+            "in the attention unit's last traced call",
+            labels=("unit",)).labels(unit=self.name).set(self.kv_latent)
+        B, T, _ = xq.shape
+        H, c = self.n_heads, self.kv_latent
+        with jax.named_scope("attn_kv_down"):
+            down = xq @ params["w_kv_down"].astype(dt)
+            latent = rms_normalize(down[..., :c], params["kv_norm"],
+                                   self.norm_eps)
+            shared = down[..., c:]
+        with jax.named_scope("attn_kv_up"):
+            k = (latent @ params["wk_up"].astype(dt)).reshape(B, T, H, -1)
+            k = jnp.concatenate(
+                [k, jnp.broadcast_to(shared[:, :, None],
+                                     (B, T, H, self.k_shared))], axis=-1)
+            v = (latent @ params["wv_up"].astype(dt)).reshape(B, T, H, -1)
+            v = jnp.pad(v, ((0, 0),) * 3 + ((0, k.shape[-1] - v.shape[-1]),))
+        return k, v
 
     def apply(self, params, state, xs, ctx: Context):
         from ..parallel.ring_attention import (_ring_attention_local,
@@ -247,8 +319,12 @@ class MultiHeadAttention(Forward):
             return y.reshape(B, T, nh, -1)
 
         q = proj(params["wq"], H, "q_norm" if whole else None)
-        k = proj(params["wk"], self.n_kv_heads, "k_norm" if whole else None)
-        v = proj(params["wv"], self.n_kv_heads)
+        if self.kv_latent is None:
+            k = proj(params["wk"], self.n_kv_heads,
+                     "k_norm" if whole else None)
+            v = proj(params["wv"], self.n_kv_heads)
+        else:
+            k, v = self._latent_kv(params, xq, dt)
         if self.qk_norm == "head":
             q = rms_normalize(q, params["q_norm"], self.norm_eps)
             k = rms_normalize(k, params["k_norm"], self.norm_eps)
@@ -293,6 +369,8 @@ class MultiHeadAttention(Forward):
                 spec = P(batch_axes(ctx.mesh, B), None, heads, None)
                 attend = shard_batch(attend, ctx.mesh, (spec,) * 3, spec)
             o = attend(q, k, v)
+        if self.v_head_dim is not None:
+            o = o[..., :self.v_head_dim]
         o = o.reshape(B, T, -1)
         if self.gate:
             g = jax.nn.sigmoid((xq @ params["wg"].astype(dt))
