@@ -161,21 +161,69 @@ def test_whole_chunks_only():
             [Spec((2, 12, E), jnp.float32)])
 
 
-def test_inverse_and_its_gradient():
-    """``(I - a)^-1`` by rows against numpy's inverse in float64, and its
-    hand-written gradient against the one autodiff takes through the
-    rows' loop."""
-    a = jnp.tril(0.4 * jax.random.normal(jax.random.key(0), (3, 2, 16, 16)),
-                 -1)
-    want = np.linalg.inv(np.eye(16) - np.asarray(a, np.float64))
-    np.testing.assert_allclose(gd.unit_lower_inverse(a), want, atol=1e-5,
+def rule_system(q, h=2, dk=8, seed=3):
+    """``A`` (1, 1, h, Q, Q) as the rule builds it: unit keys that share
+    most of one direction, steps in (0, 2), a decay by channel."""
+    k = jax.random.split(jax.random.key(seed), 4)
+    keys = olmo_hybrid.l2norm(
+        jax.random.normal(k[0], (1, 1, 1, h, dk))
+        + 0.3 * jax.random.normal(k[1], (1, 1, q, h, dk)))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(k[2], (1, 1, q, h, 1)))
+    cum = jnp.cumsum(-0.02 * jax.nn.softplus(
+        jax.random.normal(k[3], (1, 1, q, h, dk))), axis=2)
+    return -jnp.tril(gd._decayed_products(keys * beta, keys, cum,
+                                          jnp.float32), -1)
+
+
+@pytest.mark.parametrize("q, correlated", [(8, False), (16, False),
+                                           (48, False), (64, False),
+                                           (64, True)])
+def test_inverse_and_its_gradient(q, correlated):
+    """``(I - a)^-1``, by rows at Q of 16 or fewer and in blocks of 16
+    above, against numpy's inverse in float64 and against the rows' loop,
+    and its hand-written gradient against the one autodiff takes through
+    the rows' loop."""
+    if correlated:
+        a = rule_system(q)
+    else:
+        a = jnp.tril(0.4 * (16 / q) ** 0.5 * jax.random.normal(
+            jax.random.key(0), (3, 2, q, q)), -1)
+    want = np.linalg.inv(np.eye(q) - np.asarray(a, np.float64))
+    got = gd.unit_lower_inverse(a)
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(got, gd._inverse_rows(a), atol=1e-5,
                                rtol=1e-5)
     weight = jax.random.normal(jax.random.key(1), a.shape)
-    got = jax.grad(lambda a: jnp.sum(gd.unit_lower_inverse(a) * weight))(a)
+    grad = jax.grad(lambda a: jnp.sum(gd.unit_lower_inverse(a) * weight))(a)
     by_loop = jax.grad(lambda a: jnp.sum(gd._inverse_rows(a) * weight))(a)
-    np.testing.assert_allclose(got, jnp.tril(by_loop, -1), atol=1e-4,
+    np.testing.assert_allclose(grad, jnp.tril(by_loop, -1), atol=1e-4,
                                rtol=1e-4)
-    assert not np.asarray(jnp.triu(got)).any()
+    assert not np.asarray(jnp.triu(grad)).any()
+
+
+def loop_lengths(jaxpr):
+    """The trip counts of every ``scan`` in a jaxpr and the jaxprs under
+    it."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            out.append(eqn.params["length"])
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    out += loop_lengths(inner)
+    return out
+
+
+def test_inverse_at_a_chunk_of_64_loops_over_16_rows_not_64():
+    a = jnp.zeros((2, 64, 64))
+    assert gd.solve_block(64) == 16
+    lengths = loop_lengths(jax.make_jaxpr(gd.unit_lower_inverse)(a).jaxpr)
+    assert lengths == [15], lengths
+    assert loop_lengths(jax.make_jaxpr(gd.unit_lower_inverse)(
+        a[:, :8, :8]).jaxpr) == [7]
 
 
 def test_rule_keeps_its_inputs_alone_for_the_backward():
@@ -323,7 +371,8 @@ def test_the_mixers_scopes_are_in_the_compiled_program():
         p, state, [x], Context(train=False))[0]).lower(
             params, jnp.zeros((2, T, E))).as_text(debug_info=True)
     for scope in ("gdn_in_proj", "gdn_conv", "gdn_scan/", "gdn_chunk",
-                  "gdn_carry", "gdn_gate_norm", "gdn_out_proj"):
+                  "gdn_chunk/gdn_solve", "gdn_carry", "gdn_gate_norm",
+                  "gdn_out_proj"):
         assert scope in text, scope
 
 

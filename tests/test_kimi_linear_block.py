@@ -128,13 +128,15 @@ def random_vectors(params, key):
     return jax.tree_util.tree_map_with_path(leaf, params)
 
 
-def unit_of(spec, klass):
-    return klass(name="mix", **{k: v for k, v in spec.items()
+def unit_of(spec, klass, name="mix"):
+    return klass(name=name, **{k: v for k, v in spec.items()
                                 if k != "type"})
 
 
 def test_kda_mixer_is_the_reference_layer_and_sets_its_gauges():
-    unit = unit_of(KDA, KimiDeltaAttention)
+    # a name no other test file's unit takes: the gauges are the
+    # process's, and a worker may run the GatedDeltaNet tests' "mix" first
+    unit = unit_of(KDA, KimiDeltaAttention, "kda_mix")
     params, state = unit.init(jax.random.key(1),
                               [Spec((2, T, E), jnp.float32)])
     assert {k: v.shape for k, v in params.items()} == {
@@ -158,10 +160,10 @@ def test_kda_mixer_is_the_reference_layer_and_sets_its_gauges():
             assert rel(moved, want) > 1e-2, fault
     np.testing.assert_allclose(y, want, atol=1e-5, rtol=1e-5)
     gauges = {name: {key: child.value for key, child in
-                     registry().get(name)._snapshot() if "mix" in key}
+                     registry().get(name)._snapshot() if "kda_mix" in key}
               for name in ("vt_kda_chunks", "vt_delta_gate")}
     assert list(gauges["vt_kda_chunks"].values()) == [T // CHUNK]
-    assert gauges["vt_delta_gate"] == {("mix", "channel"): 1}
+    assert gauges["vt_delta_gate"] == {("kda_mix", "channel"): 1}
     gdn = GatedDeltaNet(3, 6, 10, name="gdn", chunk=CHUNK)
     p, s = gdn.init(jax.random.key(0), [Spec((1, T, E), jnp.float32)])
     gdn.apply(p, s, [x[:1]], Context(train=False))
@@ -169,12 +171,33 @@ def test_kda_mixer_is_the_reference_layer_and_sets_its_gauges():
             if "gdn" in key] == [("gdn", "head")]
 
 
+@pytest.mark.parametrize("make", [
+    lambda chunk: KimiDeltaAttention(2, 8, name="solve_kda", chunk=chunk),
+    lambda chunk: GatedDeltaNet(3, 6, 10, name="solve_gdn", chunk=chunk)],
+    ids=["kda", "gdn"])
+@pytest.mark.parametrize("chunk, block", [(8, 8), (64, 16)])
+def test_solve_block_gauge_is_the_block_the_inverse_takes(make, chunk,
+                                                          block):
+    """Both mixers set ``vt_delta_solve_block`` when their call is traced:
+    blocks of 16 at a chunk of 64, the rows loop's whole chunk at 8."""
+    unit = make(chunk)
+    spec = Spec((1, chunk, E), jnp.float32)
+    params, state = unit.init(jax.random.key(0), [spec])
+    jax.eval_shape(lambda p, x: unit.apply(p, state, [x],
+                                           Context(train=False))[0],
+                   params, spec)
+    assert [(key, child.value) for key, child in
+            registry().get("vt_delta_solve_block")._snapshot()
+            if unit.name in key] == [((unit.name,), block)]
+
+
 def test_the_units_scopes_are_in_the_compiled_program():
     x = jnp.zeros((2, T, E))
     for spec, klass, scopes in (
             (KDA, KimiDeltaAttention,
              ("kda_in_proj", "kda_conv", "kda_gate", "kda_scan",
-              "gdn_chunk", "gdn_carry", "kda_gate_norm", "kda_out_proj")),
+              "gdn_chunk", "gdn_solve", "gdn_carry", "kda_gate_norm",
+              "kda_out_proj")),
             (MLA, MultiHeadAttention, ("attn_kv_down", "attn_kv_up"))):
         unit = unit_of(spec, klass)
         params, state = unit.init(jax.random.key(1),

@@ -46,14 +46,20 @@ def _whole_chunks(unit, in_specs):
     return in_specs[0]
 
 
-def _note_decay(unit, kind):
-    """Gauge ``vt_delta_gate``, set when a delta-rule mixer's call is
-    traced: which decay it takes."""
+def _note_rule(unit, kind):
+    """Gauges ``vt_delta_gate`` and ``vt_delta_solve_block``, set when a
+    delta-rule mixer's call is traced: which decay it takes, and the
+    rows of the blocks its triangular inverse is taken in."""
     from ..runtime.metrics import registry
     registry().gauge(
         "vt_delta_gate", "1 on the decay the delta rule's last traced call "
         "took: one a head, or by channel", labels=("unit", "kind")).labels(
             unit=unit.name, kind=kind).set(1)
+    registry().gauge(
+        "vt_delta_solve_block", "rows of the diagonal blocks the delta "
+        "rule's last traced call took its triangular inverse in",
+        labels=("unit",)).labels(unit=unit.name).set(
+            gated_delta_ops.solve_block(unit.chunk))
 
 
 class GatedDeltaNet(Forward):
@@ -127,7 +133,7 @@ class GatedDeltaNet(Forward):
             "vt_gdn_chunks",
             "chunks a sequence of the delta rule's last traced call",
             labels=("unit",)).labels(unit=self.name).set(t // self.chunk)
-        _note_decay(self, "head")
+        _note_rule(self, "head")
         registry().gauge(
             "vt_gdn_heads", "heads the delta rule's last traced call held",
             labels=("unit",)).labels(unit=self.name).set(h)
@@ -242,7 +248,7 @@ class KimiDeltaAttention(Forward):
             "vt_kda_chunks",
             "chunks a sequence of the delta rule's last traced call",
             labels=("unit",)).labels(unit=self.name).set(t // self.chunk)
-        _note_decay(self, "channel")
+        _note_rule(self, "channel")
         dense = lambda a, w: ops.dense(a, params[w], compute_dtype=dtype)
         heads = lambda a: a.reshape(b, t, h, d)
         with jax.named_scope("kda_in_proj"):
